@@ -174,13 +174,14 @@ def _wire_compression_rows() -> None:
         import jax
         from repro.configs import ShapeConfig, get_arch
         from repro.core.pipeline import specialize
+        from repro.launch.mesh import make_mesh
         from repro.models import synthetic_batch
         from repro.optim.adamw import OptConfig
         from repro.train.trainer import Trainer, TrainerConfig
 
         arch = get_arch("qwen3-8b").reduced()
         shape = ShapeConfig("finetune_wire", "train", 64, 8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
 
         def run(gc):
             plan = specialize(arch, shape, mesh_axes=("data", "model"),
@@ -330,6 +331,7 @@ def _decode_step_rows(ks, H, K, D) -> None:
     import textwrap
 
     from repro.dist.flash_decode import flash_decode
+    from repro.launch.mesh import make_mesh
     from repro.models import lm
 
     B, S = 8, 4096
@@ -352,7 +354,7 @@ def _decode_step_rows(ks, H, K, D) -> None:
     emit("decode_step/xla/mixed_fill",
          _time(jax.jit(xla_step), q1, kn, vn, kc, vc, pos), fill)
 
-    mesh1 = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh1 = make_mesh((len(jax.devices()), 1), ("data", "model"))
     fd = jax.jit(lambda *a: flash_decode(*a, mesh=mesh1))
     emit("decode_step/flash/mixed_fill",
          _time(fd, q1, kn, vn, kc, vc, pos, 0),
@@ -363,6 +365,7 @@ def _decode_step_rows(ks, H, K, D) -> None:
     code = textwrap.dedent(f"""
         import jax, jax.numpy as jnp, numpy as np, time
         from repro.dist.flash_decode import flash_decode
+        from repro.launch.mesh import make_mesh
         B, S, H, K, D = {B}, {S}, {H}, {K}, {D}
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
         q = jax.random.normal(ks[0], (B, 1, H, D)).astype(jnp.bfloat16)
@@ -371,7 +374,7 @@ def _decode_step_rows(ks, H, K, D) -> None:
         kc = jax.random.normal(ks[1], (B, S, K, D)).astype(jnp.bfloat16)
         vc = jax.random.normal(ks[2], (B, S, K, D)).astype(jnp.bfloat16)
         pos = jnp.asarray(np.linspace(64, S - 1, B).astype(np.int32))
-        mesh = jax.make_mesh((1, 2), ("data", "model"))
+        mesh = make_mesh((1, 2), ("data", "model"))
         fn = jax.jit(lambda *a: flash_decode(*a, mesh=mesh))
         for _ in range(2):
             jax.block_until_ready(fn(q, kn, vn, kc, vc, pos, 0))
@@ -418,6 +421,7 @@ def _combine_topology_rows(H, K, D) -> None:
         code = textwrap.dedent(f"""
             import jax, jax.numpy as jnp, numpy as np, time
             from repro.core.costmodel import combine_hops
+            from repro.launch.mesh import make_mesh
             from repro.dist.flash_decode import flash_decode
             B, S, H, K, D, m = {B}, {S}, {H}, {K}, {D}, {m}
             ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -427,7 +431,7 @@ def _combine_topology_rows(H, K, D) -> None:
             kc = jax.random.normal(ks[1], (B, S, K, D)).astype(jnp.bfloat16)
             vc = jax.random.normal(ks[2], (B, S, K, D)).astype(jnp.bfloat16)
             pos = jnp.asarray(np.linspace(64, S - 1, B).astype(np.int32))
-            mesh = jax.make_mesh((1, m), ("data", "model"))
+            mesh = make_mesh((1, m), ("data", "model"))
             for topo in ("flat", "ring", "bidir"):
                 fn = jax.jit(lambda *a, t=topo: flash_decode(
                     *a, mesh=mesh, combine=t))
@@ -831,12 +835,13 @@ def _paged_2d_occupancy_rows(H, K, D) -> None:
         from repro.core.costmodel import kv_block_len
         from repro.dist.flash_decode import (flash_decode_paged,
                                              pool_sharding_kind)
+        from repro.launch.mesh import make_mesh
         B, S, H, K, D = {B}, {S}, {H}, {K}, {D}
         dsize, msize = 2, 4
         bl = kv_block_len(S)
         nbs = S // bl                       # blocks per sequence
         N = B * nbs                         # full worst-case pool
-        mesh = jax.make_mesh((dsize, msize), ("data", "model"))
+        mesh = make_mesh((dsize, msize), ("data", "model"))
         assert pool_sharding_kind(mesh, N, B) == "2d"
         ks = jax.random.split(jax.random.PRNGKey(0), 5)
         q = jax.random.normal(ks[0], (B, 1, H, D)).astype(jnp.bfloat16)

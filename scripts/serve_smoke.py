@@ -66,6 +66,7 @@ import numpy as np
 
 from repro.configs import ShapeConfig, get_arch
 from repro.core.pipeline import specialize
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.serve.engine import PreemptionPolicy, ServeEngine
 
@@ -493,7 +494,7 @@ def main() -> int:
     assert kvres == ("paged" if args.paged else "dense"), kvres
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((1, n_dev), ("data", "model"))
+    mesh = make_mesh((1, n_dev), ("data", "model"))
     params = lm.init_params(arch, jax.random.PRNGKey(0),
                             *plan.padded_sizes())
     eng = ServeEngine.from_plan(plan, params, arch=arch, mesh=mesh)

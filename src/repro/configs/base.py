@@ -219,6 +219,30 @@ def get_arch(name: str) -> ArchConfig:
     return mod.CONFIG
 
 
+def run_arch(name: str, *, reduced: bool = False, layers: int = 0
+             ) -> Tuple[ArchConfig, Dict[str, Tuple[object, object]]]:
+    """The config a launcher runs, and every key changed from the
+    published one as ``{key: (published, run)}``.
+
+    The published widths are the default.  ``layers`` cuts depth only
+    (the chip's share of a pipeline; every width stays as published);
+    ``reduced`` swaps in the tiny CPU config of :meth:`ArchConfig.reduced`.
+    """
+    src = get_arch(name)
+    arch = src.reduced() if reduced else src
+    if layers:
+        g = max(arch.moe_interleave, 1) if arch.is_moe else 1
+        if not 0 < layers <= arch.n_layers or layers % g:
+            raise ValueError(
+                f"layers={layers} must be in [1, {arch.n_layers}] and a "
+                f"multiple of the layer period {g} for {name}")
+        arch = dataclasses.replace(arch, n_layers=layers)
+    changed = {f.name: (getattr(src, f.name), getattr(arch, f.name))
+               for f in dataclasses.fields(src)
+               if getattr(src, f.name) != getattr(arch, f.name)}
+    return arch, changed
+
+
 def get_shape(name: str) -> ShapeConfig:
     if name not in SHAPES:
         raise KeyError(f"unknown shape {name!r}; have {sorted(SHAPES)}")

@@ -330,7 +330,12 @@ class DataOrganizationPass(Pass):
         psplit = kv_prefill_split(
             shape.seq_len, persistent, ctx.target.peak_bf16_flops,
             tick_s, chunk_len=geo.block_len)
-        pmode = psplit.mode if not arch.has_ssm else "inline"
+        # the workers are separate processes; on a one-chip mesh the
+        # serving process holds the only chip, so they would have no
+        # device of their own and prefill would run on the host
+        no_worker_device = n_chips == 1
+        pmode = ("inline" if arch.has_ssm or no_worker_device
+                 else psplit.mode)
         plan.estimates["kv_prefill_mode"] = pmode
         plan.estimates["kv_prefill_chunk"] = psplit.chunk_len
         plan.estimates["kv_prefill_stall_ticks"] = psplit.stall_ticks
@@ -341,6 +346,14 @@ class DataOrganizationPass(Pass):
                 "across the whole prompt, so chunked block-native "
                 "prefill (pure-attention KV) cannot ship blocks "
                 "incrementally; prefill stays in-process")
+        elif no_worker_device:
+            self.record(
+                ctx, "kv_prefill_mode", "inline",
+                f"one-chip mesh: the serving process holds the only chip, "
+                "so prefill workers would have no device of their own "
+                f"(modeled stall {psplit.stall_ticks:.0f} tick(s), "
+                f"threshold {psplit.threshold_ticks:.0f}); prefill stays "
+                "in-process")
         else:
             self.record(
                 ctx, "kv_prefill_mode", pmode,

@@ -17,10 +17,7 @@ run; this package only implements *how*.
 
 from __future__ import annotations
 
-# installs the jax.shard_map alias on jax < 0.5 (tests call it directly)
-from repro import compat as _compat  # noqa: F401
-
-from repro.dist.collectives import (  # noqa: E402,F401
+from repro.dist.collectives import (  # noqa: F401
     compressed_psum,
     compressed_slice_sum,
     dequantize_int8,
@@ -28,7 +25,7 @@ from repro.dist.collectives import (  # noqa: E402,F401
     ef_state,
     quantize_int8,
 )
-from repro.dist.sharding import (  # noqa: E402,F401
+from repro.dist.sharding import (  # noqa: F401
     cache_pspecs,
     mesh_sizes,
     resolve_pspec,

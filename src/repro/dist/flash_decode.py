@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat  # noqa: F401  (jax.shard_map alias)
 from repro.dist.sharding import mesh_sizes
 
 NEG_INF = -1e30
@@ -253,7 +252,6 @@ def flash_decode(q: jax.Array,            # (B, 1, H, D)
     shd = P(bspec, model_axis, None, None)
     # check_vma=False: the combine provably replicates ctx across the
     # model axis (psum/pmax), no need for the static replication checker
-    # (repro.compat translates the kwarg for jax < 0.5)
     fn = jax.shard_map(local_fn, mesh=mesh,
                        in_specs=(rep, rep, rep, shd, shd, P(bspec), P()),
                        out_specs=(rep, shd, shd), check_vma=False)

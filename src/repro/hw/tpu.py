@@ -5,8 +5,11 @@ fixed, so the target spec is the set of capacities/bandwidths the passes
 budget against.  All roofline math in :mod:`repro.analysis.roofline` reads
 these numbers, so there is a single source of truth for the hardware model.
 
-Numbers for the default target (TPU v5e) follow the task specification:
-197 TFLOP/s bf16 per chip, 819 GB/s HBM bandwidth, ~50 GB/s per ICI link.
+Peaks of the default target, one TPU v5e chip (Google Cloud
+documentation, "TPU v5e"): 197 TFLOP/s in bf16, 393 TOP/s in int8, 16 GB
+of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 ICI
+links of 50 GB/s).  :func:`target_for_device` maps the ``device_kind``
+JAX reports to a target; a kind not in the table is an error.
 """
 
 from __future__ import annotations
@@ -96,6 +99,23 @@ _TARGETS = {
         host_bytes_per_chip=96 * GiB,
     ),
 }
+
+
+# device_kind, as ``jax.devices()[i].device_kind`` reports it -> target
+_DEVICE_KINDS = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
+def target_for_device(device) -> str:
+    """Name of the target for a TPU ``device``; raises on a kind (or a
+    platform) the table does not hold."""
+    kind = getattr(device, "device_kind", "")
+    if getattr(device, "platform", "") != "tpu" or kind not in _DEVICE_KINDS:
+        raise KeyError(
+            f"no TPU target for {getattr(device, 'platform', '?')} device "
+            f"kind {kind!r}; have {sorted(_DEVICE_KINDS)}")
+    return _DEVICE_KINDS[kind]
 
 
 def get_target(name: str = "tpu-v5e") -> TpuTarget:

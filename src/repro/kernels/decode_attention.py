@@ -74,19 +74,18 @@ def _decode_kernel(
 def _paged_decode_kernel(
     tbl_ref,      # SMEM (B, nb) int32 block table (scalar prefetch)
     len_ref,      # SMEM (B,) int32 per-slot valid lengths (scalar prefetch)
-    q_ref,        # (1, 1, G, D)
-    k_ref,        # (1, block_len, 1, D) — the slot's j-th block
-    v_ref,        # (1, block_len, 1, D)
-    o_ref,        # (1, 1, G, D)
-    m_scr, l_scr, acc_scr,
+    q_ref,        # (1, K, G, D)
+    k_ref,        # (1, block_len, K, D) — the slot's j-th block, all kv heads
+    v_ref,        # (1, block_len, K, D)
+    o_ref,        # (1, K, G, D)
+    m_scr, l_scr, acc_scr,      # (K, G), (K, G), (K, G, D)
     *,
     block_len: int,
-    n_kv: int,
     window: int,
     scale: float,
 ):
-    b = pl.program_id(0) // n_kv       # grid dim 0 is batch*kv_head
-    j = pl.program_id(2)
+    b = pl.program_id(0)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -95,12 +94,6 @@ def _paged_decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     cache_len = len_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)               # (block_len, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
     # dense-view positions: block j covers rows [j*bl, (j+1)*bl); an
     # unassigned (-1) table entry was clamped to block 0 by the index
     # map, but its whole range sits past cache_len, so the mask kills it
@@ -109,21 +102,28 @@ def _paged_decode_kernel(
     mask = kpos < cache_len
     if window > 0:
         mask &= (cache_len - 1 - kpos) < window
-    s = jnp.where(mask[None, :], s, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_cur
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h].astype(jnp.float32) * scale      # (G, D)
+        k = k_ref[0, :, h].astype(jnp.float32)           # (block_len, D)
+        v = v_ref[0, :, h].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(mask[None, :], s, NEG_INF)
+        m_prev = m_scr[h]
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur[:, None])
+        l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1)
+        acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[h] = m_cur
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_scr[...], 1e-30)[..., None]
+        o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
@@ -140,9 +140,9 @@ def paged_decode_attention(
     """Flash-decode over a paged cache: the block table streams the
     slot's blocks through VMEM via scalar-prefetch indexed DMA.
 
-    The grid walks (batch*kv_head, 1, table_cols); each step's k/v
-    BlockSpec index map reads ``block_tbl[b, j]`` (prefetched to SMEM
-    before the kernel runs) to pick the pool block to DMA — the gather
+    The grid walks (batch, table_cols); each step's k/v BlockSpec index
+    map reads ``block_tbl[b, j]`` (prefetched to SMEM before the kernel
+    runs) to pick the pool block to DMA, all kv heads at once — the gather
     never materializes a dense per-slot cache in HBM.  Semantics match
     :func:`repro.kernels.ref.paged_decode_attention_ref`.
     """
@@ -152,41 +152,40 @@ def paged_decode_attention(
     G = H // K
     scale = D ** -0.5
 
-    qg = q.reshape(B, 1, K, G, D).transpose(0, 2, 1, 3, 4) \
-        .reshape(B * K, 1, G, D)
+    qg = q.reshape(B, K, G, D)
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
     tbl = jnp.asarray(block_tbl, jnp.int32)
 
-    def kv_index(bk, i, j, tbl_ref, len_ref):
-        # the pool is shared: the table row picks the block for this
-        # slot (bk // K), the grid step's kv head indexes dim 2 directly
-        return (jnp.maximum(tbl_ref[bk // K, j], 0), 0, bk % K, 0)
+    def kv_index(b, j, tbl_ref, len_ref):
+        # the pool is shared: the slot's table row picks the block; a
+        # block carries every kv head, so one DMA per (slot, block)
+        return (jnp.maximum(tbl_ref[b, j], 0), 0, 0, 0)
 
-    grid = (B * K, 1, nb)
+    grid = (B, nb)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, block_len=block_len,
-                          n_kv=K, window=window, scale=scale),
+                          window=window, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, i, j, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, block_len, 1, D), kv_index),
-                pl.BlockSpec((1, block_len, 1, D), kv_index),
+                pl.BlockSpec((1, K, G, D), lambda b, j, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, block_len, K, D), kv_index),
+                pl.BlockSpec((1, block_len, K, D), kv_index),
             ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, i, j, *_: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, K, G, D),
+                                   lambda b, j, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, D), jnp.float32),
+                pltpu.VMEM((K, G), jnp.float32),
+                pltpu.VMEM((K, G), jnp.float32),
+                pltpu.VMEM((K, G, D), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B * K, 1, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
     )(tbl, clen, qg, k_pool, v_pool)
-    return out.reshape(B, K, G, D).reshape(B, H, D)
+    return out.reshape(B, H, D)
 
 
 @functools.partial(

@@ -7,7 +7,8 @@ working set fits the VMEM budget and tile dims are MXU multiples.
 
 Grid: (batch·kv_head, q_blocks, kv_blocks) — kv innermost so the online
 softmax carry (m, l, acc) lives in VMEM scratch across kv steps.
-GQA is handled by loading q as (G·block_q, D) per kv head.
+GQA is handled by loading q as (G, block_q, D) per kv head and looping
+over the G query heads inside the kernel.
 
 Causal grid pruning: with ``causal=True`` the kv blocks strictly above
 the diagonal are fully masked, so computing-then-masking them wastes
@@ -49,26 +50,68 @@ def kv_grid_steps(seq_len: int, block_q: int, block_kv: int, *,
     return (seq_len // block_q) * (seq_len // block_kv)
 
 
-def _mask_scores(s, q_idx, kv_idx, block_q, block_kv, G, causal, window):
+def _mask_scores(s, q_idx, kv_idx, block_q, block_kv, causal, window):
     qpos = q_idx * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, G), 0).reshape(block_q * G)
+        jnp.int32, (block_q, block_kv), 0)
     kpos = kv_idx * block_kv + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_kv), 1)[0]
-    mask = jnp.ones((block_q * G, block_kv), dtype=jnp.bool_)
+        jnp.int32, (block_q, block_kv), 1)
+    mask = jnp.ones((block_q, block_kv), dtype=jnp.bool_)
     if causal:
-        mask &= qpos[:, None] >= kpos[None, :]
+        mask &= qpos >= kpos
     if window > 0:
-        mask &= (qpos[:, None] - kpos[None, :]) < window
+        mask &= (qpos - kpos) < window
     return jnp.where(mask, s, NEG_INF)
+
+
+def _init_carry(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _online_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, q_idx, kv_idx,
+                 *, block_q, block_kv, causal, window, scale):
+    """One (q tile, kv tile) online-softmax update for each of the G
+    query heads sharing this kv head.  Every operand is a 2-D
+    (rows, D) tile, so no in-kernel reshape crosses the (8, 128)
+    register tiling."""
+    k = k_ref[0].astype(jnp.float32)                      # (block_kv, D)
+    v = v_ref[0].astype(jnp.float32)
+
+    # a loop, not an unrolled comprehension: one head's (block_q,
+    # block_kv) scores live at a time, which bounds VMEM at any G
+    def head(g, carry):
+        q = q_ref[0, g].astype(jnp.float32) * scale       # (block_q, D)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = _mask_scores(s, q_idx, kv_idx, block_q, block_kv, causal,
+                         window)
+        m_prev = m_scr[g]                                 # (block_q, 1)
+        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_cur)
+        p = jnp.exp(s - m_cur)
+        l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[g] = m_cur
+        return carry
+
+    jax.lax.fori_loop(0, q_ref.shape[1], head, 0)
+
+
+def _write_out(o_ref, l_scr, acc_scr):
+    denom = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def _flash_kernel(
     # refs sliced by BlockSpec:
-    q_ref,        # (1, block_q, G, D)
+    q_ref,        # (1, G, block_q, D)
     k_ref,        # (1, block_kv, D)
     v_ref,        # (1, block_kv, D)
-    o_ref,        # (1, block_q, G, D)
-    m_scr, l_scr, acc_scr,      # VMEM scratch: (block_q*G,), (block_q*G,), (block_q*G, D)
+    o_ref,        # (1, G, block_q, D)
+    m_scr, l_scr, acc_scr,      # VMEM scratch: (G, block_q, 1) x2, (G, block_q, D)
     *,
     causal: bool,
     window: int,
@@ -78,37 +121,18 @@ def _flash_kernel(
 ):
     kv_idx = pl.program_id(2)
     q_idx = pl.program_id(1)
-    G = q_ref.shape[2]
-    D = q_ref.shape[3]
 
     @pl.when(kv_idx == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_carry(m_scr, l_scr, acc_scr)
 
-    q = q_ref[0].reshape(block_q * G, D).astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)                      # (block_kv, D)
-    v = v_ref[0].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = _mask_scores(s, q_idx, kv_idx, block_q, block_kv, G, causal, window)
-
-    m_prev = m_scr[...]
-    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_cur)
-    p = jnp.exp(s - m_cur[:, None])
-    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[...] = m_cur
+    _online_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, q_idx, kv_idx,
+                 block_q=block_q, block_kv=block_kv, causal=causal,
+                 window=window, scale=scale)
 
     @pl.when(kv_idx == pl.num_programs(2) - 1)
     def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).reshape(
-            block_q, G, D).astype(o_ref.dtype)
+        _write_out(o_ref, l_scr, acc_scr)
 
 
 # ---------------------------------------------------------------------
@@ -144,38 +168,28 @@ def _flash_kernel_packed(
     r = pl.program_id(1)
     c = pl.program_id(2)
     i, j, valid, seg_start, seg_end = _packed_coords(r, c, n)
-    G = q_ref.shape[2]
-    D = q_ref.shape[3]
 
     @pl.when(valid & seg_start)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _init_carry(m_scr, l_scr, acc_scr)
 
     @pl.when(valid)
     def _compute():
-        q = q_ref[0].reshape(block * G, D).astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = _mask_scores(s, i, j, block, block, G, True, window)
-        m_prev = m_scr[...]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[...] = m_cur
+        _online_step(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, i, j,
+                     block_q=block, block_kv=block, causal=True,
+                     window=window, scale=scale)
 
     @pl.when(valid & seg_end)
     def _finish():
-        denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0] = (acc_scr[...] / denom).reshape(
-            block, G, D).astype(o_ref.dtype)
+        _write_out(o_ref, l_scr, acc_scr)
+
+
+def _scratch(G, block_q, D):
+    """Online-softmax carry: running max and sum as (rows, 1) columns,
+    the fp32 accumulator as (rows, D), one slab per query head."""
+    return [pltpu.VMEM((G, block_q, 1), jnp.float32),
+            pltpu.VMEM((G, block_q, 1), jnp.float32),
+            pltpu.VMEM((G, block_q, D), jnp.float32)]
 
 
 def _flash_causal_packed(qg, kg, vg, *, window, block, S, G, D, scale,
@@ -187,7 +201,7 @@ def _flash_causal_packed(qg, kg, vg, *, window, block, S, G, D, scale,
 
     def q_index(b, r, c):
         i, _, _, _, _ = _packed_coords(r, c, n)
-        return (b, i, 0, 0)
+        return (b, 0, i, 0)
 
     def kv_index(b, r, c):
         _, j, _, _, _ = _packed_coords(r, c, n)
@@ -199,17 +213,13 @@ def _flash_causal_packed(qg, kg, vg, *, window, block, S, G, D, scale,
             scale=scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block, G, D), q_index),
+            pl.BlockSpec((1, G, block, D), q_index),
             pl.BlockSpec((1, block, D), kv_index),
             pl.BlockSpec((1, block, D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, block, G, D), q_index),
-        out_shape=jax.ShapeDtypeStruct((BK, S, G, D), qg.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block * G,), jnp.float32),
-            pltpu.VMEM((block * G,), jnp.float32),
-            pltpu.VMEM((block * G, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, G, block, D), q_index),
+        out_shape=jax.ShapeDtypeStruct((BK, G, S, D), qg.dtype),
+        scratch_shapes=_scratch(G, block, D),
         interpret=interpret,
     )(qg, kg, vg)
 
@@ -238,9 +248,10 @@ def flash_attention(
     assert S % block_q == 0 and S % block_kv == 0, (S, block_q, block_kv)
     scale = D ** -0.5
 
-    # layout: fold heads into the grid; q as (B*K, S, G, D)
-    qg = q.reshape(B, S, K, G, D).transpose(0, 2, 1, 3, 4) \
-        .reshape(B * K, S, G, D)
+    # layout: fold kv heads into the grid; q as (B*K, G, S, D) so every
+    # block's trailing dims are (rows, D)
+    qg = q.reshape(B, S, K, G, D).transpose(0, 2, 3, 1, 4) \
+        .reshape(B * K, G, S, D)
     kg = k.transpose(0, 2, 1, 3).reshape(B * K, S, D)
     vg = v.transpose(0, 2, 1, 3).reshape(B * K, S, D)
 
@@ -256,19 +267,15 @@ def flash_attention(
                 block_q=block_q, block_kv=block_kv, scale=scale),
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, block_q, G, D), lambda b, i, j: (b, i, 0, 0)),
+                pl.BlockSpec((1, G, block_q, D), lambda b, i, j: (b, 0, i, 0)),
                 pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
                 pl.BlockSpec((1, block_kv, D), lambda b, i, j: (b, j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, G, D),
-                                   lambda b, i, j: (b, i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B * K, S, G, D), q.dtype),
-            scratch_shapes=[
-                pltpu.VMEM((block_q * G,), jnp.float32),
-                pltpu.VMEM((block_q * G,), jnp.float32),
-                pltpu.VMEM((block_q * G, D), jnp.float32),
-            ],
+            out_specs=pl.BlockSpec((1, G, block_q, D),
+                                   lambda b, i, j: (b, 0, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((B * K, G, S, D), q.dtype),
+            scratch_shapes=_scratch(G, block_q, D),
             interpret=interpret,
         )(qg, kg, vg)
-    return out.reshape(B, K, S, G, D).transpose(0, 2, 1, 3, 4) \
+    return out.reshape(B, K, G, S, D).transpose(0, 3, 1, 2, 4) \
         .reshape(B, S, H, D)

@@ -70,8 +70,6 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):        # jax < 0.5 wraps it per-device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
 
     rep = roofline.analyze(

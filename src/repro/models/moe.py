@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat as _compat  # noqa: F401  (jax.shard_map alias)
-
 
 class MoEParams(NamedTuple):
     router: jax.Array          # (d, E) fp32

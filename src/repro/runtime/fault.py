@@ -105,9 +105,8 @@ def elastic_mesh(n_devices: int, model_parallel: int,
             f"only {len(devices)} device(s) visible; need {usable} "
             f"(data={data} x model_parallel={model_parallel}) — "
             f"shrink n_devices to what actually survived")
-    import numpy as np
-    arr = np.array(devices).reshape(data, model_parallel)
-    return jax.sharding.Mesh(arr, axis_names)
+    from repro.launch.mesh import make_mesh
+    return make_mesh((data, model_parallel), axis_names, devices=devices)
 
 
 class StepGuard:

@@ -85,8 +85,6 @@ def _worker_main(wid: int, inq, outq, payload: Dict[str, Any]) -> None:
       ``("prefill", rid, tail_tokens, prefix_k, prefix_v)``
       ``("stop",)``
     """
-    import os
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
 
     from repro.core.passes.lowering import build_run_cfg

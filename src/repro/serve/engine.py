@@ -164,6 +164,16 @@ from repro.runtime.straggler import StepTimer
 from repro.serve.prefix_cache import PrefixCache, chain_hashes
 
 
+def _refuse_disagg_on_tpu() -> None:
+    """Prefill workers are spawned processes; on a TPU host this process
+    holds the chips, so a worker would prefill on the host CPU."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "kv_prefill_mode='disagg' is refused on a TPU backend: the "
+            "prefill workers would have no chip of their own and would "
+            "prefill on the host CPU; serve with inline prefill")
+
+
 class OverloadError(RuntimeError):
     """The engine is shedding load: the recent preemption rate crossed
     the policy threshold, so new admissions would only thrash the pool
@@ -548,6 +558,7 @@ class ServeEngine:
         chunked block-native prefill needs the paged pool to scatter
         into and pure-attention KV to chunk (an SSM path's state is
         sequential across the whole prompt)."""
+        _refuse_disagg_on_tpu()
         if self.kv_residency != "paged":
             raise ValueError(
                 "disaggregated prefill streams pool-block-shaped KV "
@@ -584,7 +595,8 @@ class ServeEngine:
 
         The plan supplies everything the kwargs constructor asks for:
         the RunCfg (flash-attention tiles, padded head counts, decode
-        implementation, pallas-vs-ref dispatch), the KV-cache sizing
+        implementation; attention runs through XLA — the plan's
+        ``use_pallas`` does not reach the model path), the KV-cache sizing
         (padded kv/ssm heads), the admission mode the cost model chose
         (``kv_admission`` — grow-on-demand grants when the pool is the
         reclamation bet, worst-case reservation when it covers every
@@ -685,6 +697,7 @@ class ServeEngine:
                     and eng.kv_residency == "paged" \
                     and arch.has_attention and not arch.has_ssm:
                 from repro.serve.disagg import PrefillFleet
+                _refuse_disagg_on_tpu()        # before spawning workers
                 fleet = PrefillFleet(
                     plan, arch, params, disagg_workers,
                     block_len=eng.block_len,
@@ -1317,8 +1330,8 @@ class ServeEngine:
             self.cache["block_tbl"] = \
                 self.cache["block_tbl"].at[slot].set(jnp.asarray(rows))
         if not r.out_tokens:
-            tok = self._sample(jnp.asarray(np.asarray(logits)),
-                               r.temperature, self._next_key())
+            tok = self._sample(r, jnp.asarray(np.asarray(logits)),
+                               self._next_key())
             r.out_tokens.append(int(tok))
             r.t_first = time.time()
             if len(r.out_tokens) >= r.max_new_tokens:
@@ -1624,7 +1637,7 @@ class ServeEngine:
                 idxs.append(i)
                 live_slots.append(slots[i])
                 continue
-            tok = self._sample(logits[i], r.temperature, keys[i])
+            tok = self._sample(r, logits[i], keys[i])
             r.out_tokens.append(int(tok))
             r.t_first = time.time()
             if len(r.out_tokens) >= r.max_new_tokens:
@@ -2219,12 +2232,13 @@ class ServeEngine:
         self._key, sub = jax.random.split(self._key)
         return sub
 
-    def _sample(self, logits: jax.Array, temperature: float,
-                key: jax.Array) -> int:
+    def _sample(self, r: Request, logits: jax.Array, key: jax.Array) -> int:
+        """Request ``r``'s next token from its logits row (the one place
+        every emitted token is drawn; probes subclass it)."""
         logits = logits[:self.arch.vocab_size].astype(jnp.float32)
-        if temperature <= 0:
+        if r.temperature <= 0:
             return int(jnp.argmax(logits))
-        return int(jax.random.categorical(key, logits / temperature))
+        return int(jax.random.categorical(key, logits / r.temperature))
 
     def _sync_pos(self) -> None:
         """Mirror per-slot lengths into the device cache (freed slots 0)."""
@@ -2278,7 +2292,7 @@ class ServeEngine:
         slot_keys = jax.random.split(self._next_key(), self.max_batch)
         finished = []
         for slot, r in list(self.active.items()):
-            tok = self._sample(logits[slot], r.temperature, slot_keys[slot])
+            tok = self._sample(r, logits[slot], slot_keys[slot])
             r.out_tokens.append(int(tok))
             if r.t_first == 0.0:       # first token via decode-ride
                 r.t_first = time.time()

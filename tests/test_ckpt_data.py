@@ -11,6 +11,7 @@ import pytest
 from repro.ckpt import Checkpointer
 from repro.configs import ShapeConfig, get_arch
 from repro.data import PrefetchPipeline, SyntheticSource
+from repro.launch.mesh import make_mesh
 
 
 def _state(seed=0):
@@ -50,7 +51,7 @@ def test_atomicity_no_partial_visible(tmp_path):
 
 
 def test_restore_with_shardings(tmp_path):
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     sh = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     ck = Checkpointer(tmp_path)
     ck.save(1, _state(), blocking=True)

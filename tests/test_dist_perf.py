@@ -24,6 +24,7 @@ from repro.core.pipeline import (clear_plan_cache, plan_cache_stats,
                                  specialize)
 from repro.dist.collectives import dequantize_int8, ef_compress, quantize_int8
 from repro.dist.sharding import cache_pspecs, mesh_sizes, resolve_pspec
+from repro.launch.mesh import make_mesh
 from repro.kernels.flash_attention import flash_attention, kv_grid_steps
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
@@ -68,7 +69,8 @@ def test_compressed_psum_axis_size_two_awkward_shape():
             import jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
             from repro.dist.collectives import compressed_psum
-            mesh = jax.make_mesh((2,), ("data",))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh((2,), ("data",))
             x = jnp.arange(2 * 37, dtype=jnp.float32).reshape(2, 37) / 5.0
             def f(xs):
                 y, err = compressed_psum(xs[0], "data")
@@ -130,7 +132,7 @@ def test_mesh_sizes_accepts_all_mesh_flavors():
     mm = MeshModel(axes=("data", "model"), shape=(4, 2))
     assert mesh_sizes(mm) == {"data": 4, "model": 2}
     assert mesh_sizes({"data": 4}) == {"data": 4}
-    m = jax.make_mesh((1,), ("data",))
+    m = make_mesh((1,), ("data",))
     assert mesh_sizes(m) == {"data": 1}
 
 

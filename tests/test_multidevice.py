@@ -25,7 +25,8 @@ def run_subprocess(code: str, timeout: int = 300) -> str:
     # metadata retries) on machines with libtpu installed but no TPU
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
+        [sys.executable, "-c",
+         "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
@@ -36,7 +37,7 @@ def test_flash_decode_sharded_matches_oracle():
         import jax, jax.numpy as jnp
         from repro.dist.flash_decode import flash_decode
         from repro.kernels import ref
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, S, H, K, D = 4, 64, 8, 4, 16
         ks = jax.random.split(jax.random.PRNGKey(0), 5)
         q = jax.random.normal(ks[0], (B, 1, H, D))
@@ -99,7 +100,7 @@ def test_serve_from_plan_shard_map_flash_end_to_end():
                           kv_residency="dense")
         assert plan.estimates.get("decode_impl") == "shard_map_flash"
         assert plan.estimates.get("kv_residency") == "dense"
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         params = lm.init_params(arch, jax.random.PRNGKey(0),
                                 *plan.padded_sizes())
         eng = ServeEngine.from_plan(plan, params, arch=arch, mesh=mesh)
@@ -141,7 +142,7 @@ def test_flash_decode_paged_pool_sharded_matches_oracle():
         from repro.dist.flash_decode import flash_decode_paged, \\
             pool_sharding_kind
         from repro.kernels import ref
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, H, K, D, bl, N = 3, 8, 4, 16, 8, 16       # 4 blocks per shard
         assert pool_sharding_kind(mesh, N, B) == "1d"
         ks = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -182,7 +183,7 @@ def test_flash_decode_paged_2d_matches_oracle():
         from repro.dist.flash_decode import flash_decode_paged, \\
             pool_sharding_kind
         from repro.kernels import ref
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, H, K, D, bl, N = 4, 8, 4, 16, 8, 16   # 2 blocks/(data,model) shard
         assert pool_sharding_kind(mesh, N, B) == "2d"
         ks = jax.random.split(jax.random.PRNGKey(1), 5)
@@ -232,7 +233,7 @@ def test_flash_decode_paged_2d_aliased_tables():
         from repro.dist.flash_decode import flash_decode_paged, \\
             pool_sharding_kind
         from repro.kernels import ref
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, H, K, D, bl, N = 4, 8, 4, 16, 8, 16
         assert pool_sharding_kind(mesh, N, B) == "2d"
         ks = jax.random.split(jax.random.PRNGKey(2), 5)
@@ -311,7 +312,7 @@ def test_serve_paged_2d_shared_prefix_token_identity():
         assert plan.estimates["kv_residency"] == "paged"
         assert plan.estimates["kv_prefix_reuse"] == "on"
         assert plan.estimates["kv_pool_data_degree"] == 2
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = lm.init_params(arch, jax.random.PRNGKey(0),
                                 *plan.padded_sizes())
 
@@ -380,7 +381,7 @@ def test_serve_from_plan_paged_pool_sharded():
         assert plan.estimates.get("decode_impl") == "shard_map_flash"
         assert plan.estimates.get("kv_residency") == "paged"
         assert plan.estimates["kv_n_blocks"] % 8 == 0   # pool shardable
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         params = lm.init_params(arch, jax.random.PRNGKey(0),
                                 *plan.padded_sizes())
         eng = ServeEngine.from_plan(plan, params, arch=arch, mesh=mesh)
@@ -452,10 +453,10 @@ def test_serve_paged_2d_token_identity_vs_dense_sequential(name):
             def __init__(self, *a, **kw):
                 super().__init__(*a, **kw)
                 self.steps = []
-            def _sample(self, logits, temperature, key):
+            def _sample(self, r, logits, key):
                 self.steps.append(np.asarray(
                     logits[:self.arch.vocab_size], np.float32))
-                return super()._sample(logits, temperature, key)
+                return super()._sample(r, logits, key)
 
         arch = get_arch(name).reduced()
         if arch.has_attention:
@@ -465,7 +466,7 @@ def test_serve_paged_2d_token_identity_vs_dense_sequential(name):
         shape = ShapeConfig("serve_2d", "decode", 64, 4)
         plan = specialize(arch, shape, mesh_axes=("data", "model"),
                           mesh_shape=(2, 4), cache=False)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         params = lm.init_params(arch, jax.random.PRNGKey(0),
                                 *plan.padded_sizes())
         prompts = [np.arange(5, dtype=np.int32) % arch.vocab_size,
@@ -543,7 +544,7 @@ def test_moe_shard_map_matches_gshard_on_mesh():
         import jax, jax.numpy as jnp
         from repro.models.moe import (MoEParams, moe_gshard_einsum,
                                       moe_shard_map)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         B, S, d, E, ff, k = 4, 32, 16, 8, 32, 2
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         p = MoEParams(
@@ -571,7 +572,7 @@ def test_compressed_psum_multi_shard():
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
         from repro.dist.collectives import compressed_psum
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 7.0
         def f(xs):
             y, err = compressed_psum(xs[0], "data")
@@ -622,15 +623,15 @@ def test_combine_topology_matrix_ring_bidir_vs_flat():
         # flat; overrides force the wire pattern; a degenerate model
         # axis has no cross-shard combine so even an override is flat
         for dsz, msz in ((4, 2), (2, 4), (1, 8)):
-            m = jax.make_mesh((dsz, msz), ("data", "model"))
+            m = make_mesh((dsz, msz), ("data", "model"))
             assert combine_topology(m) == "flat"
             assert combine_topology(m, override="ring") == "ring"
             assert combine_topology(m, override="bidir") == "bidir"
-        m1 = jax.make_mesh((8, 1), ("data", "model"))
+        m1 = make_mesh((8, 1), ("data", "model"))
         assert combine_topology(m1) == "flat"
         assert combine_topology(m1, override="bidir") == "flat"
         try:
-            combine_topology(jax.make_mesh((1, 8), ("data", "model")),
+            combine_topology(make_mesh((1, 8), ("data", "model")),
                              override="hypercube")
             raise SystemExit("expected ValueError on unknown topology")
         except ValueError:
@@ -650,7 +651,7 @@ def test_combine_topology_matrix_ring_bidir_vs_flat():
         r = ref.decode_attention_ref(q[:, 0], kr, vr,
                                      cache_len=pos + 1, window=0)
         for dsz, msz in ((4, 2), (2, 4), (1, 8)):
-            mesh = jax.make_mesh((dsz, msz), ("data", "model"))
+            mesh = make_mesh((dsz, msz), ("data", "model"))
             outs = {}
             for t in TOPOS:
                 ctx, _, _ = jax.jit(lambda *a, t=t: flash_decode(
@@ -672,7 +673,7 @@ def test_combine_topology_matrix_ring_bidir_vs_flat():
         pr = ref.paged_decode_attention_ref(
             q[:Bp, 0], kpr, vpr, tbl, cache_len=ppos + 1, window=0)
         for dsz, msz in ((4, 2), (2, 4), (1, 8)):
-            mesh = jax.make_mesh((dsz, msz), ("data", "model"))
+            mesh = make_mesh((dsz, msz), ("data", "model"))
             outs = {}
             for t in TOPOS:
                 ctx, _, _ = jax.jit(lambda *a, t=t: flash_decode_paged(
@@ -684,7 +685,7 @@ def test_combine_topology_matrix_ring_bidir_vs_flat():
         # 2-D placement (batch-partitioned sub-pools, model degree 4):
         # the combine override must plumb through the 2-D combine too
         from repro.dist.flash_decode import pool_sharding_kind
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = make_mesh((2, 4), ("data", "model"))
         t2 = jnp.asarray([[0, 5, 3, -1], [7, 2, -1, -1],
                           [8, 15, 11, 13], [9, 14, -1, -1]], jnp.int32)
         p2 = jnp.asarray([16, 8, 31, 10], jnp.int32)
@@ -733,7 +734,7 @@ def test_serve_from_plan_ring_combine_end_to_end():
         assert recs and recs[-1][0] == "ring" \\
             and "forced by options" in recs[-1][1], recs
 
-        mesh = jax.make_mesh((1, 8), ("data", "model"))
+        mesh = make_mesh((1, 8), ("data", "model"))
         params = lm.init_params(arch, jax.random.PRNGKey(0),
                                 *plan.padded_sizes())
         eng = ServeEngine.from_plan(plan, params, arch=arch, mesh=mesh)
@@ -773,7 +774,7 @@ def test_train_step_fsdp_dp_multidevice():
         from repro.models import synthetic_batch
         from repro.optim import OptConfig
         from repro.train.trainer import Trainer, TrainerConfig
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         arch = get_arch("qwen3-8b").reduced()
         shape = ShapeConfig("t", "train", 64, 8)
         plan = specialize(arch, shape, mesh_axes=("data", "model"),

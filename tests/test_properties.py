@@ -24,6 +24,7 @@ from repro.core.costmodel import (MeshModel, allgather_bytes, allreduce_bytes,
                                   kv_block_geometry, reduce_scatter_bytes)
 from repro.dist.collectives import dequantize_int8, ef_compress, quantize_int8
 from repro.dist.sharding import resolve_pspec
+from repro.launch.mesh import make_mesh
 from repro.models.moe import _capacity
 from repro.serve.allocator import BlockAllocator
 
@@ -573,7 +574,7 @@ def test_compressed_slice_sum_matches_compressed_psum_degenerate():
     from repro.dist.collectives import compressed_psum, compressed_slice_sum
     x = jnp.asarray(np.random.default_rng(3).standard_normal((3, 200)),
                     jnp.float32)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     y1, e1 = jax.jit(jax.shard_map(
         lambda v: compressed_psum(v, "data"), mesh=mesh,
         in_specs=P(), out_specs=(P(), P())))(x)
@@ -633,7 +634,7 @@ def test_combine_topology_dispatch_agreement_single_process():
     from repro.models.lm import RunCfg
     from repro.serve.engine import ServeEngine
 
-    mesh1 = jax.make_mesh((1,), ("model",))
+    mesh1 = make_mesh((1,), ("model",))
     assert combine_topology(mesh1) == "flat"
     assert combine_topology(mesh1, override="bidir") == "flat"
     # the degenerate short-circuit wins even over a bogus override: no
@@ -641,7 +642,7 @@ def test_combine_topology_dispatch_agreement_single_process():
     # pinned by the 8-device matrix test in test_multidevice)
     assert combine_topology(mesh1, override="hypercube") == "flat"
     # a mesh without the model axis at all is the same degenerate case
-    assert combine_topology(jax.make_mesh((1,), ("data",))) == "flat"
+    assert combine_topology(make_mesh((1,), ("data",))) == "flat"
 
     arch = get_arch("qwen3-8b").reduced()
     params = lm.init_params(arch, jax.random.PRNGKey(0))

@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import compressed_psum, ef_compress
+from repro.launch.mesh import make_mesh
 from repro.runtime import (DeadlineSkipper, HealthMonitor, RestartPolicy,
                            StepTimer, elastic_mesh)
 
@@ -192,7 +193,7 @@ def test_step_guard_replay_is_deterministic(tmp_path):
 # ---------------- compression collectives ----------------
 
 def test_compressed_psum_single_axis():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
 
     def f(x):
         y, err = compressed_psum(x, "data")

@@ -39,7 +39,8 @@ def run_subprocess(code: str, timeout: int = 900) -> str:
     env["PYTHONPATH"] = SRC
     env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
+        [sys.executable, "-c",
+         "from repro.launch.mesh import make_mesh\n" + textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env)
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
@@ -62,7 +63,7 @@ def test_wire_trajectory_tracks_fp32_and_no_float_reduce_in_hlo():
 
         arch = get_arch("qwen3-8b").reduced()
         shape = ShapeConfig("wire_eq", "train", 64, 8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
 
         def run(gc):
             plan = specialize(arch, shape, mesh_axes=("data", "model"),
@@ -111,7 +112,9 @@ def test_wire_trajectory_tracks_fp32_and_no_float_reduce_in_hlo():
         # ({{0,4},{1,5},...} literal / [4,2]<=[2,4] iota on this (2,4)
         # mesh) are the gradient wire. "Gradient-sized" = >= 4096
         # elements; the surviving small cross-data floats are shared
-        # quantizer scales and loss/grad-norm scalars.
+        # quantizer scales and loss/grad-norm scalars.  XLA's combiner
+        # may fuse several reduces into one tuple-shaped all-reduce:
+        # each tuple element counts as its own collective.
         b = synthetic_batch(arch, shape, jax.random.PRNGKey(100))
 
         def xdata_counts(tr, state):
@@ -119,15 +122,18 @@ def test_wire_trajectory_tracks_fp32_and_no_float_reduce_in_hlo():
             c = Counter()
             for line in txt.splitlines():
                 m = re.search(
-                    r"= (\\w+)\\[([\\d,]*)\\]\\S* (all-reduce|"
+                    r"= (\\(.*?\\)|\\w+\\[[\\d,]*\\]\\S*) (all-reduce|"
                     r"reduce-scatter)\\(", line)
                 if m is None:
                     continue
-                n = int(np.prod([int(t) for t in m.group(2).split(",")
-                                 if t] or [1]))
-                if ("replica_groups={{0,4}" in line
+                if not ("replica_groups={{0,4}" in line
                         or "replica_groups=[4,2]<=[2,4]" in line):
-                    c[m.group(1), n >= 4096] += 1
+                    continue
+                for dt, dims in re.findall(r"(\\w+)\\[([\\d,]*)\\]",
+                                           m.group(1)):
+                    n = int(np.prod([int(t) for t in dims.split(",")
+                                     if t] or [1]))
+                    c[dt, n >= 4096] += 1
             return c
 
         on = xdata_counts(tr_on, st_on)
@@ -159,7 +165,7 @@ def test_compress_off_is_bit_deterministic():
 
         arch = get_arch("qwen3-8b").reduced()
         shape = ShapeConfig("wire_det", "train", 64, 8)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         plan = specialize(arch, shape, mesh_axes=("data", "model"),
                           mesh_shape=(2, 4), cache=False,
                           grad_compression="off")
