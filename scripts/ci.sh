@@ -18,7 +18,7 @@
 #                            # wire proof)
 #   scripts/ci.sh --chaos    # fault-injection lane: seeded soak of the
 #                            # grow-on-demand serving path (random grant
-#                            # denials + simulated slow ticks) asserting
+#                            # denials) asserting
 #                            # zero token divergence and zero leaked
 #                            # blocks
 #   scripts/ci.sh --prefix   # prefix-reuse lane: seeded session traffic
@@ -82,9 +82,9 @@ summary() {
 }
 
 if [[ "${1:-}" == "--chaos" ]]; then
-    run_lane "chaos: grant-denial + slow-tick soak (seed 0)" \
+    run_lane "chaos: grant-denial soak (seed 0)" \
         python scripts/serve_smoke.py --chaos --seed 0
-    run_lane "chaos: grant-denial + slow-tick soak (seed 1)" \
+    run_lane "chaos: grant-denial soak (seed 1)" \
         python scripts/serve_smoke.py --chaos --seed 1
     summary
 fi

@@ -212,8 +212,9 @@ def attention_decode_paged(
     N, bl = k_pool.shape[0], k_pool.shape[1]
     B, nb = block_tbl.shape
     safe = jnp.clip(block_tbl, 0, N - 1)
-    k = k_pool[safe].reshape(B, nb * bl, *k_pool.shape[2:])
-    v = v_pool[safe].reshape(B, nb * bl, *v_pool.shape[2:])
+    with jax.named_scope("paged_gather"):
+        k = k_pool[safe].reshape(B, nb * bl, *k_pool.shape[2:])
+        v = v_pool[safe].reshape(B, nb * bl, *v_pool.shape[2:])
     return attention_decode(q, k, v, cache_len=cache_len, window=window)
 
 

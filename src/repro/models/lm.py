@@ -671,47 +671,51 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
                             grp["attn"]["wv"], grp["attn"]["wo"],
                             grp["attn"].get("q_norm"), grp["attn"].get("k_norm"))
             Hq = ap.wq.shape[-1] // arch.hd
-            q, k, v = attn_mod.project_qkv(
-                h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd, positions,
-                arch.rope_theta, arch.mrope_sections, arch.norm_eps)
-            if cfg.decode_impl == "shard_map_flash" and cfg.mesh is not None:
-                if block_tbl is not None:
-                    from repro.dist.flash_decode import flash_decode_paged
-                    ctx, kc, vc = flash_decode_paged(
-                        q, k, v, kc, vc, block_tbl, pos, w, mesh=cfg.mesh,
-                        data_axes=cfg.data_axes, model_axis=cfg.model_axis,
-                        combine=cfg.combine_topology)
+            with jax.named_scope("qkv"):
+                q, k, v = attn_mod.project_qkv(
+                    h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd,
+                    positions, arch.rope_theta, arch.mrope_sections,
+                    arch.norm_eps)
+            with jax.named_scope("attention"):
+                if cfg.decode_impl == "shard_map_flash" \
+                        and cfg.mesh is not None:
+                    if block_tbl is not None:
+                        from repro.dist.flash_decode import flash_decode_paged
+                        ctx, kc, vc = flash_decode_paged(
+                            q, k, v, kc, vc, block_tbl, pos, w, mesh=cfg.mesh,
+                            data_axes=cfg.data_axes, model_axis=cfg.model_axis,
+                            combine=cfg.combine_topology)
+                    else:
+                        from repro.dist.flash_decode import flash_decode
+                        ctx, kc, vc = flash_decode(
+                            q, k, v, kc, vc, pos, w, mesh=cfg.mesh,
+                            data_axes=cfg.data_axes, model_axis=cfg.model_axis,
+                            combine=cfg.combine_topology)
                 else:
-                    from repro.dist.flash_decode import flash_decode
-                    ctx, kc, vc = flash_decode(
-                        q, k, v, kc, vc, pos, w, mesh=cfg.mesh,
-                        data_axes=cfg.data_axes, model_axis=cfg.model_axis,
-                        combine=cfg.combine_topology)
-            else:
-                if not cfg.shard_heads:
-                    pass
-                elif cfg.kv_heads_sharded:
-                    q = _hint(q, cfg, None, None, cfg.model_axis, None)
-                    k = _hint(k, cfg, None, None, cfg.model_axis, None)
-                    v = _hint(v, cfg, None, None, cfg.model_axis, None)
-                else:
-                    # match the head_dim-sharded cache: QK^T contracts the
-                    # sharded dim -> psum of the score tensor, and the
-                    # cache append stays local
-                    q = _hint(q, cfg, None, None, "rep", cfg.model_axis)
-                    k = _hint(k, cfg, None, None, "rep", cfg.model_axis)
-                    v = _hint(v, cfg, None, None, "rep", cfg.model_axis)
-                if block_tbl is not None:
-                    kc = append_kv_paged(kc, k, pos, block_tbl)
-                    vc = append_kv_paged(vc, v, pos, block_tbl)
-                    ctx = attn_mod.attention_decode_paged(
-                        q, kc, vc, block_tbl, cache_len=pos + 1, window=w)
-                else:
-                    kc = append_kv(kc, k, pos)
-                    vc = append_kv(vc, v, pos)
-                    ctx = attn_mod.attention_decode(q, kc, vc,
-                                                    cache_len=pos + 1,
-                                                    window=w)
+                    if not cfg.shard_heads:
+                        pass
+                    elif cfg.kv_heads_sharded:
+                        q = _hint(q, cfg, None, None, cfg.model_axis, None)
+                        k = _hint(k, cfg, None, None, cfg.model_axis, None)
+                        v = _hint(v, cfg, None, None, cfg.model_axis, None)
+                    else:
+                        # match the head_dim-sharded cache: QK^T contracts the
+                        # sharded dim -> psum of the score tensor, and the
+                        # cache append stays local
+                        q = _hint(q, cfg, None, None, "rep", cfg.model_axis)
+                        k = _hint(k, cfg, None, None, "rep", cfg.model_axis)
+                        v = _hint(v, cfg, None, None, "rep", cfg.model_axis)
+                    if block_tbl is not None:
+                        kc = append_kv_paged(kc, k, pos, block_tbl)
+                        vc = append_kv_paged(vc, v, pos, block_tbl)
+                        ctx = attn_mod.attention_decode_paged(
+                            q, kc, vc, block_tbl, cache_len=pos + 1, window=w)
+                    else:
+                        kc = append_kv(kc, k, pos)
+                        vc = append_kv(vc, v, pos)
+                        ctx = attn_mod.attention_decode(q, kc, vc,
+                                                        cache_len=pos + 1,
+                                                        window=w)
             out = out + ctx.reshape(B, 1, -1) @ ap.wo
         if arch.has_ssm:
             sp = SSMParams(**grp["ssm"])
@@ -720,7 +724,8 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
             out = out + y
         x = x + out / n_paths
         if arch.family != "ssm" and ("mlp" in grp or "moe" in grp):
-            x, _ = _ffn_fwd(arch, cfg, grp, x)
+            with jax.named_scope("mlp"):
+                x, _ = _ffn_fwd(arch, cfg, grp, x)
         return x, kc, vc, sc, cc
 
     # scan over layers with the FULL stacked cache in the carry: each
@@ -776,8 +781,9 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
     init = (x, jnp.zeros((), jnp.int32), kc_full, vc_full, sc_full, cc_full)
     (x, _, new_k, new_v, new_s, new_c), _ = jax.lax.scan(
         body, init, (blocks, win))
-    x = rms_norm(x, params["final_norm"], arch.norm_eps)
-    logits = _logits(arch, params, x, cfg)
+    with jax.named_scope("logits"):
+        x = rms_norm(x, params["final_norm"], arch.norm_eps)
+        logits = _logits(arch, params, x, cfg)
     new_cache = dict(cache)
     new_cache["pos"] = pos + 1
     if arch.has_attention:

@@ -160,7 +160,7 @@ from repro.configs.base import ArchConfig, get_arch
 from repro.models import lm
 from repro.models.lm import RunCfg
 from repro.runtime.fault import RestartPolicy
-from repro.runtime.straggler import StepTimer
+from repro.runtime.trace import span
 from repro.serve.prefix_cache import PrefixCache, chain_hashes
 
 
@@ -446,9 +446,6 @@ class ServeEngine:
         # when blocks are free (drives the preemption path exactly like
         # a hot sub-pool would; see scripts/serve_smoke.py --chaos)
         self.grant_fault: Optional[Callable[[], bool]] = None
-        # tick-time telemetry (straggler detection at the engine edge)
-        self.tick_timer = StepTimer()
-        self.straggler_ticks = 0
         # per-slot valid lengths; mirrored into cache["pos"] every tick
         # (freed slots stay at 0 — their stale KV is masked out)
         self.slot_len = np.zeros((max_batch,), np.int32)
@@ -474,23 +471,38 @@ class ServeEngine:
         self.disagg_chunks = 0
         self.disagg_resumes = 0
 
-        self._decode = jax.jit(
-            lambda p, c, b: lm.decode_step(arch, p, c, b, cfg))
-        self._prefill = jax.jit(
-            lambda p, b: lm.prefill(arch, p, b, cfg, max_len=max_len))
-        self._prefill_tail = jax.jit(
-            lambda p, b, pk, pv: lm.prefill_tail(arch, p, b, cfg, pk, pv))
-        # CoW: duplicate one pool block's k/v rows and repoint one table
-        # entry, in a single jitted gather-scatter
-        self._cow_kernel = jax.jit(
-            lambda k, v, tbl, old, new, slot, col: (
-                k.at[:, new].set(k[:, old]),
-                v.at[:, new].set(v[:, old]),
-                tbl.at[slot, col].set(new)))
+        # each program is a named function, so that its module in a
+        # profiler trace reads jit_serve_<name>
+        def serve_decode(p, c, b):
+            return lm.decode_step(arch, p, c, b, cfg)
+
+        def serve_prefill(p, b):
+            return lm.prefill(arch, p, b, cfg, max_len=max_len)
+
+        def serve_prefill_tail(p, b, pk, pv):
+            return lm.prefill_tail(arch, p, b, cfg, pk, pv)
+
+        def serve_cow(k, v, tbl, old, new, slot, col):
+            """CoW: duplicate one pool block's k/v rows and repoint one
+            table entry, in a single gather-scatter."""
+            return (k.at[:, new].set(k[:, old]),
+                    v.at[:, new].set(v[:, old]),
+                    tbl.at[slot, col].set(new))
+
         # tier migration: batched whole-block gather/scatter between
         # the device pool and the host spill pool
-        self._gather_blocks = jax.jit(lm.gather_blocks)
-        self._scatter_blocks = jax.jit(lm.scatter_blocks)
+        def serve_gather_blocks(pool, ids):
+            return lm.gather_blocks(pool, ids)
+
+        def serve_scatter_blocks(pool, ids, rows):
+            return lm.scatter_blocks(pool, ids, rows)
+
+        self._decode = jax.jit(serve_decode)
+        self._prefill = jax.jit(serve_prefill)
+        self._prefill_tail = jax.jit(serve_prefill_tail)
+        self._cow_kernel = jax.jit(serve_cow)
+        self._gather_blocks = jax.jit(serve_gather_blocks)
+        self._scatter_blocks = jax.jit(serve_scatter_blocks)
 
     # ------------------------------------------------------------------
     @property
@@ -825,7 +837,6 @@ class ServeEngine:
                 "grant_denials": self.grant_denials,
                 "shed": len(self.shed),
                 "parked": len(self.preempted),
-                "straggler_ticks": self.straggler_ticks,
                 "overloaded": self.overloaded(),
                 "shared_blocks": self._alloc.shared_blocks,
                 "prefix_hits": (self._prefix.hits
@@ -1002,12 +1013,15 @@ class ServeEngine:
         (one batched device→host gather per tensor).  The vacated HBM
         ids are already back on their free lists, but their rows stay
         intact until a next tenant writes — the copy races nothing."""
-        old_ids = jnp.asarray(np.asarray([b for b, _ in pairs], np.int32))
-        idx = np.asarray([h - self.n_blocks for _, h in pairs], np.int64)
-        self._host["k"][:, idx] = np.asarray(
-            self._gather_blocks(self.cache["k"], old_ids))
-        self._host["v"][:, idx] = np.asarray(
-            self._gather_blocks(self.cache["v"], old_ids))
+        with span("serve.spill", blocks=len(pairs)):
+            old_ids = jnp.asarray(np.asarray([b for b, _ in pairs],
+                                             np.int32))
+            idx = np.asarray([h - self.n_blocks for _, h in pairs],
+                             np.int64)
+            self._host["k"][:, idx] = np.asarray(
+                self._gather_blocks(self.cache["k"], old_ids))
+            self._host["v"][:, idx] = np.asarray(
+                self._gather_blocks(self.cache["v"], old_ids))
 
     def _promote_rows(self, pairs: List[Tuple[int, int]],
                       k_rows=None, v_rows=None) -> None:
@@ -1015,15 +1029,18 @@ class ServeEngine:
         pairs' new HBM ids — from the prefetcher's staged device arrays
         when they landed, else a synchronous host→device transfer (the
         stall ``kv_prefetch="off"`` benchmarks)."""
-        idx = np.asarray([h - self.n_blocks for h, _ in pairs], np.int64)
-        new_ids = jnp.asarray(np.asarray([b for _, b in pairs], np.int32))
-        if k_rows is None:
-            k_rows = jnp.asarray(self._host["k"][:, idx])
-            v_rows = jnp.asarray(self._host["v"][:, idx])
-        self.cache["k"] = self._scatter_blocks(self.cache["k"], new_ids,
-                                               k_rows)
-        self.cache["v"] = self._scatter_blocks(self.cache["v"], new_ids,
-                                               v_rows)
+        with span("serve.promote", blocks=len(pairs)):
+            idx = np.asarray([h - self.n_blocks for h, _ in pairs],
+                             np.int64)
+            new_ids = jnp.asarray(np.asarray([b for _, b in pairs],
+                                             np.int32))
+            if k_rows is None:
+                k_rows = jnp.asarray(self._host["k"][:, idx])
+                v_rows = jnp.asarray(self._host["v"][:, idx])
+            self.cache["k"] = self._scatter_blocks(self.cache["k"],
+                                                   new_ids, k_rows)
+            self.cache["v"] = self._scatter_blocks(self.cache["v"],
+                                                   new_ids, v_rows)
 
     def _promote_matched(self, matched: List[int],
                          group: int) -> List[int]:
@@ -1594,100 +1611,109 @@ class ServeEngine:
         if infos is None:
             infos = [None] * len(group)
         m_tok = bucket[0] if bucket else 0
-        toks = np.stack([r.feed_tokens[m_tok:] for r in group])
-        padded = 1
-        while padded < len(group):
-            padded *= 2
-        padded = min(padded, self.max_batch)   # never a batch no engine fills
-        if padded > len(group):
-            toks = np.concatenate(
-                [toks, np.repeat(toks[:1], padded - len(group), axis=0)])
-        cacheg = None
-        if m_tok:
-            # gather the aliased prefix rows (resident pool blocks) as
-            # the tail forward's K/V prefix; dummy batch rows reuse the
-            # first member's blocks (discarded, and read-only anyway)
-            nbm = m_tok // self.block_len
-            blk = np.asarray(matches, np.int32)            # (Bs, nbm)
+        with span("serve.prefill", rids=[r.rid for r in group],
+                  batch=len(group),
+                  tokens=len(group[0].feed_tokens) - m_tok,
+                  aliased=m_tok):
+            toks = np.stack([r.feed_tokens[m_tok:] for r in group])
+            padded = 1
+            while padded < len(group):
+                padded *= 2
+            # never a batch no engine fills
+            padded = min(padded, self.max_batch)
             if padded > len(group):
-                blk = np.concatenate(
-                    [blk, np.repeat(blk[:1], padded - len(group), axis=0)])
-            bid = jnp.asarray(blk.reshape(-1))
-            pk = self.cache["k"][:, bid]
-            pv = self.cache["v"][:, bid]
-            L = pk.shape[0]
-            pk = pk.reshape(L, padded, m_tok, *pk.shape[3:])
-            pv = pv.reshape(L, padded, m_tok, *pv.shape[3:])
-            logits, tail_k, tail_v = self._prefill_tail(
-                self.params, {"tokens": jnp.asarray(toks)}, pk, pv)
-        else:
-            logits, cacheg = self._prefill(self.params,
-                                           {"tokens": jnp.asarray(toks)})
-        self.prefill_calls += 1
-        self.prefill_batches.append(len(group))
-        keys = jax.random.split(self._next_key(), len(group))
-        live: List[Request] = []
-        idxs: List[int] = []
-        live_slots: List[int] = []
-        for i, r in enumerate(group):
-            if r.out_tokens:
-                # resumed after preemption: keep the retained tokens,
-                # keep decoding from where the eviction cut in
-                live.append(r)
-                idxs.append(i)
-                live_slots.append(slots[i])
-                continue
-            tok = self._sample(r, logits[i], keys[i])
-            r.out_tokens.append(int(tok))
-            r.t_first = time.time()
-            if len(r.out_tokens) >= r.max_new_tokens:
-                # the prefill sample already met the budget: finish now —
-                # no decode tick to over-generate on, no cache copy, no
-                # blocks ever allocated, and the reserved slot goes back
-                r.done = True
-                r.t_done = r.t_first
-                self.finished.append(r)
-                self.free_slots.append(slots[i])
+                toks = np.concatenate(
+                    [toks, np.repeat(toks[:1], padded - len(group), axis=0)])
+            cacheg = None
+            if m_tok:
+                # gather the aliased prefix rows (resident pool blocks) as
+                # the tail forward's K/V prefix; dummy batch rows reuse the
+                # first member's blocks (discarded, and read-only anyway)
+                nbm = m_tok // self.block_len
+                blk = np.asarray(matches, np.int32)            # (Bs, nbm)
+                if padded > len(group):
+                    blk = np.concatenate(
+                        [blk, np.repeat(blk[:1], padded - len(group), axis=0)])
+                bid = jnp.asarray(blk.reshape(-1))
+                pk = self.cache["k"][:, bid]
+                pv = self.cache["v"][:, bid]
+                L = pk.shape[0]
+                pk = pk.reshape(L, padded, m_tok, *pk.shape[3:])
+                pv = pv.reshape(L, padded, m_tok, *pv.shape[3:])
+                logits, tail_k, tail_v = self._prefill_tail(
+                    self.params, {"tokens": jnp.asarray(toks)}, pk, pv)
             else:
-                live.append(r)
-                idxs.append(i)
-                live_slots.append(slots[i])
-        if not live:
-            return
-        plen = len(live[0].feed_tokens)
-        slots = np.asarray(live_slots, np.int32)
-        gidx = np.asarray(idxs, np.int32)
-        live_matches = [matches[i] for i in idxs]
-        if self.arch.has_attention:
-            if self.kv_residency == "paged":
-                if m_tok:
-                    self._scatter_tail(live, slots, gidx, tail_k, tail_v,
-                                       plen, m_tok, live_matches)
+                logits, cacheg = self._prefill(self.params,
+                                               {"tokens": jnp.asarray(toks)})
+            self.prefill_calls += 1
+            self.prefill_batches.append(len(group))
+            live: List[Request] = []
+            idxs: List[int] = []
+            live_slots: List[int] = []
+            # the first sample also waits for the prefill dispatched above
+            with span("serve.sample", slots=len(group),
+                      rids=[r.rid for r in group]):
+                keys = jax.random.split(self._next_key(), len(group))
+                for i, r in enumerate(group):
+                    if r.out_tokens:
+                        # resumed after preemption: keep the retained tokens,
+                        # keep decoding from where the eviction cut in
+                        live.append(r)
+                        idxs.append(i)
+                        live_slots.append(slots[i])
+                        continue
+                    tok = self._sample(r, logits[i], keys[i])
+                    r.out_tokens.append(int(tok))
+                    r.t_first = time.time()
+                    if len(r.out_tokens) >= r.max_new_tokens:
+                        # the prefill sample already met the budget: finish
+                        # now — no decode tick to over-generate on, no cache
+                        # copy, no blocks ever allocated, and the reserved
+                        # slot goes back
+                        r.done = True
+                        r.t_done = r.t_first
+                        self.finished.append(r)
+                        self.free_slots.append(slots[i])
+                    else:
+                        live.append(r)
+                        idxs.append(i)
+                        live_slots.append(slots[i])
+            if not live:
+                return
+            plen = len(live[0].feed_tokens)
+            slots = np.asarray(live_slots, np.int32)
+            gidx = np.asarray(idxs, np.int32)
+            live_matches = [matches[i] for i in idxs]
+            if self.arch.has_attention:
+                if self.kv_residency == "paged":
+                    if m_tok:
+                        self._scatter_tail(live, slots, gidx, tail_k, tail_v,
+                                           plen, m_tok, live_matches)
+                    else:
+                        self._scatter_paged_prefill(live, slots, gidx, cacheg,
+                                                    plen, live_matches)
                 else:
-                    self._scatter_paged_prefill(live, slots, gidx, cacheg,
-                                                plen, live_matches)
-            else:
-                for key in ("k", "v"):
+                    for key in ("k", "v"):
+                        self.cache[key] = self.cache[key].at[:, slots].set(
+                            cacheg[key][:, gidx])
+            for key in ("ssm", "conv"):
+                if key in self.cache:
                     self.cache[key] = self.cache[key].at[:, slots].set(
                         cacheg[key][:, gidx])
-        for key in ("ssm", "conv"):
-            if key in self.cache:
-                self.cache[key] = self.cache[key].at[:, slots].set(
-                    cacheg[key][:, gidx])
-        for slot, r in zip(slots, live):
-            r.slot = int(slot)
-            self.slot_len[slot] = plen
-            self.active[int(slot)] = r
-        for i, r in enumerate(live):
-            g = self._slot_group(int(slots[i]))
-            self._register_prefix(r, infos[idxs[i]], g)
-            if self._prefix is not None:
-                mt = live_matches[i]
-                if mt:
-                    self._prefix.hits += 1
-                    self._prefix.hit_tokens += len(mt) * self.block_len
-                else:
-                    self._prefix.misses += 1
+            for slot, r in zip(slots, live):
+                r.slot = int(slot)
+                self.slot_len[slot] = plen
+                self.active[int(slot)] = r
+            for i, r in enumerate(live):
+                g = self._slot_group(int(slots[i]))
+                self._register_prefix(r, infos[idxs[i]], g)
+                if self._prefix is not None:
+                    mt = live_matches[i]
+                    if mt:
+                        self._prefix.hits += 1
+                        self._prefix.hit_tokens += len(mt) * self.block_len
+                    else:
+                        self._prefix.misses += 1
 
     def _scatter_paged_prefill(self, live: List[Request], slots: np.ndarray,
                                gidx: np.ndarray, cacheg, plen: int,
@@ -1875,14 +1901,15 @@ class ServeEngine:
         reference to the original — the sharers keep it resident, trie
         entry and all."""
         old = r.blocks[col]
-        k, v, tbl = self._cow_kernel(
-            self.cache["k"], self.cache["v"], self.cache["block_tbl"],
-            np.int32(old), np.int32(fresh), np.int32(r.slot),
-            np.int32(col))
-        self.cache["k"], self.cache["v"] = k, v
-        self.cache["block_tbl"] = tbl
-        r.blocks[col] = fresh
-        self._release_blocks([old])
+        with span("serve.cow", rid=r.rid):
+            k, v, tbl = self._cow_kernel(
+                self.cache["k"], self.cache["v"], self.cache["block_tbl"],
+                np.int32(old), np.int32(fresh), np.int32(r.slot),
+                np.int32(col))
+            self.cache["k"], self.cache["v"] = k, v
+            self.cache["block_tbl"] = tbl
+            r.blocks[col] = fresh
+            self._release_blocks([old])
         self.cow_copies += 1
 
     def _try_migrate(self, r: Request) -> bool:
@@ -2223,9 +2250,10 @@ class ServeEngine:
             if got is not None and got[0] == ids:
                 continue
             idx = np.asarray([h - self.n_blocks for h in ids], np.int64)
-            self._staged[rid] = (ids,
-                                 jax.device_put(self._host["k"][:, idx]),
-                                 jax.device_put(self._host["v"][:, idx]))
+            with span("serve.promote", blocks=len(ids), rid=rid):
+                self._staged[rid] = (
+                    ids, jax.device_put(self._host["k"][:, idx]),
+                    jax.device_put(self._host["v"][:, idx]))
 
     # ------------------------------------------------------------------
     def _next_key(self) -> jax.Array:
@@ -2250,69 +2278,72 @@ class ServeEngine:
     def step(self) -> int:
         """One engine tick: shed expired, re-admit parked evictions,
         admit, secure grants, decode one token for all active slots."""
-        t0 = time.perf_counter()
         self.tick += 1
-        if self.kv_residency == "paged" and \
-                self.tick % self.preemption.shed_window_ticks == 0:
-            # new low-water epoch once per rebalance window: without the
-            # reset the watermark only ever ratchets down, so one
-            # transient dip reads as a permanently hot sub-pool forever
-            self._alloc.reset_low_water()
-        self._poll_disagg()
-        self._shed_expired_pending()
-        self._shed_expired_flights()
-        self._readmit_preempted()
-        self._admit()
-        self._ensure_grants()
-        self._ensure_writable()
-        # stage next tick's resume transfers before dispatching this
-        # tick's decode: the async device_put streams in underneath it
-        self._stage_prefetch()
-        if not self.active:
-            if self._disagg:
-                # only flights in play: workers are computing off-process;
-                # don't spin the tick counter at memory speed waiting
-                time.sleep(0.01)
-            self._observe_tick(t0)
-            return 0
-        # per-slot positions: every slot decodes at its own offset.  Freed
-        # slots are masked to (token 0, pos 0): their decode is a bounded
-        # dummy over one cache row, so stale KV / stale last-token garbage
-        # never reaches a live slot's logits.
-        self._sync_pos()
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        for slot, r in self.active.items():
-            # a ride-admitted request has no output yet: its first tick
-            # feeds the last prompt token (the one admission left
-            # unaliased) and samples the first output
-            tokens[slot, 0] = (r.out_tokens[-1] if r.out_tokens
-                               else int(r.feed_tokens[-1]))
-        logits, self.cache = self._decode(self.params, self.cache,
-                                          {"tokens": jnp.asarray(tokens)})
-        slot_keys = jax.random.split(self._next_key(), self.max_batch)
-        finished = []
-        for slot, r in list(self.active.items()):
-            tok = self._sample(r, logits[slot], slot_keys[slot])
-            r.out_tokens.append(int(tok))
-            if r.t_first == 0.0:       # first token via decode-ride
-                r.t_first = time.time()
-            self.slot_len[slot] += 1
-            if len(r.out_tokens) >= r.max_new_tokens:
-                r.done = True
-                r.t_done = time.time()
-                finished.append(r)
-                self.finished.append(r)
-                del self.active[slot]
-                self._release_slot(slot, r)
-                self._backoff.pop(r.rid, None)
-        self._observe_tick(t0)
-        return len(finished)
-
-    def _observe_tick(self, t0: float) -> None:
-        dt = time.perf_counter() - t0
-        if self.tick_timer.is_straggler(dt):
-            self.straggler_ticks += 1
-        self.tick_timer.observe(dt)
+        with span("serve.step", tick=self.tick):
+            if self.kv_residency == "paged" and \
+                    self.tick % self.preemption.shed_window_ticks == 0:
+                # new low-water epoch once per rebalance window: without
+                # the reset the watermark only ever ratchets down, so one
+                # transient dip reads as a permanently hot sub-pool forever
+                self._alloc.reset_low_water()
+            self._poll_disagg()
+            self._shed_expired_pending()
+            self._shed_expired_flights()
+            with span("serve.readmit"):
+                self._readmit_preempted()
+            with span("serve.admit"):
+                self._admit()
+            with span("serve.grant"):
+                self._ensure_grants()
+                self._ensure_writable()
+            # stage next tick's resume transfers before dispatching this
+            # tick's decode: the async device_put streams in underneath it
+            self._stage_prefetch()
+            if not self.active:
+                if self._disagg:
+                    # only flights in play: workers are computing
+                    # off-process; don't spin the tick counter at memory
+                    # speed waiting
+                    time.sleep(0.01)
+                return 0
+            with span("serve.decode", slots=len(self.active)):
+                # per-slot positions: every slot decodes at its own
+                # offset.  Freed slots are masked to (token 0, pos 0):
+                # their decode is a bounded dummy over one cache row, so
+                # stale KV / stale last-token garbage never reaches a
+                # live slot's logits.
+                self._sync_pos()
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                for slot, r in self.active.items():
+                    # a ride-admitted request has no output yet: its
+                    # first tick feeds the last prompt token (the one
+                    # admission left unaliased) and samples the first
+                    # output
+                    tokens[slot, 0] = (r.out_tokens[-1] if r.out_tokens
+                                       else int(r.feed_tokens[-1]))
+                logits, self.cache = self._decode(
+                    self.params, self.cache, {"tokens": jnp.asarray(tokens)})
+            # the first sample also waits for the decode dispatched above
+            with span("serve.sample", slots=len(self.active),
+                      rids=[r.rid for r in self.active.values()]):
+                slot_keys = jax.random.split(self._next_key(),
+                                             self.max_batch)
+                finished = []
+                for slot, r in list(self.active.items()):
+                    tok = self._sample(r, logits[slot], slot_keys[slot])
+                    r.out_tokens.append(int(tok))
+                    if r.t_first == 0.0:       # first token via decode-ride
+                        r.t_first = time.time()
+                    self.slot_len[slot] += 1
+                    if len(r.out_tokens) >= r.max_new_tokens:
+                        r.done = True
+                        r.t_done = time.time()
+                        finished.append(r)
+                        self.finished.append(r)
+                        del self.active[slot]
+                        self._release_slot(slot, r)
+                        self._backoff.pop(r.rid, None)
+            return len(finished)
 
     def _release_slot(self, slot: int, r: Request) -> None:
         """Return the slot — and, when paged, its blocks — to the pool.
