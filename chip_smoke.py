@@ -16,6 +16,8 @@ One chip:
   finished with the requested tokens, every id is in the vocabulary,
   every sampled logits row is finite, and each prompt length's first
   logits agree with a dense one-request ``lm.prefill`` of that prompt.
+  The probe samples row by row; the same prompts through a plain engine,
+  which samples every row of a tick in one program, give its tokens.
 * train — hymba-1.5b cut in depth to 12 of 32 layers (global layers 0
   and 11, windowed 1-10): ``Trainer.fit`` for 3 steps at seq 4096.
   Checks: finite losses, step-0 loss near ln(vocab).
@@ -152,9 +154,9 @@ def phase_serve(args, jax, meter, dev):
     prompts = make_prompts(arch.vocab_size, lens, args.seed)
     meter.reset()
     t0 = time.perf_counter()
+    mesh = make_mesh((1, 1), ("data", "model"), devices=[dev])
     out = serve(arch, prompts, new_tokens=NEW_TOKENS, max_batch=SERVE_BATCH,
-                max_len=SERVE_LEN, seed=args.seed,
-                mesh=make_mesh((1, 1), ("data", "model"), devices=[dev]),
+                max_len=SERVE_LEN, seed=args.seed, mesh=mesh,
                 engine_cls=probe_engine(ServeEngine, np, jnp))
     wall = time.perf_counter() - t0
     log(f"[serve] {' '.join(f'{k}={v}' for k, v in out.summary().items())}"
@@ -190,7 +192,24 @@ def phase_serve(args, jax, meter, dev):
         f"max relative L2 {rel:.2e} over {len(seen)} prompt lengths")
     log(f"[serve] {meter} peak_bytes_in_use {peaks(jax, [dev])} "
         f"smoke_wall_s={wall:.1f} (smoke time, not a benchmark)")
+    # the plain engine shares the weights; the probe's pool goes first
+    want = {r.rid: r.out_tokens for r in out.done}
+    plan, params = out.plan, out.engine.params
     out.engine.shutdown()
+    del out
+    gc.collect()
+    plain = ServeEngine.from_plan(plan, params, arch=arch, mesh=mesh,
+                                  seed=args.seed)
+    for p in prompts:
+        plain.submit(p, max_new_tokens=NEW_TOKENS)
+    got = {r.rid: r.out_tokens for r in plain.run_until_idle(1000)}
+    check(got == want, ("batched sampling differs from the probe's",
+                        got, want))
+    ps = plain.pressure_stats()
+    check((ps["sampled_batched"], ps["sampled_per_slot"]) == (toks, 0), ps)
+    log(f"[serve] ok: plain engine, {toks} tokens sampled in one program "
+        "a tick, identical to the probe's")
+    plain.shutdown()
 
 
 def phase_train(args, jax, meter, dev):

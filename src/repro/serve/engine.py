@@ -15,7 +15,10 @@ whose length differs from the batch max.  Freed slots are masked to
 ``(token 0, pos 0)`` so their stale KV never flows into a live decode.
 Greedy or temperature sampling; sampling threads one engine PRNG key
 (``seed=``), split per tick and per slot, so runs are reproducible and
-slots never share a key within a tick.
+slots never share a key within a tick.  A tick samples every slot in
+one program (``serve_sample``) and one transfer of the tokens to the
+host; an engine whose ``_sample`` is overridden (a probe) samples row
+by row through it instead.
 
 KV residency is a plan decision (``kv_residency`` in the artifact):
 ``dense`` keeps the classic per-slot ``max_len`` stripes; ``paged``
@@ -470,6 +473,9 @@ class ServeEngine:
         self.disagg_dispatches = 0
         self.disagg_chunks = 0
         self.disagg_resumes = 0
+        # rows drawn by the batched program / by per-row ``_sample``
+        self.sampled_batched = 0
+        self.sampled_per_slot = 0
 
         # each program is a named function, so that its module in a
         # profiler trace reads jit_serve_<name>
@@ -497,12 +503,27 @@ class ServeEngine:
         def serve_scatter_blocks(pool, ids, rows):
             return lm.scatter_blocks(pool, ids, rows)
 
+        def serve_sample(logits, temps, keys, sampled):
+            """Every row's next token by ``_sample``'s rule: argmax where
+            the row's temperature is <= 0, a categorical draw with its
+            key where it is > 0.  ``sampled`` (static) is False when no
+            row draws, so a greedy batch pays for no Gumbel noise."""
+            rows = logits[:, :arch.vocab_size].astype(jnp.float32)
+            greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+            if not sampled:
+                return greedy
+            hot = temps > 0
+            drawn = jax.vmap(jax.random.categorical)(
+                keys, rows / jnp.where(hot, temps, 1.0)[:, None])
+            return jnp.where(hot, drawn.astype(jnp.int32), greedy)
+
         self._decode = jax.jit(serve_decode)
         self._prefill = jax.jit(serve_prefill)
         self._prefill_tail = jax.jit(serve_prefill_tail)
         self._cow_kernel = jax.jit(serve_cow)
         self._gather_blocks = jax.jit(serve_gather_blocks)
         self._scatter_blocks = jax.jit(serve_scatter_blocks)
+        self._sample_rows = jax.jit(serve_sample, static_argnames="sampled")
 
     # ------------------------------------------------------------------
     @property
@@ -856,7 +877,9 @@ class ServeEngine:
                 "disagg_dispatches": self.disagg_dispatches,
                 "disagg_chunks": self.disagg_chunks,
                 "disagg_resumes": self.disagg_resumes,
-                "disagg_inflight": len(self._disagg)}
+                "disagg_inflight": len(self._disagg),
+                "sampled_batched": self.sampled_batched,
+                "sampled_per_slot": self.sampled_per_slot}
 
     def telemetry(self) -> Dict[str, Any]:
         """One JSON-serializable snapshot of everything the engine
@@ -1347,6 +1370,7 @@ class ServeEngine:
             self.cache["block_tbl"] = \
                 self.cache["block_tbl"].at[slot].set(jnp.asarray(rows))
         if not r.out_tokens:
+            self.sampled_per_slot += 1
             tok = self._sample(r, jnp.asarray(np.asarray(logits)),
                                self._next_key())
             r.out_tokens.append(int(tok))
@@ -1651,9 +1675,14 @@ class ServeEngine:
             idxs: List[int] = []
             live_slots: List[int] = []
             # the first sample also waits for the prefill dispatched above
+            batched = self._samples_batched()
             with span("serve.sample", slots=len(group),
-                      rids=[r.rid for r in group]):
+                      rids=[r.rid for r in group], batched=batched):
                 keys = jax.random.split(self._next_key(), len(group))
+                # a resumed request's row, like a dummy row, is not drawn
+                toks = self._draw(logits, keys, {
+                    i: r for i, r in enumerate(group) if not r.out_tokens},
+                    batched)
                 for i, r in enumerate(group):
                     if r.out_tokens:
                         # resumed after preemption: keep the retained tokens,
@@ -1662,8 +1691,7 @@ class ServeEngine:
                         idxs.append(i)
                         live_slots.append(slots[i])
                         continue
-                    tok = self._sample(r, logits[i], keys[i])
-                    r.out_tokens.append(int(tok))
+                    r.out_tokens.append(toks[i])
                     r.t_first = time.time()
                     if len(r.out_tokens) >= r.max_new_tokens:
                         # the prefill sample already met the budget: finish
@@ -2261,12 +2289,48 @@ class ServeEngine:
         return sub
 
     def _sample(self, r: Request, logits: jax.Array, key: jax.Array) -> int:
-        """Request ``r``'s next token from its logits row (the one place
-        every emitted token is drawn; probes subclass it)."""
+        """Request ``r``'s next token from its logits row.  ``serve_sample``
+        applies the same rule to every row at once; probes override this
+        hook, and an engine whose hook is not this function calls it for
+        every emitted token."""
         logits = logits[:self.arch.vocab_size].astype(jnp.float32)
         if r.temperature <= 0:
             return int(jnp.argmax(logits))
         return int(jax.random.categorical(key, logits / r.temperature))
+
+    _own_sample = _sample
+
+    def _samples_batched(self) -> bool:
+        """True when ``_sample`` is the engine's own rule, which
+        ``serve_sample`` batches: an override on a subclass, on the
+        class or on the instance needs every row on the host."""
+        return (getattr(self._sample, "__func__", None)
+                is ServeEngine._own_sample)
+
+    def _draw(self, logits: jax.Array, keys: jax.Array,
+              rows: Dict[int, Request], batched: bool) -> Dict[int, int]:
+        """The next token of each request in ``rows`` (logits row index
+        -> request), with ``keys[i]`` for row ``i``.  Batched: one
+        ``serve_sample`` over every row of ``logits`` (rows not in
+        ``rows`` are drawn greedily and discarded) and one transfer;
+        otherwise ``_sample`` row by row, in ``rows``' order."""
+        if not rows:
+            return {}
+        if not batched:
+            self.sampled_per_slot += len(rows)
+            return {i: int(self._sample(r, logits[i], keys[i]))
+                    for i, r in rows.items()}
+        temps = np.zeros((logits.shape[0],), np.float32)
+        for i, r in rows.items():
+            temps[i] = r.temperature
+        if keys.shape[0] < logits.shape[0]:
+            # a padded prefill batch: dummy rows need a key of any value
+            keys = jnp.pad(keys, ((0, logits.shape[0] - keys.shape[0]),
+                                  (0, 0)))
+        toks = np.asarray(self._sample_rows(
+            logits, temps, keys, sampled=bool((temps > 0).any())))
+        self.sampled_batched += len(rows)
+        return {i: int(toks[i]) for i in rows}
 
     def _sync_pos(self) -> None:
         """Mirror per-slot lengths into the device cache (freed slots 0)."""
@@ -2324,14 +2388,17 @@ class ServeEngine:
                 logits, self.cache = self._decode(
                     self.params, self.cache, {"tokens": jnp.asarray(tokens)})
             # the first sample also waits for the decode dispatched above
+            batched = self._samples_batched()
             with span("serve.sample", slots=len(self.active),
-                      rids=[r.rid for r in self.active.values()]):
+                      rids=[r.rid for r in self.active.values()],
+                      batched=batched):
                 slot_keys = jax.random.split(self._next_key(),
                                              self.max_batch)
+                toks = self._draw(logits, slot_keys, dict(self.active),
+                                  batched)
                 finished = []
                 for slot, r in list(self.active.items()):
-                    tok = self._sample(r, logits[slot], slot_keys[slot])
-                    r.out_tokens.append(int(tok))
+                    r.out_tokens.append(toks[slot])
                     if r.t_first == 0.0:       # first token via decode-ride
                         r.t_first = time.time()
                     self.slot_len[slot] += 1

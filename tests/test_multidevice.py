@@ -539,6 +539,63 @@ def test_serve_paged_2d_token_identity_vs_dense_sequential(name):
     """, timeout=900)
 
 
+def test_serve_batched_sampling_on_sharded_logits_matches_probe():
+    """On the 2x4 mesh the decode and prefill logits arrive sharded over
+    data x model.  The engine's one-program sampling of every row gives
+    the tokens a probe engine (per-row ``_sample``) gives for the same
+    staggered schedule, greedy and sampled requests mixed."""
+    run_subprocess("""
+        import dataclasses, jax, numpy as np
+        from repro.configs import ShapeConfig, get_arch
+        from repro.core.pipeline import specialize
+        from repro.models import lm
+        from repro.serve.engine import ServeEngine
+
+        class Probe(ServeEngine):
+            def _sample(self, r, logits, key):
+                return super()._sample(r, logits, key)
+
+        arch = dataclasses.replace(get_arch("qwen3-8b").reduced(),
+                                   n_kv_heads=1)
+        shape = ShapeConfig("serve_2d", "decode", 64, 4)
+        plan = specialize(arch, shape, mesh_axes=("data", "model"),
+                          mesh_shape=(2, 4), cache=False)
+        mesh = make_mesh((2, 4), ("data", "model"))
+        params = lm.init_params(arch, jax.random.PRNGKey(0),
+                                *plan.padded_sizes())
+
+        def serve(cls):
+            eng = cls.from_plan(plan, params, arch=arch, mesh=mesh)
+            assert eng.decode_path == "shard_map_flash_paged_2d"
+            specs = []
+            run = eng._sample_rows
+
+            def record(logits, *a, **kw):
+                specs.append(logits.sharding.spec)
+                return run(logits, *a, **kw)
+
+            eng._sample_rows = record
+            for i in range(5):
+                p = (np.arange(5 + 2 * i, dtype=np.int32) * (i + 1)
+                     % arch.vocab_size)
+                eng.submit(p, max_new_tokens=5, temperature=0.7 * (i % 2))
+                eng.step()
+            done = eng.run_until_idle(max_ticks=64)
+            assert len(done) == 5
+            return ({r.rid: r.out_tokens for r in done},
+                    eng.pressure_stats(), specs)
+
+        got, stats, specs = serve(ServeEngine)
+        want, pstats, pspecs = serve(Probe)
+        assert got == want, (got, want)
+        assert stats["sampled_batched"] == 25, stats
+        assert stats["sampled_per_slot"] == 0, stats
+        assert (pstats["sampled_per_slot"], pspecs) == (25, [])
+        assert any("model" in str(s) for s in specs), specs
+        print("OK")
+    """, timeout=600)
+
+
 def test_moe_shard_map_matches_gshard_on_mesh():
     run_subprocess("""
         import jax, jax.numpy as jnp

@@ -5,7 +5,8 @@ that takes every engine path the spans mark: prefill and tail prefill,
 a prefix hit on a block the pool's pressure spilled to the host tier
 (spill inside an admission, promote inside the next), a copy-on-write,
 decode and sampling.  Traced, every ``serve.*`` span appears inside a
-``serve.step``, the programs carry their ``jit_serve_*`` names, and the
+``serve.step``, the programs carry their ``jit_serve_*`` names (sampling
+is one ``jit_serve_sample`` program, and its span says so), and the
 served tokens equal those of the same schedule untraced.
 """
 
@@ -77,24 +78,26 @@ def traced(tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     path = sorted(glob.glob(str(d / "plugins/profile/*/*.xplane.pb")))[-1]
-    spans, modules = [], set()
+    spans, modules, sample_args = [], set(), []
     for plane in ProfileData.from_file(path).planes:
         for line in plane.lines:
             for ev in line.events:
                 if ev.name.startswith("serve."):
                     s = ev.start_ns
                     spans.append((ev.name, s, s + ev.duration_ns))
+                if ev.name == "serve.sample":
+                    sample_args.append(dict(ev.stats))
                 modules.update(v for k, v in ev.stats if k == "hlo_module")
-    return plain, served, spans, modules
+    return plain, served, spans, modules, sample_args
 
 
 def test_every_engine_span_is_traced(traced):
-    _, _, spans, _ = traced
+    _, _, spans, _, _ = traced
     assert {n for n, _, _ in spans} == SPANS
 
 
 def test_spans_nest_in_a_tick(traced):
-    _, _, spans, _ = traced
+    _, _, spans, _, _ = traced
     ticks = [(s, e) for n, s, e in spans if n == "serve.step"]
     for n, s, e in spans:
         if n != "serve.step":
@@ -102,8 +105,8 @@ def test_spans_nest_in_a_tick(traced):
 
 
 def test_programs_carry_stable_names(traced):
-    _, _, _, modules = traced
-    assert "jit_serve_decode" in modules
+    _, _, _, modules, _ = traced
+    assert {"jit_serve_decode", "jit_serve_sample"} <= modules
     assert {"jit_serve_prefill", "jit_serve_prefill_tail"} <= modules
     assert {"jit_serve_cow", "jit_serve_gather_blocks",
             "jit_serve_scatter_blocks"} <= modules
@@ -111,5 +114,10 @@ def test_programs_carry_stable_names(traced):
 
 
 def test_tracing_leaves_served_tokens_unchanged(traced):
-    plain, served, _, _ = traced
+    plain, served, _, _, _ = traced
     assert served == plain and len(served) == 4
+
+
+def test_sample_span_says_the_rows_were_batched(traced):
+    _, _, _, _, sample_args = traced
+    assert sample_args and all(a.get("batched") == 1 for a in sample_args)
