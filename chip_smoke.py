@@ -10,9 +10,12 @@ published widths with seeded random weights.
 
 One chip:
 
-* serve — qwen3-8b cut in depth to 16 of 36 layers: specialize a decode
-  shape, ``ServeEngine.from_plan`` on a one-device mesh, 8 requests of
-  mixed prompt lengths, ``run_until_idle``.  Checks: every request
+* serve — qwen3-8b cut in depth to 16 of 36 layers, then
+  deepseek-v2-lite whole with 8 of each MoE layer's 64 routed experts
+  held (one chip's share under expert parallelism 8; latent attention
+  over a latent paged cache): specialize a decode shape,
+  ``ServeEngine.from_plan`` on a one-device mesh, 8 requests of mixed
+  prompt lengths, ``run_until_idle``.  Checks: every request
   finished with the requested tokens, every id is in the vocabulary,
   every sampled logits row is finite, and each prompt length's first
   logits agree with a dense one-request ``lm.prefill`` of that prompt.
@@ -57,6 +60,8 @@ HERE = Path(__file__).resolve().parent
 SERVE_ARCH, SERVE_LAYERS = "qwen3-8b", 16
 # 16 layers: weights 8.07 GiB + the plan's 64-block pool 2.00 GiB; the
 # decode step's compiled footprint (memory_analysis, v5e) is 12.3 GiB
+# deepseek-v2-lite, 27 layers, 8 of 64 experts held: weights 6.2 GB
+MLA_ARCH, MLA_HELD = "deepseek-v2-lite", 8
 SERVE_BATCH, SERVE_LEN, NEW_TOKENS = 8, 4096, 16
 PROMPT_LENS = (96, 480, 1200)      # three prefill buckets
 TRAIN_ARCH, TRAIN_LAYERS, TRAIN_SEQ = "hymba-1.5b", 12, 4096
@@ -138,16 +143,25 @@ def check_served(out, n_req, vocab, np):
         check(all(np.isfinite(x).all() for x in rows), f"rid {r.rid}")
 
 
-def phase_serve(args, jax, meter, dev):
+def serve_archs():
+    """The one-chip serving configurations and what each changes from
+    the published model."""
+    import dataclasses
+    from repro.configs import get_arch, run_arch
+    yield run_arch(SERVE_ARCH, layers=SERVE_LAYERS)
+    src = get_arch(MLA_ARCH)
+    yield (dataclasses.replace(src, n_routed_experts=MLA_HELD),
+           {"n_routed_experts": (src.n_experts, MLA_HELD)})
+
+
+def phase_serve(args, jax, meter, dev, arch, changed):
     import jax.numpy as jnp
     import numpy as np
-    from repro.configs import run_arch
     from repro.launch.mesh import make_mesh
     from repro.launch.serve import make_prompts, serve
     from repro.models import lm
     from repro.serve.engine import ServeEngine
 
-    arch, changed = run_arch(SERVE_ARCH, layers=SERVE_LAYERS)
     log(f"[serve] arch {arch.name} changed from published: {changed}")
     rng = np.random.default_rng(args.seed)
     lens = rng.choice(PROMPT_LENS, SERVE_BATCH)
@@ -370,8 +384,9 @@ def main() -> int:
         f"{plan_target()}, compile cache {enable_compile_cache()}")
     meter = CompileMeter(jax)
     if args.chips == 1:
-        phase_serve(args, jax, meter, devs[0])
-        gc.collect()
+        for arch, changed in serve_archs():
+            phase_serve(args, jax, meter, devs[0], arch, changed)
+            gc.collect()
         phase_train(args, jax, meter, devs[0])
     else:
         devs = devs[:4]

@@ -45,6 +45,26 @@ class ArchConfig:
     moe_interleave: int = 1      # MoE every k-th layer (llama4: 2)
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    first_dense_layers: int = 0  # deepseek: leading dense layers (d_ff)
+    # routed experts whose weights this configuration holds, numbered
+    # 0..n-1 of the router's n_experts (0 -> all): one chip's share
+    # under expert parallelism; the rest of the routed sum is left out
+    n_routed_experts: int = 0
+
+    # latent attention (MLA, deepseek-v2): kv_lora_rank > 0 caches one
+    # row of kv_lora_rank + qk_rope_head_dim values a token and layer
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling (arXiv:2309.00071, as deepseek-v2 reads it);
+    # yarn_factor 0 is plain rope
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM (mamba2)
     ssm_state: int = 0
@@ -86,11 +106,50 @@ class ArchConfig:
         return self.n_experts > 0
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights are held (experts 0..n-1)."""
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_row_width(self) -> int:
+        """Cached values a token and layer: the latent row under MLA."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def cache_bytes_per_token(self, dtype_bytes: int = 2) -> int:
+        """Bytes the attention cache holds for one token, every layer:
+        one latent row under MLA, else K and V of every kv head."""
+        if not self.has_attention:
+            return 0
+        row = (self.kv_row_width if self.is_mla
+               else 2 * self.n_kv_heads * self.hd)
+        return self.n_layers * row * dtype_bytes
+
+    def attn_param_count(self) -> int:
+        """Attention weights of one layer."""
+        d, H = self.d_model, self.n_heads
+        if self.is_mla:
+            nope, rope = self.qk_nope_head_dim, self.qk_rope_head_dim
+            r, dv = self.kv_lora_rank, self.v_head_dim
+            return (d * H * (nope + rope) + d * (r + rope) + r
+                    + r * H * (nope + dv) + H * dv * d)
+        return d * H * self.hd * 2 + 2 * d * self.n_kv_heads * self.hd
+
+    @property
     def sub_quadratic(self) -> bool:
         """Can this arch handle 500k-token context (decode) sanely?"""
         if self.family in ("ssm", "hybrid"):
             return True
         return self.window > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        if not self.is_moe:
+            return 0
+        return (self.n_layers - self.first_dense_layers) // self.moe_interleave
 
     # --- parameter counts (analytical; cross-checked in tests) ---------
     def param_count(self) -> int:
@@ -100,10 +159,7 @@ class ArchConfig:
         n += self.vocab_size * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
         if self.has_attention:
-            q = d * self.n_heads * self.hd
-            kv = 2 * d * self.n_kv_heads * self.hd
-            o = self.n_heads * self.hd * d
-            per_layer += q + kv + o
+            per_layer += self.attn_param_count()
         if self.has_ssm:
             di, g, s = self.d_inner, self.ssm_n_groups, self.ssm_state
             in_proj = d * (2 * di + 2 * g * s + self.ssm_heads)
@@ -114,9 +170,9 @@ class ArchConfig:
         # FFN: dense layers + MoE layers
         if self.is_moe:
             moe_ff = self.moe_d_ff or self.d_ff
-            n_moe_layers = L // self.moe_interleave
+            n_moe_layers = self.n_moe_layers
             n_dense_layers = L - n_moe_layers
-            n += n_moe_layers * self.n_experts * 3 * d * moe_ff
+            n += n_moe_layers * self.experts_held * 3 * d * moe_ff
             n += n_moe_layers * self.n_shared_experts * 3 * d * moe_ff
             n += n_moe_layers * d * self.n_experts          # router
             n += n_dense_layers * 3 * d * self.d_ff
@@ -134,8 +190,9 @@ class ArchConfig:
             return self.param_count()
         full = self.param_count()
         moe_ff = self.moe_d_ff or self.d_ff
-        n_moe_layers = self.n_layers // self.moe_interleave
-        all_experts = n_moe_layers * self.n_experts * 3 * self.d_model * moe_ff
+        n_moe_layers = self.n_moe_layers
+        all_experts = (n_moe_layers * self.experts_held * 3 * self.d_model
+                       * moe_ff)
         active = n_moe_layers * self.experts_per_token * 3 * self.d_model * moe_ff
         return full - all_experts + active
 
@@ -148,9 +205,15 @@ class ArchConfig:
             half = 32 // 2          # reduced head_dim is 32
             s = half * 3 // 8
             mrope = (half - 2 * s, s, s)
+        mla = {}
+        if self.is_mla:
+            mla = dict(kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
         return dataclasses.replace(
             self,
-            n_layers=min(self.n_layers, 2 * max(self.moe_interleave, 1)),
+            **mla,
+            n_layers=min(self.n_layers, 2 * max(self.moe_interleave, 1)
+                         + self.first_dense_layers),
             d_model=128,
             n_heads=r(self.n_heads, 4),
             n_kv_heads=r(self.n_kv_heads, 2),
@@ -159,6 +222,7 @@ class ArchConfig:
             moe_d_ff=r(self.moe_d_ff, 128) if self.moe_d_ff else 0,
             vocab_size=min(self.vocab_size, 512),
             n_experts=r(self.n_experts, 8),
+            n_routed_experts=r(self.n_routed_experts, 8),
             experts_per_token=r(self.experts_per_token, 2),
             ssm_state=r(self.ssm_state, 16),
             ssm_head_dim=32 if self.ssm_state else 64,
@@ -209,6 +273,7 @@ _ARCH_MODULES = {
     "deepseek-coder-33b": "deepseek_coder_33b",
     "minitron-8b": "minitron_8b",
     "hymba-1.5b": "hymba_1p5b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 
@@ -232,7 +297,8 @@ def run_arch(name: str, *, reduced: bool = False, layers: int = 0
     arch = src.reduced() if reduced else src
     if layers:
         g = max(arch.moe_interleave, 1) if arch.is_moe else 1
-        if not 0 < layers <= arch.n_layers or layers % g:
+        if not 0 < layers <= arch.n_layers or \
+                (layers - arch.first_dense_layers) % g:
             raise ValueError(
                 f"layers={layers} must be in [1, {arch.n_layers}] and a "
                 f"multiple of the layer period {g} for {name}")
@@ -254,7 +320,7 @@ def all_archs() -> Tuple[str, ...]:
 
 
 def all_cells() -> Tuple[Tuple[str, str, bool, str], ...]:
-    """Every (arch, shape, runnable, reason) cell — 40 total."""
+    """Every (arch, shape, runnable, reason) cell — 44 total."""
     out = []
     for a in all_archs():
         cfg = get_arch(a)

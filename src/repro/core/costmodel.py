@@ -222,7 +222,7 @@ class KVBlockGeometry:
     block_len: int                 # cache rows per block
     blocks_per_seq: int            # ceil(seq_len / block_len)
     n_blocks: int                  # pool capacity (global, all sub-pools)
-    dense_bytes: int               # B x seq_len stripe footprint (k+v, all layers)
+    dense_bytes: int               # B x seq_len stripe footprint (all layers)
     paged_bytes: int               # pool footprint at this capacity
     data_degree: int = 1           # sub-pools the block dim splits into
     model_degree: int = 1          # model shards per sub-pool
@@ -292,15 +292,17 @@ def kv_block_len(seq_len: int, min_block: int = 16,
 def kv_block_geometry(
     seq_len: int,
     batch: int,
-    n_layers: int,
-    kv_heads: int,
-    head_dim: int,
-    dtype_bytes: int = 2,
+    token_bytes: int,
     budget_bytes: Optional[float] = None,
     data_shards: int = 1,
     align: int = 1,
 ) -> KVBlockGeometry:
     """Choose the paged-pool geometry for a decode workload.
+
+    ``token_bytes`` is what the cache holds for one token in every
+    layer (``ArchConfig.cache_bytes_per_token``): the cache kind's row
+    — K and V of every kv head, or one MLA latent row — sizes the
+    blocks, so a latent cache gets its many more blocks per byte.
 
     2-D pool sharding: the block dim is split data-major into
     ``data_shards`` sub-pools (one per data shard, serving the batch
@@ -336,7 +338,7 @@ def kv_block_geometry(
     bl = kv_block_len(seq_len)
     per_seq = -(-seq_len // bl)
     want = max(1, batch) * per_seq
-    block_bytes = 2 * n_layers * bl * kv_heads * head_dim * dtype_bytes
+    block_bytes = bl * token_bytes
     d = max(1, data_shards)
     n = max(per_seq, want // d)
     if budget_bytes is not None and block_bytes > 0:
@@ -357,8 +359,7 @@ def kv_block_geometry(
         block_len=bl,
         blocks_per_seq=per_seq,
         n_blocks=n,
-        dense_bytes=2 * n_layers * max(1, batch) * seq_len
-        * kv_heads * head_dim * dtype_bytes,
+        dense_bytes=max(1, batch) * seq_len * token_bytes,
         paged_bytes=n * block_bytes,
         data_degree=d,
         model_degree=max(1, align),
@@ -387,7 +388,7 @@ class KVTierSplit:
 
     hbm_blocks: int                # HBM pool capacity (== geometry n_blocks)
     host_blocks: int               # host spill pool capacity (0 = hbm-only)
-    block_bytes: int               # one block, k+v, all layers
+    block_bytes: int               # one block, every pool, all layers
     pcie_bw: float                 # host<->HBM stream bandwidth (bytes/s)
     decode_tick_s: float           # modeled steady-state decode tick
     lookahead_ticks: int           # ticks between one slot's boundary crossings

@@ -38,7 +38,19 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
     if not arch.tie_embeddings:
         ir.declare(A.weight("lm_head", (d, V), ("embed", "vocab")))
 
-    if arch.has_attention:
+    if arch.is_mla:
+        nope, rope, dv = (arch.qk_nope_head_dim, arch.qk_rope_head_dim,
+                          arch.v_head_dim)
+        r = arch.kv_lora_rank
+        ir.declare(A.weight("attn.wq", (L, d, H * (nope + rope)),
+                            ("layers", "embed", "heads")))
+        ir.declare(A.weight("attn.wkv_a", (L, d, r + rope),
+                            ("layers", "embed", None)))
+        ir.declare(A.weight("attn.wkv_b", (L, r, H * (nope + dv)),
+                            ("layers", None, "heads")))
+        ir.declare(A.weight("attn.wo", (L, H * dv, d),
+                            ("layers", "heads", "embed")))
+    elif arch.has_attention:
         ir.declare(A.weight("attn.wq", (L, d, H * hd), ("layers", "embed", "heads")))
         ir.declare(A.weight("attn.wk", (L, d, K * hd), ("layers", "embed", "kv_heads")))
         ir.declare(A.weight("attn.wv", (L, d, K * hd), ("layers", "embed", "kv_heads")))
@@ -56,13 +68,13 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
         ir.declare(A.weight("ssm.A", (L, nh), ("layers", None), dtype="float32"))
 
     if arch.is_moe:
-        Lm = L // arch.moe_interleave
+        Lm = arch.n_moe_layers
         Ld = L - Lm
         ff = arch.moe_d_ff or arch.d_ff
-        E = arch.n_experts
-        ir.declare(A.weight("moe.wi", (Lm, E, d, 2 * ff),
+        E, Eh = arch.n_experts, arch.experts_held
+        ir.declare(A.weight("moe.wi", (Lm, Eh, d, 2 * ff),
                             ("layers", "experts", "embed", "ff"), expert=True))
-        ir.declare(A.weight("moe.wo", (Lm, E, ff, d),
+        ir.declare(A.weight("moe.wo", (Lm, Eh, ff, d),
                             ("layers", "experts", "ff", "embed"), expert=True))
         ir.declare(A.weight("moe.router", (Lm, d, E), ("layers", "embed", "act_experts")))
         if arch.n_shared_experts:
@@ -84,7 +96,11 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
     # ---------------- step inputs / activations -------------------------
     if shape.kind == "decode":
         ir.declare(A.model_input("tokens", (B, 1), ("batch", None)))
-        if arch.has_attention:
+        if arch.is_mla:
+            # one latent row a token and layer, shared by every head
+            ir.declare(A.kv_cache("cache.ckv", (L, B, S, arch.kv_row_width),
+                                  ("layers", "batch", "seq_kv", None)))
+        elif arch.has_attention:
             # cache layout is decided by the layout pass; declared seq-major.
             # names match the runtime cache pytree (dist.sharding.cache_axes)
             for nm in ("cache.k", "cache.v"):
@@ -131,9 +147,13 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
     op("embed_lookup", OpKind.EMBED, 0, act_T * d * 2, operands=(tokens_name, "embed"))
 
     if arch.has_attention:
-        proj_flops = 2 * act_T * d * (H + 2 * K) * hd * L
-        op("attn.qkv_proj", OpKind.MATMUL, proj_flops,
-           (d * (H + 2 * K) * hd * 2) * L, operands=("residual", "attn.wq"))
+        # q and the cached projections, per token (MLA: q, the latent
+        # row, and its expansion to per-head k_nope and v)
+        proj = (H + 2 * K) * hd * d
+        if arch.is_mla:
+            proj = arch.attn_param_count() - H * arch.v_head_dim * d
+        op("attn.qkv_proj", OpKind.MATMUL, 2 * act_T * proj * L,
+           proj * 2 * L, operands=("residual", "attn.wq"))
         # attention context per query token
         if shape.kind == "decode":
             ctx = S if arch.window == 0 else min(S, arch.window)
@@ -141,7 +161,7 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
             n_glob = _n_global_layers(arch)
             att_flops = 4 * B * hd * H * (ctx * (L - n_glob) + S * n_glob)
             kind = OpKind.ATTENTION_DECODE
-            operands = ("qkv", "cache.k")
+            operands = ("qkv", "cache.ckv" if arch.is_mla else "cache.k")
         else:
             ctx = S if arch.window == 0 else min(S, arch.window)
             n_glob = _n_global_layers(arch)
@@ -169,17 +189,18 @@ def describe_program(arch: ArchConfig, shape: ShapeConfig,
         op("ssm.out_proj", OpKind.MATMUL, 2 * act_T * di * d * L, di * d * 2 * L)
 
     if arch.is_moe:
-        Lm = L // arch.moe_interleave
+        Lm = arch.n_moe_layers
         Ld = L - Lm
         ff = arch.moe_d_ff or arch.d_ff
-        topk = arch.experts_per_token
+        # the routed work of the experts held here
+        topk = arch.experts_per_token * arch.experts_held / arch.n_experts
         op("moe.router", OpKind.MATMUL, 2 * act_T * d * arch.n_experts * Lm,
            d * arch.n_experts * 4 * Lm, operands=("residual", "moe.router"),
            results=("residual",))
         moe_flops = 2 * act_T * d * ff * 3 * topk * Lm
         moe_flops += 2 * act_T * d * ff * 3 * arch.n_shared_experts * Lm
         op("moe.experts", OpKind.MOE_DISPATCH, moe_flops,
-           arch.n_experts * 3 * d * ff * 2 * Lm,
+           arch.experts_held * 3 * d * ff * 2 * Lm,
            operands=("residual", "moe.wi", "moe.wo"),
            experts=arch.n_experts, topk=topk, capacity_factor=arch.capacity_factor)
         if Ld:

@@ -250,7 +250,18 @@ class CommunicationPass(Pass):
             ctx.template["channel.dcn"].remove(self.name, "single-pod mesh")
 
         # ---- MoE execution strategy ---------------------------------------
-        if ctx.arch.is_moe:
+        if ctx.arch.is_moe and ctx.arch.experts_held < ctx.arch.n_experts:
+            a = ctx.arch
+            plan.estimates["moe_impl"] = "held_dropless"
+            self.record(
+                ctx, "moe_impl", "held_dropless",
+                f"this chip holds {a.experts_held} of the router's "
+                f"{a.n_experts} experts (its expert-parallel share): the "
+                "dense layer routes over all of them, runs each held "
+                "expert over every token and keeps the gates of the pairs "
+                "on held experts, dropping none; capacity dispatch cannot "
+                "compute a share")
+        elif ctx.arch.is_moe:
             a = ctx.arch
             ff = a.moe_d_ff or a.d_ff
             k, cf, E = a.experts_per_token, a.capacity_factor, a.n_experts

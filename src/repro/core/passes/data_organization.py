@@ -210,8 +210,8 @@ class DataOrganizationPass(Pass):
         dsize = max(1, ctx.mesh.n_devices // msize)
         left = max(budget - persistent, 0.0) * msize * dsize
         geo = kv_block_geometry(
-            shape.seq_len, shape.global_batch, arch.n_layers,
-            arch.n_kv_heads, arch.hd, budget_bytes=left,
+            shape.seq_len, shape.global_batch,
+            arch.cache_bytes_per_token(), budget_bytes=left,
             data_shards=dsize, align=msize)
         batch_ok = dsize == 1 or shape.global_batch % dsize == 0
         forced = ctx.options.get("kv_residency")
@@ -512,6 +512,13 @@ class DataOrganizationPass(Pass):
         over a sharded seq axis (flash-decode semantics via psum).
         """
         plan, mesh = ctx.plan, ctx.mesh
+        if ctx.arch.is_mla:
+            # one latent row per token serves every head: no head dim
+            # to shard, and the seq-sharded flash decode is per-head KV
+            self.record(ctx, t.name, "replicated latent rows",
+                        "MLA caches one row shared by every head; the "
+                        "latent decode runs on the XLA path")
+            return
         spec = list(plan.placements[t.name].spec)
         used = {n for s in spec if s is not None
                 for n in ((s,) if isinstance(s, str) else s)}

@@ -94,6 +94,7 @@ CACHE_AXES: Dict[str, Tuple[Optional[str], ...]] = {
     "pos": ("batch",),                  # per-slot (B,) decode offsets
     "k": ("layers", "batch", "seq_kv", "kv_heads", "head_dim"),
     "v": ("layers", "batch", "seq_kv", "kv_heads", "head_dim"),
+    "ckv": ("layers", "batch", "seq_kv", None),    # MLA latent rows
     "ssm": ("layers", "batch", "ssm_heads", None, None),
     "conv": ("layers", "batch", None, "ssm_inner"),
 }
@@ -104,6 +105,7 @@ PAGED_CACHE_AXES: Dict[str, Tuple[Optional[str], ...]] = {
     "pos": ("batch",),
     "k": ("layers", "kv_blocks", None, "kv_heads", "head_dim"),
     "v": ("layers", "kv_blocks", None, "kv_heads", "head_dim"),
+    "ckv": ("layers", "kv_blocks", None, None),
     "block_tbl": ("batch", None),
     "ssm": ("layers", "batch", "ssm_heads", None, None),
     "conv": ("layers", "batch", None, "ssm_inner"),

@@ -18,7 +18,8 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import apply_rope, rms_norm
+from repro.models.common import (apply_rope, mla_softmax_scale, rms_norm,
+                                 rope_interleaved, yarn_rope_table)
 
 NEG_INF = -1e30
 
@@ -80,15 +81,18 @@ def attention_chunked(
     window=0,
     block_q: int = 512,
     positions: Optional[jax.Array] = None,   # (B, S)
+    scale: Optional[float] = None,           # None -> hd ** -0.5
 ) -> jax.Array:
     """Scan over query blocks; each block sees the full K/V stream.
 
     The per-block closure is rematerialized (``jax.checkpoint``) so the
     backward pass never holds more than one block's score matrix — the
-    XLA equivalent of flash attention's O(S) memory.
+    XLA equivalent of flash attention's O(S) memory.  Values may be
+    narrower than queries and keys (MLA: 192 against 128).
     """
     B, S, H, hd = q.shape
-    scale = hd ** -0.5
+    dv = v.shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     bq = min(block_q, S)
@@ -111,10 +115,10 @@ def attention_chunked(
         m = m & (qpb >= 0)[..., :, None]
         s = jnp.where(m[:, None, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        return gqa_context(p, v).astype(q.dtype)          # (B,bq,H,hd)
+        return gqa_context(p, v).astype(q.dtype)          # (B,bq,H,dv)
 
     out = jax.lax.map(lambda xs: one_block(*xs), (q_blocks, qpos_blocks))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_blocks * bq, H, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_blocks * bq, H, dv)
     return out[:, :S]
 
 
@@ -128,6 +132,7 @@ def attention_tail(
     causal: bool,
     window=0,
     block_q: int = 512,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Chunked attention for a *tail* of queries over a longer key
     stream (prefix-cache prefill: the leading M keys come from resident
@@ -141,7 +146,8 @@ def attention_tail(
     runs leans on this).
     """
     B, T, H, hd = q.shape
-    scale = hd ** -0.5
+    dv = v.shape[-1]
+    scale = hd ** -0.5 if scale is None else scale
     bq = min(block_q, T)
     n_blocks = -(-T // bq)
     pad = n_blocks * bq - T
@@ -161,10 +167,10 @@ def attention_tail(
         m = m & (qpb >= 0)[..., :, None]
         s = jnp.where(m[:, None, None], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        return gqa_context(p, v).astype(q.dtype)          # (B,bq,H,hd)
+        return gqa_context(p, v).astype(q.dtype)          # (B,bq,H,dv)
 
     out = jax.lax.map(lambda xs: one_block(*xs), (q_blocks, qpos_blocks))
-    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_blocks * bq, H, hd)
+    out = out.transpose(1, 0, 2, 3, 4).reshape(B, n_blocks * bq, H, dv)
     return out[:, :T]
 
 
@@ -238,3 +244,97 @@ def project_qkv(
     q = apply_rope(q, positions, theta, mrope_sections)
     k = apply_rope(k, positions, theta, mrope_sections)
     return q, k, v
+
+
+# ---------------------------------------------------------------------
+# Latent attention (MLA, deepseek-v2)
+# ---------------------------------------------------------------------
+
+class MLAParams(NamedTuple):
+    """Multi-head latent attention without a query LoRA."""
+
+    wq: jax.Array              # (d, H * (nope + rope))
+    wkv_a: jax.Array           # (d, r + rope): latent c_kv, shared k_pe
+    kv_norm: jax.Array         # (r,) RMSNorm on c_kv (offset from 1)
+    wkv_b: jax.Array           # (r, H * (nope + v))
+    wo: jax.Array              # (H * v, d)
+
+
+def mla_project(x: jax.Array, p: MLAParams, arch, positions: jax.Array):
+    """Queries and the cache row of each token.
+
+    Returns ``q_nope`` (B,S,H,nope), ``q_pe`` (B,S,H,rope) rotated, and
+    the latent ``row`` (B,S,r+rope): the normalized ``c_kv`` followed by
+    the rotated shared ``k_pe`` — all a token leaves in the cache."""
+    nope, rope = arch.qk_nope_head_dim, arch.qk_rope_head_dim
+    r = arch.kv_lora_rank
+    q = (x @ p.wq).reshape(*x.shape[:-1], -1, nope + rope)
+    q_nope, q_pe = q[..., :nope], q[..., nope:]
+    ckv = x @ p.wkv_a
+    c = rms_norm(ckv[..., :r], p.kv_norm, arch.norm_eps)
+    inv, cs = yarn_rope_table(arch)
+    q_pe = rope_interleaved(q_pe, positions, inv, cs)
+    k_pe = rope_interleaved(ckv[..., None, r:], positions, inv, cs)[..., 0, :]
+    return q_nope, q_pe, jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+
+
+def mla_expand(row: jax.Array, p: MLAParams, arch):
+    """Per-head keys (B,S,H,nope+rope) and values (B,S,H,v) expanded
+    from latent rows (B,S,r+rope): the prefill form."""
+    nope, r = arch.qk_nope_head_dim, arch.kv_lora_rank
+    kv = (row[..., :r] @ p.wkv_b).reshape(*row.shape[:-1], -1,
+                                           nope + arch.v_head_dim)
+    k_pe = jnp.broadcast_to(row[..., None, r:],
+                            (*kv.shape[:-1], arch.qk_rope_head_dim))
+    return (jnp.concatenate([kv[..., :nope], k_pe], axis=-1),
+            kv[..., nope:])
+
+
+def attention_decode_latent(
+    q_nope: jax.Array,         # (B, 1, H, nope)
+    q_pe: jax.Array,           # (B, 1, H, rope) — rotated
+    rows: jax.Array,           # (B, S, r + rope) latent cache rows
+    p: MLAParams,
+    arch,
+    *,
+    cache_len: jax.Array,      # (B,) valid positions
+) -> jax.Array:
+    """One-token decode in the absorbed form: ``W_UK`` is folded into
+    the query and ``W_UV`` applied after the context, so every head
+    attends over the one shared latent row (MQA of H heads over a
+    width of r + rope).  Returns (B, 1, H, v)."""
+    nope, r = arch.qk_nope_head_dim, arch.kv_lora_rank
+    B, S, _ = rows.shape
+    wkv_b = p.wkv_b.reshape(r, -1, nope + arch.v_head_dim)
+    with jax.named_scope("absorb"):
+        q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, wkv_b[..., :nope],
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat.astype(rows.dtype),
+                             q_pe.astype(rows.dtype)], axis=-1)
+    q = q * jnp.asarray(mla_softmax_scale(arch), q.dtype)
+    s = jnp.einsum("bqhc,bsc->bhqs", q, rows,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < \
+        jnp.asarray(cache_len).reshape(-1, 1)
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhqs,bsr->bqhr", pr, rows[..., :r].astype(jnp.float32))
+    with jax.named_scope("absorb"):
+        out = jnp.einsum("bqhr,rhv->bqhv", ctx.astype(rows.dtype),
+                         wkv_b[..., nope:],
+                         preferred_element_type=jnp.float32)
+    return out.astype(rows.dtype)
+
+
+def attention_decode_latent_paged(q_nope, q_pe, pool, block_tbl, p, arch,
+                                  *, cache_len):
+    """:func:`attention_decode_latent` over a paged latent pool
+    ``(N, bl, r + rope)`` (one layer): each slot's blocks are gathered
+    once into a dense view; rows past ``cache_len`` are masked."""
+    N, bl = pool.shape[0], pool.shape[1]
+    B, nb = block_tbl.shape
+    safe = jnp.clip(block_tbl, 0, N - 1)
+    with jax.named_scope("paged_gather"):
+        rows = pool[safe].reshape(B, nb * bl, pool.shape[-1])
+    return attention_decode_latent(q_nope, q_pe, rows, p, arch,
+                                   cache_len=cache_len)

@@ -65,6 +65,60 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
+def yarn_rope_table(arch) -> Tuple[np.ndarray, float]:
+    """Inverse frequencies (rope/2,) of the rotary dims and the scale of
+    their cos/sin, YaRN-interpolated when ``arch.yarn_factor`` is set
+    (deepseek-v2's ``DeepseekV2YarnRotaryEmbedding``)."""
+    dim, base = arch.qk_rope_head_dim, arch.rope_theta
+    exps = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / base ** exps
+    f = arch.yarn_factor
+    if not f:
+        return extra.astype(np.float32), 1.0
+
+    def corr(rot):
+        return (dim * math.log(arch.yarn_original_len / (rot * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    lo = max(math.floor(corr(arch.yarn_beta_fast)), 0)
+    hi = min(math.ceil(corr(arch.yarn_beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: extrapolate (high frequency)
+    inv = extra / f * (1.0 - keep) + extra * keep
+    cs = yarn_mscale(f, arch.yarn_mscale) / yarn_mscale(
+        f, arch.yarn_mscale_all_dim)
+    return inv.astype(np.float32), float(cs)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 or not mscale else \
+        0.1 * mscale * math.log(factor) + 1.0
+
+
+def mla_softmax_scale(arch) -> float:
+    """(nope + rope)^-0.5, times YaRN's attention temperature squared."""
+    s = (arch.qk_nope_head_dim + arch.qk_rope_head_dim) ** -0.5
+    if arch.yarn_factor and arch.yarn_mscale_all_dim:
+        s *= yarn_mscale(arch.yarn_factor, arch.yarn_mscale_all_dim) ** 2
+    return s
+
+
+def rope_interleaved(x: jax.Array, positions: jax.Array, inv: np.ndarray,
+                     cs: float = 1.0) -> jax.Array:
+    """Rotary embedding of dims read in interleaved pairs, as deepseek-v2
+    reads them: pair (x[2i], x[2i+1]) turns by ``pos * inv[i]``; the
+    result is laid out de-interleaved (evens, then odds).
+    x (..., seq, heads, dim), positions (..., seq)."""
+    ang = positions.astype(jnp.float32)[..., None, None] * jnp.asarray(inv)
+    c, s = jnp.cos(ang) * cs, jnp.sin(ang) * cs
+    x1 = x[..., 0::2].astype(jnp.float32)
+    x2 = x[..., 1::2].astype(jnp.float32)
+    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+    return out.astype(x.dtype)
+
+
 def sinusoidal_positions(seq: int, dim: int) -> jax.Array:
     """Fixed sinusoidal position table (hubert frontend stub)."""
     pos = np.arange(seq)[:, None]

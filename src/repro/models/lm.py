@@ -38,7 +38,7 @@ from repro.core.describe import global_layer_mask
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
-from repro.models.attention import AttnParams
+from repro.models.attention import AttnParams, MLAParams
 from repro.models.common import (
     cross_entropy_loss,
     rms_norm,
@@ -103,7 +103,9 @@ class LeafSpec(NamedTuple):
 
 def _attn_specs(arch: ArchConfig, Lg: int, d: int,
                 heads_padded: int = 0,
-                kv_heads_padded: int = 0) -> Dict[str, LeafSpec]:
+                kv_heads_padded: int = 0) -> Dict[str, Any]:
+    if arch.is_mla:
+        return _mla_specs(arch, Lg, d)
     hd = arch.hd
     H = heads_padded or arch.n_heads
     K = kv_heads_padded or arch.n_kv_heads
@@ -124,6 +126,26 @@ def _attn_specs(arch: ArchConfig, Lg: int, d: int,
     return specs
 
 
+def _mla_specs(arch: ArchConfig, Lg: int, d: int) -> Dict[str, Any]:
+    H, r = arch.n_heads, arch.kv_lora_rank
+    nope, rope, dv = (arch.qk_nope_head_dim, arch.qk_rope_head_dim,
+                      arch.v_head_dim)
+    return {
+        "wq": LeafSpec((Lg, d, H * (nope + rope)), "bfloat16",
+                       ("layers", "embed", "heads")),
+        "wkv_a": LeafSpec((Lg, d, r + rope), "bfloat16",
+                          ("layers", "embed", None)),
+        # the leaf's last name is "norm": a norm scale, offset from 1
+        "kv_norm": {"norm": LeafSpec((Lg, r), "float32",
+                                     ("layers", None), 0.0)},
+        "wkv_b": LeafSpec((Lg, r, H * (nope + dv)), "bfloat16",
+                          ("layers", None, "heads")),
+        "wo": LeafSpec((Lg, H * dv, d), "bfloat16",
+                       ("layers", "heads", "embed"),
+                       scale=0.02 / math.sqrt(2 * arch.n_layers)),
+    }
+
+
 def _mlp_specs(arch: ArchConfig, Lg: int, d: int, ff: int) -> Dict[str, LeafSpec]:
     gated = arch.gated_mlp and arch.family != "encoder"
     n_in = 2 if gated else 1
@@ -136,12 +158,12 @@ def _mlp_specs(arch: ArchConfig, Lg: int, d: int, ff: int) -> Dict[str, LeafSpec
 
 def _moe_specs(arch: ArchConfig, Lg: int, d: int) -> Dict[str, LeafSpec]:
     ff = arch.moe_d_ff or arch.d_ff
-    E = arch.n_experts
+    E, Eh = arch.n_experts, arch.experts_held
     specs = {
         "router": LeafSpec((Lg, d, E), "float32", ("layers", "embed", None)),
-        "wi": LeafSpec((Lg, E, d, 2 * ff), "bfloat16",
+        "wi": LeafSpec((Lg, Eh, d, 2 * ff), "bfloat16",
                        ("layers", "experts", "embed", "ff")),
-        "wo": LeafSpec((Lg, E, ff, d), "bfloat16",
+        "wo": LeafSpec((Lg, Eh, ff, d), "bfloat16",
                        ("layers", "experts", "ff", "embed"),
                        scale=0.02 / math.sqrt(2 * arch.n_layers)),
     }
@@ -184,7 +206,7 @@ def leaf_specs(arch: ArchConfig, vocab_padded: int = 0,
     """The full parameter-spec pytree for an architecture."""
     d = arch.d_model
     V = vocab_padded or arch.vocab_size
-    L = arch.n_layers
+    L = arch.n_layers - arch.first_dense_layers
     g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
     Lg = L // g
 
@@ -223,6 +245,14 @@ def leaf_specs(arch: ArchConfig, vocab_padded: int = 0,
         blocks.update(mixer_specs(Lg))
         blocks["mlp_norm"] = LeafSpec((Lg, d), "float32", ("layers", "embed"), 0.0)
         blocks["moe"] = _moe_specs(arch, Lg, d)
+        Ld = arch.first_dense_layers
+        if Ld:
+            # deepseek: leading dense layers, a stack of their own that
+            # runs before the MoE stack
+            specs["lead"] = {**mixer_specs(Ld),
+                             "mlp_norm": LeafSpec((Ld, d), "float32",
+                                                  ("layers", "embed"), 0.0),
+                             "mlp": _mlp_specs(arch, Ld, d, arch.d_ff)}
     else:
         blocks.update(mixer_specs(Lg))
         blocks["mlp_norm"] = LeafSpec((Lg, d), "float32", ("layers", "embed"), 0.0)
@@ -301,7 +331,7 @@ def _mixer_groups(params):
     b = params["blocks"]
     if "dense" in b and isinstance(b["dense"], dict):
         return [b["dense"], b["moe"]]
-    return [b]
+    return [b] + ([params["lead"]] if "lead" in params else [])
 
 
 # =====================================================================
@@ -350,28 +380,77 @@ def _ssm_dims(arch: ArchConfig, sp: SSMParams = None) -> SSMDims:
                    arch.ssm_conv)
 
 
+def _attn_params(grp) -> AttnParams:
+    a = grp["attn"]
+    return AttnParams(a["wq"], a["wk"], a["wv"], a["wo"], a.get("q_norm"),
+                      a.get("k_norm"))
+
+
+def _mla_params(grp) -> MLAParams:
+    a = grp["attn"]
+    return MLAParams(a["wq"], a["wkv_a"], a["kv_norm"]["norm"], a["wkv_b"],
+                     a["wo"])
+
+
+def _self_attention(arch, cfg, grp, h, positions, mask_pos, window,
+                    prefix=None, q_pos=None, k_pos=None):
+    """Prefill-form attention of one layer over ``h`` (B,S,d).
+
+    With ``prefix`` (``{pool: (B,M,...)}``: the cache rows of M earlier
+    positions) the S queries sit at ``q_pos`` after them and attend over
+    prefix-plus-own keys through :func:`attention_tail`.  Returns the
+    layer's output and ``{pool: (B,S,...)}``, the rows its cache gains:
+    post-RoPE ``k``/``v`` per kv head, or one latent ``ckv`` row (MLA)."""
+    B, S = h.shape[:2]
+    if arch.is_mla:
+        p = _mla_params(grp)
+        q_nope, q_pe, row = attn_mod.mla_project(h, p, arch, positions)
+        full = row if prefix is None else jnp.concatenate(
+            [prefix["ckv"].astype(row.dtype), row], axis=1)
+        k, v = attn_mod.mla_expand(full, p, arch)
+        q = jnp.concatenate([q_nope, q_pe], axis=-1)
+        scale = attn_mod.mla_softmax_scale(arch)
+        if prefix is None:
+            ctx = attn_mod.attention_chunked(
+                q, k, v, causal=arch.causal, window=window,
+                block_q=cfg.block_q, positions=mask_pos, scale=scale)
+        else:
+            ctx = attn_mod.attention_tail(
+                q, k, v, q_positions=q_pos, k_positions=k_pos,
+                causal=arch.causal, window=window, block_q=cfg.block_q,
+                scale=scale)
+        return ctx.reshape(B, S, -1) @ p.wo, {"ckv": row}
+    ap = _attn_params(grp)
+    Hq = ap.wq.shape[-1] // arch.hd        # layout pass may pad heads
+    q, k, v = attn_mod.project_qkv(
+        h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd, positions,
+        arch.rope_theta, arch.mrope_sections, arch.norm_eps)
+    if cfg.shard_heads:
+        q = _hint(q, cfg, None, None, cfg.model_axis, None)
+        kv_spec = cfg.model_axis if cfg.kv_heads_sharded else "rep"
+        k = _hint(k, cfg, None, None, kv_spec, None)
+        v = _hint(v, cfg, None, None, kv_spec, None)
+    if prefix is None:
+        ctx = attn_mod.attention_chunked(
+            q, k, v, causal=arch.causal, window=window,
+            block_q=cfg.block_q, positions=mask_pos)
+    else:
+        k_full = jnp.concatenate([prefix["k"].astype(k.dtype), k], axis=1)
+        v_full = jnp.concatenate([prefix["v"].astype(v.dtype), v], axis=1)
+        ctx = attn_mod.attention_tail(
+            q, k_full, v_full, q_positions=q_pos, k_positions=k_pos,
+            causal=arch.causal, window=window, block_q=cfg.block_q)
+    return ctx.reshape(B, S, -1) @ ap.wo, {"k": k, "v": v}
+
+
 def _mixer_fwd(arch, cfg, grp, x, positions, mask_pos, window):
     """Pre-norm mixer: attention and/or SSM paths (parallel for hybrid)."""
     h = rms_norm(x, grp["pre_norm"], arch.norm_eps)
     out = 0.0
     n_paths = int(arch.has_attention) + int(arch.has_ssm)
     if arch.has_attention:
-        ap = AttnParams(grp["attn"]["wq"], grp["attn"]["wk"],
-                        grp["attn"]["wv"], grp["attn"]["wo"],
-                        grp["attn"].get("q_norm"), grp["attn"].get("k_norm"))
-        Hq = ap.wq.shape[-1] // arch.hd        # layout pass may pad heads
-        q, k, v = attn_mod.project_qkv(
-            h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd, positions,
-            arch.rope_theta, arch.mrope_sections, arch.norm_eps)
-        if cfg.shard_heads:
-            q = _hint(q, cfg, None, None, cfg.model_axis, None)
-            kv_spec = cfg.model_axis if cfg.kv_heads_sharded else "rep"
-            k = _hint(k, cfg, None, None, kv_spec, None)
-            v = _hint(v, cfg, None, None, kv_spec, None)
-        ctx = attn_mod.attention_chunked(
-            q, k, v, causal=arch.causal, window=window,
-            block_q=cfg.block_q, positions=mask_pos)
-        out = out + ctx.reshape(*ctx.shape[:2], -1) @ ap.wo
+        y, _ = _self_attention(arch, cfg, grp, h, positions, mask_pos, window)
+        out = out + y
     if arch.has_ssm:
         sp = SSMParams(**grp["ssm"])
         out = out + ssm_mod.ssm_forward(h, sp, _ssm_dims(arch, sp),
@@ -379,9 +458,17 @@ def _mixer_fwd(arch, cfg, grp, x, positions, mask_pos, window):
     return x + out / n_paths
 
 
+def _counts_pairs(cfg) -> bool:
+    """Does the FFN report held token-expert pairs (the held layer)?"""
+    return cfg.moe_impl == "held_dropless"
+
+
 def _ffn_fwd(arch, cfg, grp, x):
-    """Pre-norm FFN: dense MLP or MoE. Returns (x, aux_loss)."""
+    """Pre-norm FFN: dense MLP or MoE. Returns (x, aux_loss, held):
+    ``held`` (B,S) counts each token's pairs on held experts under the
+    held-expert layer, and is None elsewhere."""
     aux = jnp.zeros((), jnp.float32)
+    held = None
     if "mlp" in grp:
         h = rms_norm(x, grp["mlp_norm"], arch.norm_eps)
         wi, wo = grp["mlp"]["wi"], grp["mlp"]["wo"]
@@ -395,31 +482,37 @@ def _ffn_fwd(arch, cfg, grp, x):
         else:  # squared relu (minitron/nemotron)
             z = jnp.square(jax.nn.relu(z.astype(jnp.float32))).astype(x.dtype)
         x = x + z @ wo
+        if _counts_pairs(cfg):
+            held = jnp.zeros(x.shape[:2], jnp.int32)
     elif "moe" in grp:
         h = rms_norm(x, grp["mlp_norm"], arch.norm_eps)
         mp = MoEParams(grp["moe"]["router"], grp["moe"]["wi"], grp["moe"]["wo"],
                        grp["moe"].get("shared_wi"), grp["moe"].get("shared_wo"))
-        if cfg.moe_impl == "shard_map_alltoall" and cfg.mesh is not None:
+        if cfg.moe_impl in ("dense_einsum", "held_dropless"):
+            with jax.named_scope("moe"):
+                y, aux, pairs = moe_mod.moe_dense_einsum(
+                    h, mp, top_k=arch.experts_per_token)
+            if _counts_pairs(cfg):
+                held = pairs
+        elif cfg.moe_impl == "shard_map_alltoall" and cfg.mesh is not None:
             y, aux = moe_mod.moe_shard_map(
                 h, mp, top_k=arch.experts_per_token,
                 capacity_factor=arch.capacity_factor, mesh=cfg.mesh,
                 data_axes=cfg.data_axes, model_axis=cfg.model_axis)
-        elif cfg.moe_impl == "dense_einsum":
-            y, aux = moe_mod.moe_dense_einsum(
-                h, mp, top_k=arch.experts_per_token)
         else:
             y, aux = moe_mod.moe_gshard_einsum(
                 h, mp, top_k=arch.experts_per_token,
                 capacity_factor=arch.capacity_factor)
         x = x + y
-    return x, aux
+    return x, aux, held
 
 
 def _block_fwd(arch, cfg, grp, x, positions, mask_pos, window):
     x = _mixer_fwd(arch, cfg, grp, x, positions, mask_pos, window)
     if arch.family == "ssm":
         return x, jnp.zeros((), jnp.float32)
-    return _ffn_fwd(arch, cfg, grp, x)
+    x, aux, _ = _ffn_fwd(arch, cfg, grp, x)
+    return x, aux
 
 
 def _remat(fn, policy: str):
@@ -439,6 +532,22 @@ def _window_schedule(arch: ArchConfig) -> jnp.ndarray:
         [0 if g else arch.window for g in mask], dtype=jnp.int32)
 
 
+def _stacks(arch: ArchConfig, params):
+    """The layer stacks a pass scans, in order, each with its per-layer
+    attention windows: the leading dense layers (deepseek), then the
+    main stack; llama4's super-blocks of ``g`` layers scan as one."""
+    L = arch.n_layers
+    w = _window_schedule(arch) if arch.has_attention \
+        else jnp.zeros((L,), jnp.int32)
+    g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
+    if g > 1:
+        return [(params["blocks"], w.reshape(L // g, g))]
+    Ld = arch.first_dense_layers
+    if not Ld:
+        return [(params["blocks"], w)]
+    return [(params["lead"], w[:Ld]), (params["blocks"], w[Ld:])]
+
+
 def forward(arch: ArchConfig, params, batch, cfg: RunCfg):
     """Full-sequence forward -> (hidden (B,S,d), aux_loss)."""
     x, positions, mask_pos = _embed_in(arch, params, batch, cfg)
@@ -448,36 +557,29 @@ def forward(arch: ArchConfig, params, batch, cfg: RunCfg):
     if cfg.batch_spec is not None:
         x = _hint(x, cfg, cfg.batch_spec, None, "rep")
     g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
-    Lg = arch.n_layers // g
-    windows = _window_schedule(arch) if arch.has_attention else None
 
     def body(carry, xs):
         x, aux = carry
         if g > 1:
             grp_params, w = xs
             x = _mixer_fwd(arch, cfg, grp_params["dense"], x, positions,
-                           mask_pos, w[0] if windows is not None else 0)
-            x, a1 = _ffn_fwd(arch, cfg, grp_params["dense"], x)
+                           mask_pos, w[0])
+            x, a1, _ = _ffn_fwd(arch, cfg, grp_params["dense"], x)
             x = _mixer_fwd(arch, cfg, grp_params["moe"], x, positions,
-                           mask_pos, w[1] if windows is not None else 0)
-            x, a2 = _ffn_fwd(arch, cfg, grp_params["moe"], x)
+                           mask_pos, w[1])
+            x, a2, _ = _ffn_fwd(arch, cfg, grp_params["moe"], x)
             return (x, aux + a1 + a2), None
         grp_params, w = xs
-        x, a = _block_fwd(arch, cfg, grp_params, x, positions, mask_pos,
-                          w if windows is not None else 0)
+        x, a = _block_fwd(arch, cfg, grp_params, x, positions, mask_pos, w)
         if cfg.batch_spec is not None:
             x = _hint(x, cfg, cfg.batch_spec, None, "rep")
         return (x, aux + a), None
 
     body = _remat(body, cfg.remat)
-    if g > 1:
-        w_xs = windows.reshape(Lg, g) if windows is not None \
-            else jnp.zeros((Lg, g), jnp.int32)
-        xs = (params["blocks"], w_xs)
-    else:
-        w_xs = windows if windows is not None else jnp.zeros((Lg,), jnp.int32)
-        xs = (params["blocks"], w_xs)
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    carry = (x, jnp.zeros((), jnp.float32))
+    for xs in _stacks(arch, params):
+        carry, _ = jax.lax.scan(body, carry, xs)
+    x, aux = carry
     x = rms_norm(x, params["final_norm"], arch.norm_eps)
     return x, aux
 
@@ -497,6 +599,25 @@ def train_loss(arch: ArchConfig, params, batch, cfg: RunCfg):
 # Serving: prefill + decode
 # =====================================================================
 
+def cache_rows(arch: ArchConfig, kv_heads: int = 0
+               ) -> Dict[str, Tuple[int, ...]]:
+    """The attention cache's pools and the shape of one token's row in
+    each, for one layer: ``k``/``v`` of every kv head, or MLA's one
+    latent ``ckv`` row (``kv_lora_rank + qk_rope_head_dim`` values).
+
+    This is the cache *kind* the memory template specializes: every
+    dense stripe is ``(L, B, max_len, *row)``, every paged pool ``(L,
+    n_blocks, block_len, *row)``, and the serving engine's block movers
+    (spill, promote, copy-on-write, prefix alias, park) carry whatever
+    pools this names."""
+    if not arch.has_attention:
+        return {}
+    if arch.is_mla:
+        return {"ckv": (arch.kv_row_width,)}
+    K, hd = kv_heads or arch.n_kv_heads, arch.hd
+    return {"k": (K, hd), "v": (K, hd)}
+
+
 def init_cache(arch: ArchConfig, batch_size: int, max_len: int,
                dtype=jnp.bfloat16, ssm_heads: int = 0,
                kv_heads: int = 0) -> Dict[str, Any]:
@@ -510,10 +631,8 @@ def init_cache(arch: ArchConfig, batch_size: int, max_len: int,
     L = arch.n_layers
     Hs = ssm_heads or arch.ssm_heads
     cache: Dict[str, Any] = {"pos": jnp.zeros((batch_size,), jnp.int32)}
-    if arch.has_attention:
-        K, hd = kv_heads or arch.n_kv_heads, arch.hd
-        cache["k"] = jnp.zeros((L, batch_size, max_len, K, hd), dtype)
-        cache["v"] = jnp.zeros((L, batch_size, max_len, K, hd), dtype)
+    for key, row in cache_rows(arch, kv_heads).items():
+        cache[key] = jnp.zeros((L, batch_size, max_len, *row), dtype)
     if arch.has_ssm:
         cache["ssm"] = jnp.zeros(
             (L, batch_size, Hs, arch.ssm_head_dim, arch.ssm_state),
@@ -529,10 +648,11 @@ def init_paged_cache(arch: ArchConfig, batch_size: int, max_len: int,
                      block_len: int, n_blocks: int,
                      dtype=jnp.bfloat16, ssm_heads: int = 0,
                      kv_heads: int = 0) -> Dict[str, Any]:
-    """Paged session state: the KV stripes become a block pool.
+    """Paged session state: the cache stripes become a block pool.
 
-    ``k``/``v`` are ``(L, n_blocks, block_len, K, hd)`` pools shared by
-    all slots — one block id addresses the same block in every layer —
+    Each pool of :func:`cache_rows` (``k``/``v``, or MLA's latent
+    ``ckv``) is ``(L, n_blocks, block_len, *row)``, shared by all slots
+    — one block id addresses the same block in every layer and pool —
     and ``block_tbl`` is the per-slot ``(B, ceil(max_len/block_len))``
     table mapping sequence positions to pool blocks (-1 = unassigned).
     SSM/conv states stay dense per-slot (they are O(1) in seq).  The
@@ -549,10 +669,9 @@ def init_paged_cache(arch: ArchConfig, batch_size: int, max_len: int,
     Hs = ssm_heads or arch.ssm_heads
     nb = -(-max_len // block_len)
     cache: Dict[str, Any] = {"pos": jnp.zeros((batch_size,), jnp.int32)}
+    for key, row in cache_rows(arch, kv_heads).items():
+        cache[key] = jnp.zeros((L, n_blocks, block_len, *row), dtype)
     if arch.has_attention:
-        K, hd = kv_heads or arch.n_kv_heads, arch.hd
-        cache["k"] = jnp.zeros((L, n_blocks, block_len, K, hd), dtype)
-        cache["v"] = jnp.zeros((L, n_blocks, block_len, K, hd), dtype)
         cache["block_tbl"] = jnp.full((batch_size, nb), -1, jnp.int32)
     if arch.has_ssm:
         cache["ssm"] = jnp.zeros(
@@ -570,30 +689,33 @@ def init_host_pool(arch: ArchConfig, n_host_blocks: int, block_len: int,
                    ) -> Dict[str, np.ndarray]:
     """Host-DRAM spill tier behind the paged pool (``kv_tier_split``).
 
-    Same per-block row layout as the device pools — ``k``/``v`` are
-    ``(L, host_blocks, block_len, K, hd)`` — but held as **numpy**
-    arrays: host memory by construction, never part of a jit graph, so
-    a spilled block costs HBM nothing.  Blocks migrate between the two
-    pools with :func:`gather_blocks` / :func:`scatter_blocks` (one
-    batched gather or scatter per transfer; the host->device leg is the
+    Same per-block row layout as the device pools — one ``(L,
+    host_blocks, block_len, *row)`` array per pool of
+    :func:`cache_rows` — but held as **numpy** arrays: host memory by
+    construction, never part of a jit graph, so a spilled block costs
+    HBM nothing.  Blocks migrate between the two pools with
+    :func:`gather_blocks` / :func:`scatter_blocks` (one batched gather
+    or scatter per transfer; the host->device leg is the
     ``jax.device_put`` the engine's prefetch stages a tick early).
     The dtype matches the device pool exactly (bf16 via ml_dtypes), so
     a spill/promote round trip is bit-identical — the token-identity
     tests lean on that.
     """
-    K, hd = kv_heads or arch.n_kv_heads, arch.hd
     L = arch.n_layers
-    shape = (L, n_host_blocks, block_len, K, hd)
-    return {"k": np.zeros(shape, dtype=np.dtype(dtype)),
-            "v": np.zeros(shape, dtype=np.dtype(dtype))}
+    return {key: np.zeros((L, n_host_blocks, block_len, *row),
+                          dtype=np.dtype(dtype))
+            for key, row in cache_rows(arch, kv_heads).items()}
 
 
 def gather_blocks(pool: jax.Array, ids: jax.Array) -> jax.Array:
-    """Pull whole blocks out of a ``(L, n_blocks, block_len, K, hd)``
-    pool as ``(L, len(ids), block_len, K, hd)`` rows — one batched
-    gather, the device half of a block migration (spill reads, promote
-    scatter-writes).  Jit-friendly: the engine wraps it once."""
-    return pool[:, ids]
+    """Pull whole blocks out of a ``(L, n_blocks, block_len, *row)``
+    pool as ``(L, len(ids), block_len, *row)`` rows — the device half
+    of a block migration (spill reads, promote scatter-writes) and of a
+    prefix alias.  One gather per layer: a gather across the layer axis
+    makes the TPU compiler lay the whole pool out again first (a 4.8 GiB
+    temporary for a 5.4 GB latent pool).  Jit-friendly: the engine wraps
+    it once."""
+    return jax.lax.map(lambda layer: layer[ids], pool)
 
 
 def scatter_blocks(pool: jax.Array, ids: jax.Array,
@@ -607,8 +729,8 @@ def append_kv_paged(pool: jax.Array, new: jax.Array, pos: jax.Array,
                     tbl: jax.Array, start=0) -> jax.Array:
     """Paged KV append: write ``new[b]`` into slot b's owning block.
 
-    ``pool`` is ``(n_blocks, block_len, K, hd)``, ``new`` ``(B, 1, K,
-    hd)``, ``pos`` ``(B,)`` dense-view offsets, ``tbl`` ``(B, nb)``.
+    ``pool`` is ``(n_blocks, block_len, *row)``, ``new`` ``(B, 1,
+    *row)``, ``pos`` ``(B,)`` dense-view offsets, ``tbl`` ``(B, nb)``.
     Slots whose owning table entry is unassigned (-1) are dropped — a
     freed slot's dummy decode never touches the pool.  ``start`` is the
     caller's first global block id when ``pool`` is one shard of a
@@ -626,16 +748,10 @@ def append_kv_paged(pool: jax.Array, new: jax.Array, pos: jax.Array,
                                       mode="drop")
 
 
-def _flatten_groups(arch, params):
-    """Stacked per-layer params (group-interleaved archs -> per-layer)."""
-    g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
-    return params["blocks"], g
-
-
 def append_kv(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
     """Per-slot KV append: write ``new[b]`` at seq offset ``pos[b]``.
 
-    ``cache`` is ``(B, S, K, hd)``, ``new`` ``(B, 1, K, hd)``, ``pos``
+    ``cache`` is ``(B, S, *row)``, ``new`` ``(B, 1, *row)``, ``pos``
     ``(B,)`` — each batch entry lands at its own offset (continuous
     batching; oracle: :func:`repro.kernels.ref.decode_append_ref`).
     """
@@ -645,8 +761,19 @@ def append_kv(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
     return jax.vmap(one)(cache, new, pos)
 
 
-def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
-    """One-token decode across all layers. Returns (logits, new_cache)."""
+def _held_sum(held) -> Any:
+    """Sum of the per-token held-pair counts over a scan step's layers
+    (None entries are layers without an FFN)."""
+    got = [h.sum(-1) for h in held if h is not None]
+    return sum(got[1:], got[0])
+
+
+def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
+                stats: bool = False):
+    """One-token decode across all layers. Returns (logits, new_cache);
+    with ``stats`` a third item, ``{"moe_held_pairs": (B,) int32}`` —
+    each row's token-expert pairs on held experts this step, summed
+    over layers — under the held-expert layer (``{}`` otherwise)."""
     x, positions, _ = _embed_in(arch, params, batch, cfg)   # (B,1,d)
     B = x.shape[0]
     pos = jnp.asarray(cache["pos"], jnp.int32)
@@ -656,77 +783,100 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
         positions = pos[:, None]                            # (B, 1)
         if arch.mrope_sections is not None:
             positions = jnp.broadcast_to(positions, (3, B, 1))
-    windows = _window_schedule(arch) if arch.has_attention else \
-        jnp.zeros((arch.n_layers,), jnp.int32)
-    blocks, g = _flatten_groups(arch, params)
+    g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
     block_tbl = cache.get("block_tbl")        # paged residency marker
+    pools = tuple(cache_rows(arch))
+    counting = _counts_pairs(cfg)
 
-    def layer(x, grp, w, kc, vc, sc, cc):
-        """One layer of decode; returns (x, new kc/vc/sc/cc)."""
+    def attend(grp, h, w, kv):
+        """Decode attention of one layer over its pools ``kv``; returns
+        the layer's attention output and the appended pools."""
+        if arch.is_mla:
+            p = _mla_params(grp)
+            with jax.named_scope("qkv"):
+                q_nope, q_pe, row = attn_mod.mla_project(h, p, arch,
+                                                         positions)
+            with jax.named_scope("attention"):
+                (c,) = kv
+                if block_tbl is not None:
+                    c = append_kv_paged(c, row, pos, block_tbl)
+                    ctx = attn_mod.attention_decode_latent_paged(
+                        q_nope, q_pe, c, block_tbl, p, arch,
+                        cache_len=pos + 1)
+                else:
+                    c = append_kv(c, row, pos)
+                    ctx = attn_mod.attention_decode_latent(
+                        q_nope, q_pe, c, p, arch, cache_len=pos + 1)
+            return ctx.reshape(B, 1, -1) @ p.wo, (c,)
+        kc, vc = kv
+        ap = _attn_params(grp)
+        Hq = ap.wq.shape[-1] // arch.hd
+        with jax.named_scope("qkv"):
+            q, k, v = attn_mod.project_qkv(
+                h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd,
+                positions, arch.rope_theta, arch.mrope_sections,
+                arch.norm_eps)
+        with jax.named_scope("attention"):
+            if cfg.decode_impl == "shard_map_flash" \
+                    and cfg.mesh is not None:
+                if block_tbl is not None:
+                    from repro.dist.flash_decode import flash_decode_paged
+                    ctx, kc, vc = flash_decode_paged(
+                        q, k, v, kc, vc, block_tbl, pos, w, mesh=cfg.mesh,
+                        data_axes=cfg.data_axes, model_axis=cfg.model_axis,
+                        combine=cfg.combine_topology)
+                else:
+                    from repro.dist.flash_decode import flash_decode
+                    ctx, kc, vc = flash_decode(
+                        q, k, v, kc, vc, pos, w, mesh=cfg.mesh,
+                        data_axes=cfg.data_axes, model_axis=cfg.model_axis,
+                        combine=cfg.combine_topology)
+            else:
+                if not cfg.shard_heads:
+                    pass
+                elif cfg.kv_heads_sharded:
+                    q = _hint(q, cfg, None, None, cfg.model_axis, None)
+                    k = _hint(k, cfg, None, None, cfg.model_axis, None)
+                    v = _hint(v, cfg, None, None, cfg.model_axis, None)
+                else:
+                    # match the head_dim-sharded cache: QK^T contracts the
+                    # sharded dim -> psum of the score tensor, and the
+                    # cache append stays local
+                    q = _hint(q, cfg, None, None, "rep", cfg.model_axis)
+                    k = _hint(k, cfg, None, None, "rep", cfg.model_axis)
+                    v = _hint(v, cfg, None, None, "rep", cfg.model_axis)
+                if block_tbl is not None:
+                    kc = append_kv_paged(kc, k, pos, block_tbl)
+                    vc = append_kv_paged(vc, v, pos, block_tbl)
+                    ctx = attn_mod.attention_decode_paged(
+                        q, kc, vc, block_tbl, cache_len=pos + 1, window=w)
+                else:
+                    kc = append_kv(kc, k, pos)
+                    vc = append_kv(vc, v, pos)
+                    ctx = attn_mod.attention_decode(q, kc, vc,
+                                                    cache_len=pos + 1,
+                                                    window=w)
+        return ctx.reshape(B, 1, -1) @ ap.wo, (kc, vc)
+
+    def layer(x, grp, w, kv, sc, cc):
+        """One layer of decode; returns (x, new kv/sc/cc, held pairs)."""
         h = rms_norm(x, grp["pre_norm"], arch.norm_eps)
         out = 0.0
         n_paths = int(arch.has_attention) + int(arch.has_ssm)
         if arch.has_attention:
-            ap = AttnParams(grp["attn"]["wq"], grp["attn"]["wk"],
-                            grp["attn"]["wv"], grp["attn"]["wo"],
-                            grp["attn"].get("q_norm"), grp["attn"].get("k_norm"))
-            Hq = ap.wq.shape[-1] // arch.hd
-            with jax.named_scope("qkv"):
-                q, k, v = attn_mod.project_qkv(
-                    h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd,
-                    positions, arch.rope_theta, arch.mrope_sections,
-                    arch.norm_eps)
-            with jax.named_scope("attention"):
-                if cfg.decode_impl == "shard_map_flash" \
-                        and cfg.mesh is not None:
-                    if block_tbl is not None:
-                        from repro.dist.flash_decode import flash_decode_paged
-                        ctx, kc, vc = flash_decode_paged(
-                            q, k, v, kc, vc, block_tbl, pos, w, mesh=cfg.mesh,
-                            data_axes=cfg.data_axes, model_axis=cfg.model_axis,
-                            combine=cfg.combine_topology)
-                    else:
-                        from repro.dist.flash_decode import flash_decode
-                        ctx, kc, vc = flash_decode(
-                            q, k, v, kc, vc, pos, w, mesh=cfg.mesh,
-                            data_axes=cfg.data_axes, model_axis=cfg.model_axis,
-                            combine=cfg.combine_topology)
-                else:
-                    if not cfg.shard_heads:
-                        pass
-                    elif cfg.kv_heads_sharded:
-                        q = _hint(q, cfg, None, None, cfg.model_axis, None)
-                        k = _hint(k, cfg, None, None, cfg.model_axis, None)
-                        v = _hint(v, cfg, None, None, cfg.model_axis, None)
-                    else:
-                        # match the head_dim-sharded cache: QK^T contracts the
-                        # sharded dim -> psum of the score tensor, and the
-                        # cache append stays local
-                        q = _hint(q, cfg, None, None, "rep", cfg.model_axis)
-                        k = _hint(k, cfg, None, None, "rep", cfg.model_axis)
-                        v = _hint(v, cfg, None, None, "rep", cfg.model_axis)
-                    if block_tbl is not None:
-                        kc = append_kv_paged(kc, k, pos, block_tbl)
-                        vc = append_kv_paged(vc, v, pos, block_tbl)
-                        ctx = attn_mod.attention_decode_paged(
-                            q, kc, vc, block_tbl, cache_len=pos + 1, window=w)
-                    else:
-                        kc = append_kv(kc, k, pos)
-                        vc = append_kv(vc, v, pos)
-                        ctx = attn_mod.attention_decode(q, kc, vc,
-                                                        cache_len=pos + 1,
-                                                        window=w)
-            out = out + ctx.reshape(B, 1, -1) @ ap.wo
+            y, kv = attend(grp, h, w, kv)
+            out = out + y
         if arch.has_ssm:
             sp = SSMParams(**grp["ssm"])
             y, sc, cc = ssm_mod.ssm_decode_step(h, sp, _ssm_dims(arch, sp),
                                                 sc, cc)
             out = out + y
         x = x + out / n_paths
+        held = None
         if arch.family != "ssm" and ("mlp" in grp or "moe" in grp):
             with jax.named_scope("mlp"):
-                x, _ = _ffn_fwd(arch, cfg, grp, x)
-        return x, kc, vc, sc, cc
+                x, _, held = _ffn_fwd(arch, cfg, grp, x)
+        return x, kv, sc, cc, held
 
     # scan over layers with the FULL stacked cache in the carry: each
     # iteration slices its layer and updates it in place (dynamic-update-
@@ -734,23 +884,16 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
     # end-to-end (with donation) instead of double-buffered through ys.
     L = arch.n_layers
     Lg = L // g
-    kc_full = cache.get("k")
-    vc_full = cache.get("v")
+    zeros = lambda: jnp.zeros((Lg, 1), jnp.float32)
+    kv_full = tuple(cache[k] for k in pools) or (zeros(), zeros())
     sc_full = cache.get("ssm")
     cc_full = cache.get("conv")
-    win = windows
     if g > 1:
-        kc_full = kc_full.reshape(Lg, g, *kc_full.shape[1:]) \
-            if kc_full is not None else None
-        vc_full = vc_full.reshape(Lg, g, *vc_full.shape[1:]) \
-            if vc_full is not None else None
-        win = windows.reshape(Lg, g)
+        kv_full = tuple(f.reshape(Lg, g, *f.shape[1:]) for f in kv_full) \
+            if pools else kv_full
         if sc_full is not None:
             sc_full = sc_full.reshape(Lg, g, *sc_full.shape[1:])
             cc_full = cc_full.reshape(Lg, g, *cc_full.shape[1:])
-    zeros = lambda: jnp.zeros((Lg, 1), jnp.float32)
-    kc_full = kc_full if kc_full is not None else zeros()
-    vc_full = vc_full if vc_full is not None else zeros()
     sc_full = sc_full if sc_full is not None else zeros()
     cc_full = cc_full if cc_full is not None else zeros()
 
@@ -761,55 +904,88 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg):
         return jax.lax.dynamic_update_index_in_dim(full, val, i, axis=0)
 
     def body(carry, xs):
-        x, i, kf, vf, sf, cf = carry
+        x, i, kf, sf, cf, pairs = carry
         grp, w = xs
-        kc, vc, sc, cc = at(kf, i), at(vf, i), at(sf, i), at(cf, i)
+        kv = tuple(at(f, i) for f in kf)
+        sc, cc = at(sf, i), at(cf, i)
         if g > 1:
-            x, kc0, vc0, sc0, cc0 = layer(x, grp["dense"], w[0],
-                                          kc[0], vc[0], sc[0], cc[0])
-            x, kc1, vc1, sc1, cc1 = layer(x, grp["moe"], w[1],
-                                          kc[1], vc[1], sc[1], cc[1])
-            kc = jnp.stack([kc0, kc1]) if arch.has_attention else kc
-            vc = jnp.stack([vc0, vc1]) if arch.has_attention else vc
+            x, kv0, sc0, cc0, h0 = layer(x, grp["dense"], w[0],
+                                         tuple(c[0] for c in kv),
+                                         sc[0], cc[0])
+            x, kv1, sc1, cc1, h1 = layer(x, grp["moe"], w[1],
+                                         tuple(c[1] for c in kv),
+                                         sc[1], cc[1])
+            kv = tuple(jnp.stack([a, b]) for a, b in zip(kv0, kv1)) \
+                if arch.has_attention else kv
             sc = jnp.stack([sc0, sc1]) if arch.has_ssm else sc
             cc = jnp.stack([cc0, cc1]) if arch.has_ssm else cc
+            held = (h0, h1)
         else:
-            x, kc, vc, sc, cc = layer(x, grp, w, kc, vc, sc, cc)
-        return (x, i + 1, put(kf, i, kc), put(vf, i, vc),
-                put(sf, i, sc), put(cf, i, cc)), None
+            x, kv, sc, cc, h = layer(x, grp, w, kv, sc, cc)
+            held = (h,)
+        if counting:
+            pairs = pairs + _held_sum(held)
+        return (x, i + 1, tuple(put(f, i, c) for f, c in zip(kf, kv)),
+                put(sf, i, sc), put(cf, i, cc), pairs), None
 
-    init = (x, jnp.zeros((), jnp.int32), kc_full, vc_full, sc_full, cc_full)
-    (x, _, new_k, new_v, new_s, new_c), _ = jax.lax.scan(
-        body, init, (blocks, win))
+    pairs = jnp.zeros((B,), jnp.int32) if counting else ()
+    stacks = _stacks(arch, params)
+    # the leading dense layers run before the loop, unrolled, and write
+    # their cache rows back after it: a write into the carried cache
+    # ahead of the loop would make XLA copy every pool whole
+    lead = []
+    for j in range(arch.first_dense_layers):
+        grp, w = jax.tree.map(lambda a: a[j], stacks[0])
+        x, kv, sc, cc, h = layer(x, grp, w, tuple(at(f, j) for f in kv_full),
+                                 at(sc_full, j), at(cc_full, j))
+        if counting:
+            pairs = pairs + _held_sum((h,))
+        lead.append((kv, sc, cc))
+    carry = (x, jnp.asarray(len(lead), jnp.int32), kv_full, sc_full,
+             cc_full, pairs)
+    carry, _ = jax.lax.scan(body, carry, stacks[-1])
+    x, _, new_kv, new_s, new_c, pairs = carry
+    for j, (kv, sc, cc) in enumerate(lead):
+        new_kv = tuple(put(f, j, c) for f, c in zip(new_kv, kv))
+        new_s, new_c = put(new_s, j, sc), put(new_c, j, cc)
     with jax.named_scope("logits"):
         x = rms_norm(x, params["final_norm"], arch.norm_eps)
         logits = _logits(arch, params, x, cfg)
     new_cache = dict(cache)
     new_cache["pos"] = pos + 1
-    if arch.has_attention:
-        new_cache["k"] = new_k.reshape(L, *new_k.shape[2:]) if g > 1 else new_k
-        new_cache["v"] = new_v.reshape(L, *new_v.shape[2:]) if g > 1 else new_v
+    for key, f in zip(pools, new_kv):
+        new_cache[key] = f.reshape(L, *f.shape[2:]) if g > 1 else f
     if arch.has_ssm:
         new_cache["ssm"] = new_s.reshape(L, *new_s.shape[2:]) if g > 1 else new_s
         new_cache["conv"] = new_c.reshape(L, *new_c.shape[2:]) if g > 1 else new_c
-    return logits[:, 0], new_cache
+    if not stats:
+        return logits[:, 0], new_cache
+    return logits[:, 0], new_cache, (
+        {"moe_held_pairs": pairs} if counting else {})
 
 
-def prefill(arch: ArchConfig, params, batch, cfg: RunCfg, max_len: int = 0):
+def _concat_stacks(ys):
+    """Per-stack scan outputs joined along the layer axis."""
+    if len(ys) == 1:
+        return ys[0]
+    return jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *ys)
+
+
+def prefill(arch: ArchConfig, params, batch, cfg: RunCfg, max_len: int = 0,
+            stats: bool = False):
     """Process a prompt, build the session cache, return last-token logits.
 
-    Implemented as the full-sequence forward plus cache extraction — the
-    K/V for every layer are recomputed from the per-layer projections in
-    a second scan that shares the same block params (cheap relative to
-    the FFN work, and keeps `forward` cache-free for training).
-    For SSM archs the final state comes from running the SSD scan.
+    Implemented as the full-sequence forward plus cache extraction: each
+    layer's scan step also emits the rows its cache gains (post-RoPE
+    K/V, or MLA's latent row), padded to ``max_len``.  For SSM archs the
+    final state comes from running the SSD scan.  With ``stats`` a
+    third item as :func:`decode_step`'s, summed over the prompt.
     """
     x, positions, mask_pos = _embed_in(arch, params, batch, cfg)
     B, S = x.shape[:2]
     max_len = max_len or S
-    windows = _window_schedule(arch) if arch.has_attention else None
     g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
-    Lg = arch.n_layers // g
+    counting = _counts_pairs(cfg)
 
     cache = init_cache(arch, B, max_len)
 
@@ -817,95 +993,87 @@ def prefill(arch: ArchConfig, params, batch, cfg: RunCfg, max_len: int = 0):
         h = rms_norm(x, grp["pre_norm"], arch.norm_eps)
         out = 0.0
         n_paths = int(arch.has_attention) + int(arch.has_ssm)
-        kv = (jnp.zeros((B, 0, 1, 1), jnp.bfloat16),) * 2
+        rows: Dict[str, Any] = {}
         states = ()
         if arch.has_attention:
-            ap = AttnParams(grp["attn"]["wq"], grp["attn"]["wk"],
-                            grp["attn"]["wv"], grp["attn"]["wo"],
-                            grp["attn"].get("q_norm"), grp["attn"].get("k_norm"))
-            Hq = ap.wq.shape[-1] // arch.hd
-            q, k, v = attn_mod.project_qkv(
-                h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd, positions,
-                arch.rope_theta, arch.mrope_sections, arch.norm_eps)
-            if cfg.shard_heads:
-                q = _hint(q, cfg, None, None, cfg.model_axis, None)
-                kv_spec = cfg.model_axis if cfg.kv_heads_sharded else "rep"
-                k = _hint(k, cfg, None, None, kv_spec, None)
-                v = _hint(v, cfg, None, None, kv_spec, None)
-            ctx = attn_mod.attention_chunked(
-                q, k, v, causal=arch.causal, window=w,
-                block_q=cfg.block_q, positions=mask_pos)
-            out = out + ctx.reshape(B, S, -1) @ ap.wo
-            kv = (k, v)
+            y, rows = _self_attention(arch, cfg, grp, h, positions,
+                                      mask_pos, w)
+            out = out + y
         if arch.has_ssm:
             sp = SSMParams(**grp["ssm"])
             y, fin_s, fin_c = _ssm_prefill(h, sp, arch, cfg)
             out = out + y
             states = (fin_s, fin_c)
         x = x + out / n_paths
-        aux = jnp.zeros((), jnp.float32)
+        held = None
         if arch.family != "ssm" and ("mlp" in grp or "moe" in grp):
-            x, aux = _ffn_fwd(arch, cfg, grp, x)
-        return x, kv, states
+            x, _, held = _ffn_fwd(arch, cfg, grp, x)
+        return x, rows, states, held
 
     def body(carry, xs):
-        x = carry
+        x, pairs = carry
         grp, w = xs
-        outs = []
         if g > 1:
-            x, kv0, st0 = layer(x, grp["dense"], w[0])
-            x, kv1, st1 = layer(x, grp["moe"], w[1])
-            ys = _stack_cache(arch, (kv0, kv1), (st0, st1), max_len, S)
+            x, r0, st0, h0 = layer(x, grp["dense"], w[0])
+            x, r1, st1, h1 = layer(x, grp["moe"], w[1])
+            ys = _stack_cache(arch, (r0, r1), (st0, st1), max_len, S)
+            held = (h0, h1)
         else:
-            x, kv, st = layer(x, grp, w)
-            ys = _stack_cache(arch, (kv,), (st,), max_len, S)
-        return x, ys
+            x, r, st, h = layer(x, grp, w)
+            ys = _stack_cache(arch, (r,), (st,), max_len, S)
+            held = (h,)
+        if counting:
+            pairs = pairs + _held_sum(held)
+        return (x, pairs), ys
 
-    if g > 1:
-        w_xs = (windows.reshape(Lg, g) if windows is not None
-                else jnp.zeros((Lg, g), jnp.int32))
-    else:
-        w_xs = (windows if windows is not None
-                else jnp.zeros((Lg,), jnp.int32))
-    x, ys = jax.lax.scan(body, x, (params["blocks"], w_xs))
+    carry = (x, jnp.zeros((B,), jnp.int32) if counting else ())
+    ys = []
+    for xs in _stacks(arch, params):
+        carry, y = jax.lax.scan(body, carry, xs)
+        ys.append(y)
+    ys = _concat_stacks(ys)
+    x, pairs = carry
     x = rms_norm(x, params["final_norm"], arch.norm_eps)
     logits = _logits(arch, params, x[:, -1:], cfg)
 
     # unpack stacked cache entries
     L = arch.n_layers
-    idx = 0
-    if arch.has_attention:
-        cache["k"] = ys[idx].reshape(L, B, max_len, -1, arch.hd)
-        cache["v"] = ys[idx + 1].reshape(L, B, max_len, -1, arch.hd)
-        idx += 2
+    for key, row in cache_rows(arch).items():
+        cache[key] = ys[key].reshape(L, B, max_len, *ys[key].shape[-len(row):])
     if arch.has_ssm:
-        cache["ssm"] = ys[idx].reshape(L, *ys[idx].shape[-4:])
-        cache["conv"] = ys[idx + 1].reshape(L, *ys[idx + 1].shape[-3:])
+        cache["ssm"] = ys["ssm"].reshape(L, *ys["ssm"].shape[-4:])
+        cache["conv"] = ys["conv"].reshape(L, *ys["conv"].shape[-3:])
     cache["pos"] = jnp.full((B,), S, jnp.int32)
-    return logits[:, 0], cache
+    if not stats:
+        return logits[:, 0], cache
+    return logits[:, 0], cache, (
+        {"moe_held_pairs": pairs} if counting else {})
 
 
 def prefill_tail(arch: ArchConfig, params, batch, cfg: RunCfg,
-                 prefix_k: jax.Array, prefix_v: jax.Array):
+                 prefix: Dict[str, jax.Array], stats: bool = False):
     """Prefill only the unmatched *tail* of a prompt whose leading rows
     already sit in resident pool blocks (cross-request prefix reuse).
 
-    ``batch["tokens"]`` is ``(B, T)`` tail tokens; ``prefix_k`` /
-    ``prefix_v`` are ``(L, B, M, K, hd)`` — the matched prefix rows
-    gathered from the pool (already post-RoPE at absolute positions
-    ``[0, M)``, exactly as the donor's prefill wrote them).  The tail is
-    embedded at absolute positions ``M + [0, T)`` and each layer attends
-    over prefix-plus-tail keys through :func:`repro.models.attention
-    .attention_tail`, whose op structure matches the full-prefill
-    attention bit-for-bit on the tail positions — the token-identity
-    contract aliased admission leans on.
+    ``batch["tokens"]`` is ``(B, T)`` tail tokens; ``prefix`` maps each
+    pool of :func:`cache_rows` to ``(L, B, M, *row)`` — the matched
+    prefix rows gathered from the pool (post-RoPE at absolute positions
+    ``[0, M)``, exactly as the donor's prefill wrote them; MLA expands
+    its latent rows to per-head keys and values here, as prefill does).
+    The tail is embedded at absolute positions ``M + [0, T)`` and each
+    layer attends over prefix-plus-tail keys through
+    :func:`repro.models.attention.attention_tail`, whose op structure
+    matches the full-prefill attention bit-for-bit on the tail
+    positions — the token-identity contract aliased admission leans on.
 
-    Returns ``(last-token logits (B, V), tail_k, tail_v)`` with tail
-    K/V stacked ``(L, B, T, K, hd)`` for the caller to scatter into its
-    freshly allocated blocks.  Attention-only architectures: an SSM
-    path's state at position M depends on every earlier token, so a
-    hybrid cannot skip the prefix compute (the engine runs those
-    through the full prefill and aliases blocks without skipping).
+    Returns ``(last-token logits (B, V), tail)`` with ``tail`` mapping
+    each pool to ``(L, B, T, *row)`` rows for the caller to scatter into
+    its freshly allocated blocks (and with ``stats`` a third item as
+    :func:`decode_step`'s, over the tail).  Attention-only
+    architectures: an SSM path's state at position M depends on every
+    earlier token, so a hybrid cannot skip the prefix compute (the
+    engine runs those through the full prefill and aliases blocks
+    without skipping).
     """
     if arch.has_ssm:
         raise ValueError(
@@ -913,93 +1081,96 @@ def prefill_tail(arch: ArchConfig, params, batch, cfg: RunCfg,
             "SSM state at the split point depends on the whole prefix")
     tokens = batch["tokens"]
     B, T = tokens.shape
-    M = prefix_k.shape[2]
+    M = next(iter(prefix.values())).shape[2]
     x = jnp.take(params["embed"], tokens, axis=0)
     q_pos = jnp.broadcast_to(M + jnp.arange(T, dtype=jnp.int32), (B, T))
     k_pos = jnp.broadcast_to(jnp.arange(M + T, dtype=jnp.int32), (B, M + T))
     positions = q_pos
     if arch.mrope_sections is not None:
         positions = jnp.broadcast_to(positions, (3, B, T))
-    windows = _window_schedule(arch)
     g = arch.moe_interleave if arch.is_moe and arch.moe_interleave > 1 else 1
-    Lg = arch.n_layers // g
+    counting = _counts_pairs(cfg)
 
-    def layer(x, grp, w, pk, pv):
+    def layer(x, grp, w, pre):
         h = rms_norm(x, grp["pre_norm"], arch.norm_eps)
-        ap = AttnParams(grp["attn"]["wq"], grp["attn"]["wk"],
-                        grp["attn"]["wv"], grp["attn"]["wo"],
-                        grp["attn"].get("q_norm"), grp["attn"].get("k_norm"))
-        Hq = ap.wq.shape[-1] // arch.hd
-        q, k, v = attn_mod.project_qkv(
-            h, ap, Hq, ap.wk.shape[-1] // arch.hd, arch.hd, positions,
-            arch.rope_theta, arch.mrope_sections, arch.norm_eps)
-        if cfg.shard_heads:
-            q = _hint(q, cfg, None, None, cfg.model_axis, None)
-            kv_spec = cfg.model_axis if cfg.kv_heads_sharded else "rep"
-            k = _hint(k, cfg, None, None, kv_spec, None)
-            v = _hint(v, cfg, None, None, kv_spec, None)
-        k_full = jnp.concatenate([pk.astype(k.dtype), k], axis=1)
-        v_full = jnp.concatenate([pv.astype(v.dtype), v], axis=1)
-        ctx = attn_mod.attention_tail(
-            q, k_full, v_full, q_positions=q_pos, k_positions=k_pos,
-            causal=arch.causal, window=w, block_q=cfg.block_q)
-        x = x + ctx.reshape(B, T, -1) @ ap.wo
+        y, rows = _self_attention(arch, cfg, grp, h, positions, None, w,
+                                  prefix=pre, q_pos=q_pos, k_pos=k_pos)
+        x = x + y
+        held = None
         if "mlp" in grp or "moe" in grp:
-            x, _ = _ffn_fwd(arch, cfg, grp, x)
-        return x, k, v
+            x, _, held = _ffn_fwd(arch, cfg, grp, x)
+        return x, rows, held
 
-    pk_xs = prefix_k.reshape(Lg, g, *prefix_k.shape[1:]) if g > 1 \
-        else prefix_k
-    pv_xs = prefix_v.reshape(Lg, g, *prefix_v.shape[1:]) if g > 1 \
-        else prefix_v
-
-    def body(x, xs):
-        grp, w, pk, pv = xs
+    def body(carry, xs):
+        x, pairs = carry
+        grp, w, pre = xs
         if g > 1:
-            x, k0, v0 = layer(x, grp["dense"], w[0], pk[0], pv[0])
-            x, k1, v1 = layer(x, grp["moe"], w[1], pk[1], pv[1])
-            return x, (jnp.stack([k0, k1]), jnp.stack([v0, v1]))
-        x, k, v = layer(x, grp, w, pk, pv)
-        return x, (k, v)
+            x, r0, h0 = layer(x, grp["dense"], w[0],
+                              {k: v[0] for k, v in pre.items()})
+            x, r1, h1 = layer(x, grp["moe"], w[1],
+                              {k: v[1] for k, v in pre.items()})
+            rows = {k: jnp.stack([r0[k], r1[k]]) for k in r0}
+            held = (h0, h1)
+        else:
+            x, rows, h = layer(x, grp, w, pre)
+            held = (h,)
+        if counting:
+            pairs = pairs + _held_sum(held)
+        return (x, pairs), rows
 
-    w_xs = windows.reshape(Lg, g) if g > 1 else windows
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (params["blocks"], w_xs, pk_xs, pv_xs))
+    carry = (x, jnp.zeros((B,), jnp.int32) if counting else ())
+    ys = []
+    at = 0
+    for grp, w in _stacks(arch, params):
+        n = w.shape[0] * g
+        pre = {k: v[at:at + n] for k, v in prefix.items()}
+        if g > 1:
+            pre = {k: v.reshape(n // g, g, *v.shape[1:])
+                   for k, v in pre.items()}
+        carry, y = jax.lax.scan(body, carry, (grp, w, pre))
+        ys.append(y)
+        at += n
+    ys = _concat_stacks(ys)
+    x, pairs = carry
     x = rms_norm(x, params["final_norm"], arch.norm_eps)
     logits = _logits(arch, params, x[:, -1:], cfg)
     L = arch.n_layers
-    tail_k = ks.reshape(L, B, T, -1, arch.hd)
-    tail_v = vs.reshape(L, B, T, -1, arch.hd)
-    return logits[:, 0], tail_k, tail_v
+    tail = {k: v.reshape(L, B, T, *prefix[k].shape[3:])
+            for k, v in ys.items()}
+    if not stats:
+        return logits[:, 0], tail
+    return logits[:, 0], tail, (
+        {"moe_held_pairs": pairs} if counting else {})
 
 
 def prefill_chunked(arch: ArchConfig, params, tokens, chunk: int,
                     cfg: RunCfg, kv_heads: int = 0,
-                    prefix_k=None, prefix_v=None,
+                    prefix: Optional[Dict[str, Any]] = None,
                     on_chunk=None, tail_fn=None):
     """Block-native chunked prefill of ONE prompt: no dense ``(B, plen)``
     intermediate ever exists.
 
     ``tokens`` is ``(T,)`` — the part of the feed *after* any prefix
-    already in hand; ``prefix_k``/``prefix_v`` are ``(L, M, K, hd)``
-    rows covering the first M tokens (``None`` for a fresh prompt).
-    The tail is processed in ``chunk``-sized slices, each one a
-    :func:`prefill_tail` call chained on the KV accumulated so far —
-    every slice comes out pool-block-shaped, ready to scatter straight
-    into paged blocks.  Because ``attention_tail`` mirrors the
-    full-prefill ``attention_chunked`` op-for-op, the chained chunks
-    reproduce the dense prefill's hidden states exactly: same KV rows,
-    same last-token logits (pinned by ``test_disagg``).
+    already in hand; ``prefix`` maps each pool of :func:`cache_rows` to
+    ``(L, M, *row)`` rows covering the first M tokens (``None`` for a
+    fresh prompt).  The tail is processed in ``chunk``-sized slices,
+    each one a :func:`prefill_tail` call chained on the rows
+    accumulated so far — every slice comes out pool-block-shaped, ready
+    to scatter straight into paged blocks.  Because ``attention_tail``
+    mirrors the full-prefill ``attention_chunked`` op-for-op, the
+    chained chunks reproduce the dense prefill's hidden states exactly:
+    same cache rows, same last-token logits (pinned by ``test_disagg``).
 
-    ``on_chunk(block_idx, k_c, v_c)`` fires after each slice with
-    ``(L, t, K, hd)`` rows (``block_idx`` counts from the start of the
-    *feed*, prefix included) — the disagg worker streams these to the
-    decode engine and heartbeats between them.  ``tail_fn`` lets a
-    long-lived caller supply a pre-jitted ``prefill_tail`` closure so
-    the per-shape compile cache survives across prompts.
+    ``on_chunk(block_idx, rows)`` fires after each slice with ``{pool:
+    (L, t, *row)}`` (``block_idx`` counts from the start of the *feed*,
+    prefix included) — the disagg worker streams these to the decode
+    engine and heartbeats between them.  ``tail_fn`` lets a long-lived
+    caller supply a pre-jitted ``prefill_tail`` closure so the
+    per-shape compile cache survives across prompts.
 
-    Returns ``(last-token logits (V,), ks, vs)`` with the per-chunk row
-    lists.  Attention-only archs (same restriction as ``prefill_tail``).
+    Returns ``(last-token logits (V,), chunks)``: one rows dict per
+    slice.  Attention-only archs (same restriction as
+    ``prefill_tail``).
     """
     if arch.has_ssm:
         raise ValueError(
@@ -1011,30 +1182,27 @@ def prefill_chunked(arch: ArchConfig, params, tokens, chunk: int,
     (T,) = tokens.shape
     if T < 1:
         raise ValueError("prefill_chunked needs at least one tail token")
-    L, K = arch.n_layers, (kv_heads or arch.n_kv_heads)
-    if prefix_k is None:
-        pk = jnp.zeros((L, 1, 0, K, arch.hd), jnp.bfloat16)
-        pv = pk
+    L = arch.n_layers
+    if prefix is None:
+        pre = {k: jnp.zeros((L, 1, 0, *row), jnp.bfloat16)
+               for k, row in cache_rows(arch, kv_heads).items()}
     else:
-        pk = jnp.asarray(prefix_k)[:, None]    # (L, 1, M, K, hd)
-        pv = jnp.asarray(prefix_v)[:, None]
-    M = pk.shape[2]
+        pre = {k: jnp.asarray(v)[:, None] for k, v in prefix.items()}
+    M = next(iter(pre.values())).shape[2]
     if M % chunk:
         raise ValueError(f"prefix length {M} not block-aligned to {chunk}")
     if tail_fn is None:
-        tail_fn = lambda p, b, k, v: prefill_tail(arch, p, b, cfg, k, v)
-    ks, vs = [], []
+        tail_fn = lambda p, b, pr: prefill_tail(arch, p, b, cfg, pr)
+    chunks = []
     logits = None
     for i in range(0, T, chunk):
         tok = tokens[None, i:i + chunk]                      # (1, t)
-        logits, tk, tv = tail_fn(params, {"tokens": tok}, pk, pv)
-        ks.append(tk[:, 0])                                  # (L, t, K, hd)
-        vs.append(tv[:, 0])
+        logits, tail = tail_fn(params, {"tokens": tok}, pre)
+        chunks.append({k: v[:, 0] for k, v in tail.items()})  # (L, t, ...)
         if on_chunk is not None:
-            on_chunk((M + i) // chunk, ks[-1], vs[-1])
-        pk = jnp.concatenate([pk, tk], axis=2)
-        pv = jnp.concatenate([pv, tv], axis=2)
-    return logits[0], ks, vs
+            on_chunk((M + i) // chunk, chunks[-1])
+        pre = {k: jnp.concatenate([pre[k], tail[k]], axis=2) for k in pre}
+    return logits[0], chunks
 
 
 def _ssm_prefill(h, sp, arch, cfg):
@@ -1063,21 +1231,20 @@ def _ssm_prefill(h, sp, arch, cfg):
     return y, final, conv_fin
 
 
-def _stack_cache(arch, kvs, states, max_len, S):
-    """Build the per-scan-step cache ys tuple (padded to max_len)."""
-    out = []
-    if arch.has_attention:
-        ks = jnp.stack([jnp.pad(k, ((0, 0), (0, max_len - S), (0, 0), (0, 0)))
-                        for k, _ in kvs])
-        vs = jnp.stack([jnp.pad(v, ((0, 0), (0, max_len - S), (0, 0), (0, 0)))
-                        for _, v in kvs])
-        if len(kvs) == 1:
-            ks, vs = ks[0], vs[0]
-        out += [ks, vs]
+def _stack_cache(arch, rows, states, max_len, S):
+    """The per-scan-step cache ys: each pool's rows padded to
+    ``max_len`` (``rows`` holds one dict per layer of the step), and the
+    SSM states."""
+    out = {}
+    for key in (rows[0] if arch.has_attention else ()):
+        r = jnp.stack([
+            jnp.pad(x[key], ((0, 0), (0, max_len - S))
+                    + ((0, 0),) * (x[key].ndim - 2)) for x in rows])
+        out[key] = r[0] if len(rows) == 1 else r
     if arch.has_ssm:
         ss = jnp.stack([s[0] for s in states])
         cs = jnp.stack([s[1] for s in states])
         if len(states) == 1:
             ss, cs = ss[0], cs[0]
-        out += [ss, cs]
-    return tuple(out)
+        out["ssm"], out["conv"] = ss, cs
+    return out

@@ -87,42 +87,60 @@ def moe_dense_einsum(
     *,
     top_k: int,
     capacity_factor: float = 0.0,   # unused; signature parity
-) -> Tuple[jax.Array, jax.Array]:
-    """Dense-execution MoE: run EVERY expert on every token, combine with
-    the top-k router weights.
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dense-execution MoE: run every held expert on every token, combine
+    with the top-k router weights.
 
     For small-expert/high-top-k configs (granite: 8-of-32, ff=512) the
     GShard dispatch/combine one-hot matmuls cost MORE FLOPs than simply
     computing all experts — and this path has no capacity drops, no
     (T,E,C) tensors, and no all-to-all.  The communication pass picks it
     when 6·E·ff <= 6·k·ff + 4·k·cf·(E·C/T)·... (see _moe_impl decision).
+
+    ``wi``/``wo`` may hold fewer experts than the router scores: experts
+    ``[0, E_h)`` of ``E``, one chip's share under expert parallelism.
+    The router still scores all ``E`` and each token keeps its greedy
+    top-k, unrenormalized; the result is this share's part of the routed
+    sum (plus the shared experts, which every share runs).  On a v5e
+    this beat sorting the pairs by held expert into grouped products
+    (``lax.ragged_dot``) at 32 to 10240 tokens, though it does
+    ``E_h / (k E_h / E)`` times the routed FLOPs.
+
+    Returns ``(y, aux, held)``: ``held`` (B, S) int32 counts each
+    token's pairs on held experts.
     """
     B, S, d = x.shape
     E = p.router.shape[-1]
-    logits = x.astype(jnp.float32) @ p.router.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)                  # (B,S,E)
-    top1 = jnp.argmax(probs, axis=-1)
-    frac_tokens = jnp.mean(jax.nn.one_hot(top1, E, dtype=jnp.float32),
-                           axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac_tokens * frac_probs)
+    Eh = p.wi.shape[0]
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ p.router.astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)              # (B,S,E)
+        top1 = jnp.argmax(probs, axis=-1)
+        frac_tokens = jnp.mean(jax.nn.one_hot(top1, E, dtype=jnp.float32),
+                               axis=(0, 1))
+        frac_probs = jnp.mean(probs, axis=(0, 1))
+        aux = E * jnp.sum(frac_tokens * frac_probs)
 
-    # top-k gate weights, zero elsewhere
-    thresh = jax.lax.top_k(probs, top_k)[0][..., -1:]
-    gates = jnp.where(probs >= thresh, probs, 0.0)           # (B,S,E)
+        # top-k gate weights of the held experts, zero elsewhere
+        thresh = jax.lax.top_k(probs, top_k)[0][..., -1:]
+        picked = (probs >= thresh)[..., :Eh]                 # (B,S,Eh)
+        gates = jnp.where(picked, probs[..., :Eh], 0.0)
 
-    h = jnp.einsum("bsd,edf->bsef", x, p.wi)                 # (B,S,E,2ff)
-    gate, up = jnp.split(h, 2, axis=-1)
-    h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    out = jnp.einsum("bsef,efd->bsed", h, p.wo)              # (B,S,E,d)
-    y = jnp.einsum("bsed,bse->bsd", out.astype(jnp.float32), gates)
-    y = y.astype(x.dtype)
+    with jax.named_scope("held"):
+        h = jnp.einsum("bsd,edf->bsef", x, p.wi)             # (B,S,Eh,2ff)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
+        out = jnp.einsum("bsef,efd->bsed", h, p.wo)          # (B,S,Eh,d)
+        y = jnp.einsum("bsed,bse->bsd", out.astype(jnp.float32), gates)
+        y = y.astype(x.dtype)
     if p.shared_wi is not None:
-        hs = x @ p.shared_wi
-        gs, us = jnp.split(hs, 2, axis=-1)
-        y = y + (jax.nn.silu(gs.astype(jnp.float32)).astype(x.dtype)
-                 * us) @ p.shared_wo
-    return y, aux
+        with jax.named_scope("shared"):
+            hs = x @ p.shared_wi
+            gs, us = jnp.split(hs, 2, axis=-1)
+            y = y + (jax.nn.silu(gs.astype(jnp.float32)).astype(x.dtype)
+                     * us) @ p.shared_wo
+    held = jnp.sum(picked, axis=-1, dtype=jnp.int32)
+    return y, aux, held
 
 
 def moe_gshard_einsum(

@@ -77,12 +77,13 @@ def _worker_main(wid: int, inq, outq, payload: Dict[str, Any]) -> None:
     Protocol (worker -> orchestrator, all through ``outq``):
       ``("hello", wid, plan_hash)``      handshake, first message
       ``("beat", wid, t)``               heartbeat (idle and per chunk)
-      ``("chunk", wid, rid, idx, k, v)`` one pool-block-shaped KV slab
+      ``("chunk", wid, rid, idx, rows)`` one pool-block-shaped slab of
+                                         each cache pool (``{pool: rows}``)
       ``("done", wid, rid, logits)``     last-token logits, prompt done
       ``("error", wid, rid, msg)``       prefill raised (typed, not a crash)
 
     Instructions (orchestrator -> worker, through ``inq``):
-      ``("prefill", rid, tail_tokens, prefix_k, prefix_v)``
+      ``("prefill", rid, tail_tokens, prefix)`` (``{pool: rows}`` or None)
       ``("stop",)``
     """
     import jax
@@ -104,7 +105,7 @@ def _worker_main(wid: int, inq, outq, payload: Dict[str, Any]) -> None:
     # one long-lived jit so the per-(prefix, tail) shape compile cache
     # survives across prompts
     tail_fn = jax.jit(
-        lambda p, b, pk, pv: lm.prefill_tail(arch, p, b, cfg, pk, pv))
+        lambda p, b, pre: lm.prefill_tail(arch, p, b, cfg, pre))
     while True:
         try:
             msg = inq.get(timeout=hb)
@@ -113,20 +114,19 @@ def _worker_main(wid: int, inq, outq, payload: Dict[str, Any]) -> None:
             continue
         if msg[0] == "stop":
             return
-        _, rid, tokens, pk, pv = msg
+        _, rid, tokens, prefix = msg
 
-        def on_chunk(idx, kc, vc, _rid=rid):
+        def on_chunk(idx, rows, _rid=rid):
             if delay:
                 time.sleep(delay)   # chaos knob: widen the kill window
-            outq.put(("chunk", wid, _rid, idx, np.asarray(kc),
-                      np.asarray(vc)))
+            outq.put(("chunk", wid, _rid, idx,
+                      {k: np.asarray(v) for k, v in rows.items()}))
             outq.put(("beat", wid, time.time()))
 
         try:
-            logits, _, _ = lm.prefill_chunked(
+            logits, _ = lm.prefill_chunked(
                 arch, params, tokens, bl, cfg, kv_heads=kvh,
-                prefix_k=pk, prefix_v=pv, on_chunk=on_chunk,
-                tail_fn=tail_fn)
+                prefix=prefix, on_chunk=on_chunk, tail_fn=tail_fn)
             outq.put(("done", wid, rid, np.asarray(logits)))
         except Exception as e:      # noqa: BLE001 — typed event, no crash
             outq.put(("error", wid, rid, f"{type(e).__name__}: {e}"))
@@ -245,12 +245,12 @@ class PrefillFleet:
             self._handle(msg, [])
 
     # ------------------------------------------------------------------
-    def dispatch(self, rid: int, tokens, prefix_k=None,
-                 prefix_v=None) -> bool:
+    def dispatch(self, rid: int, tokens, prefix=None) -> bool:
         """Send one prompt (tail tokens past any journaled prefix) to
         the least-loaded live worker.  ``False`` when no worker is
         live right now (all between death and respawn, or retired) —
-        the caller retries next poll or degrades."""
+        the caller retries next poll or degrades.  ``prefix`` maps
+        each cache pool to the journaled rows a resume starts after."""
         live = [s for s in self._slots
                 if not s.retired and s.proc is not None
                 and s.proc.is_alive()]
@@ -259,8 +259,8 @@ class PrefillFleet:
         slot = min(live, key=lambda s: (len(s.inflight), s.idx))
         tokens = np.asarray(tokens, np.int32)
         slot.inq.put(("prefill", rid, tokens,
-                      None if prefix_k is None else np.asarray(prefix_k),
-                      None if prefix_v is None else np.asarray(prefix_v)))
+                      None if prefix is None else
+                      {k: np.asarray(v) for k, v in prefix.items()}))
         slot.inflight.append(rid)
         self._assign[rid] = slot
         self.dispatches += 1
@@ -311,9 +311,9 @@ class PrefillFleet:
                     "geometry; refusing to exchange KV blocks")
             slot.ready = True
         elif kind == "chunk":
-            _, _, rid, idx, k, v = msg
+            _, _, rid, idx, rows = msg
             if rid in self._assign:
-                events.append(("chunk", rid, idx, k, v))
+                events.append(("chunk", rid, idx, rows))
         elif kind == "done":
             _, _, rid, logits = msg
             if rid in self._assign:
@@ -329,7 +329,7 @@ class PrefillFleet:
 
     def poll(self) -> List[Tuple]:
         """Drain worker messages and supervise the fleet.  Returns
-        engine-facing events: ``("chunk", rid, idx, k, v)``,
+        engine-facing events: ``("chunk", rid, idx, rows)``,
         ``("done", rid, logits)``, ``("error", rid, msg)``, and
         ``("dead", rid)`` for every request a worker death orphaned.
         Also respawns due slots and raises the degraded flag when the

@@ -77,7 +77,8 @@ prefill calls), while hybrid archs still prefill the full feed (their
 SSM states need every token) but share the matched blocks' capacity.
 Writers never mutate shared state: a copy-on-write barrier before each
 decode tick copies any shared append block into a freshly granted one
-(one jitted gather-scatter of k/v rows plus the table entry).  The
+(one jitted gather-scatter of the block's rows in every cache pool plus
+the table entry).  The
 degradation ladder is sharing-aware — migration refuses to move shared
 blocks and victim selection prefers requests pinning the fewest —
 and trie entries are pruned exactly when their blocks return to the
@@ -116,6 +117,11 @@ it:
 
 With tiering off (``kv_host_blocks=0``, the default) every path keeps
 its exact pre-tier semantics.
+
+Every block mover — spill, promote, prefetch, copy-on-write, prefix
+alias, migration, park and the disaggregated chunk stream — carries
+whatever pools the cache kind holds (:func:`repro.models.lm.cache_rows`:
+per-head ``k``/``v``, or latent attention's one ``ckv`` row a token).
 
 Prefill itself is a plan decision (``kv_prefill_mode``): when the
 interference model says a worst-case inline prefill would steal too
@@ -265,7 +271,7 @@ class PreemptedRequest:
     request: Request
     not_before_tick: int
     # tiered park: {"slot_len": int, "kv_host": [host ids]} for paged
-    # KV, {"kv_rows": (k, v)} for dense stripes, plus "ssm"/"conv"
+    # KV, {"kv_rows": {pool: rows}} for dense stripes, plus "ssm"/"conv"
     # host copies when the arch carries them
     parked_state: Optional[Dict[str, Any]] = None
 
@@ -311,6 +317,9 @@ class ServeEngine:
                 f"kv_host_blocks must be >= 0, got {kv_host_blocks}")
         self.arch, self.params, self.cfg = arch, params, cfg
         self.plan = None               # set by from_plan()
+        # the cache pools every block mover carries (k/v per kv head,
+        # or MLA's latent row): ``lm.cache_rows`` names them
+        self._pools = tuple(lm.cache_rows(arch, kv_heads))
         self.max_batch, self.max_len = max_batch, max_len
         self.kv_admission = kv_admission
         self.preemption = preemption or PreemptionPolicy()
@@ -416,8 +425,8 @@ class ServeEngine:
         # here).  Insertion-ordered — the spill scheduler works
         # oldest-first.  Only populated with tiering on.
         self._cached: Dict[int, None] = {}
-        # prefetch staging: rid -> (host id tuple, k rows, v rows) put
-        # on device one tick before the parked request's re-admission
+        # prefetch staging: rid -> (host id tuple, {pool: rows}) put on
+        # device one tick before the parked request's re-admission
         self._staged: Dict[int, Any] = {}
         # admission-scoped map of promoted ids (old host id -> new HBM
         # id) so a second request matching the same just-promoted block
@@ -476,23 +485,27 @@ class ServeEngine:
         # rows drawn by the batched program / by per-row ``_sample``
         self.sampled_batched = 0
         self.sampled_per_slot = 0
+        # token-expert pairs the held-expert layer computed, by phase
+        # (the model programs' ``moe_held_pairs`` counter)
+        self.moe_held_pairs = {"prefill": 0, "decode": 0}
 
         # each program is a named function, so that its module in a
-        # profiler trace reads jit_serve_<name>
+        # profiler trace reads jit_serve_<name>; each model program also
+        # returns its counters (``lm.decode_step``'s ``stats``)
         def serve_decode(p, c, b):
-            return lm.decode_step(arch, p, c, b, cfg)
+            return lm.decode_step(arch, p, c, b, cfg, stats=True)
 
         def serve_prefill(p, b):
-            return lm.prefill(arch, p, b, cfg, max_len=max_len)
+            return lm.prefill(arch, p, b, cfg, max_len=max_len, stats=True)
 
-        def serve_prefill_tail(p, b, pk, pv):
-            return lm.prefill_tail(arch, p, b, cfg, pk, pv)
+        def serve_prefill_tail(p, b, pre):
+            return lm.prefill_tail(arch, p, b, cfg, pre, stats=True)
 
-        def serve_cow(k, v, tbl, old, new, slot, col):
-            """CoW: duplicate one pool block's k/v rows and repoint one
-            table entry, in a single gather-scatter."""
-            return (k.at[:, new].set(k[:, old]),
-                    v.at[:, new].set(v[:, old]),
+        def serve_cow(pools, tbl, old, new, slot, col):
+            """CoW: duplicate one block's rows in every cache pool and
+            repoint one table entry, in a single gather-scatter."""
+            return ({k: v.at[:, new].set(v[:, old])
+                     for k, v in pools.items()},
                     tbl.at[slot, col].set(new))
 
         # tier migration: batched whole-block gather/scatter between
@@ -503,26 +516,36 @@ class ServeEngine:
         def serve_scatter_blocks(pool, ids, rows):
             return lm.scatter_blocks(pool, ids, rows)
 
-        def serve_sample(logits, temps, keys, sampled):
+        def serve_sample(logits, temps, keys, sampled, counts=None):
             """Every row's next token by ``_sample``'s rule: argmax where
             the row's temperature is <= 0, a categorical draw with its
             key where it is > 0.  ``sampled`` (static) is False when no
-            row draws, so a greedy batch pays for no Gumbel noise."""
+            row draws, so a greedy batch pays for no Gumbel noise.
+            ``counts`` (per-row int32 counters of the program sampled
+            from) ride home in the same transfer, stacked under the
+            tokens."""
             rows = logits[:, :arch.vocab_size].astype(jnp.float32)
-            greedy = jnp.argmax(rows, axis=-1).astype(jnp.int32)
-            if not sampled:
-                return greedy
-            hot = temps > 0
-            drawn = jax.vmap(jax.random.categorical)(
-                keys, rows / jnp.where(hot, temps, 1.0)[:, None])
-            return jnp.where(hot, drawn.astype(jnp.int32), greedy)
+            toks = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+            if sampled:
+                hot = temps > 0
+                drawn = jax.vmap(jax.random.categorical)(
+                    keys, rows / jnp.where(hot, temps, 1.0)[:, None])
+                toks = jnp.where(hot, drawn.astype(jnp.int32), toks)
+            if counts is None:
+                return toks
+            return jnp.stack([toks, counts.astype(jnp.int32)])
 
-        self._decode = jax.jit(serve_decode)
+        # the decode updates the cache in place: donated, the pools are
+        # not held twice across the call
+        self._decode = jax.jit(serve_decode, donate_argnums=1)
         self._prefill = jax.jit(serve_prefill)
         self._prefill_tail = jax.jit(serve_prefill_tail)
-        self._cow_kernel = jax.jit(serve_cow)
+        # block writes update a pool in place: donated, no write holds
+        # a second copy of a pool (a latent pool is 5.4 GB of 16)
+        self._cow_kernel = jax.jit(serve_cow, donate_argnums=0)
         self._gather_blocks = jax.jit(serve_gather_blocks)
-        self._scatter_blocks = jax.jit(serve_scatter_blocks)
+        self._scatter_blocks = jax.jit(serve_scatter_blocks,
+                                       donate_argnums=0)
         self._sample_rows = jax.jit(serve_sample, static_argnames="sampled")
 
     # ------------------------------------------------------------------
@@ -879,7 +902,8 @@ class ServeEngine:
                 "disagg_resumes": self.disagg_resumes,
                 "disagg_inflight": len(self._disagg),
                 "sampled_batched": self.sampled_batched,
-                "sampled_per_slot": self.sampled_per_slot}
+                "sampled_per_slot": self.sampled_per_slot,
+                "moe_held_pairs": dict(self.moe_held_pairs)}
 
     def telemetry(self) -> Dict[str, Any]:
         """One JSON-serializable snapshot of everything the engine
@@ -1032,8 +1056,8 @@ class ServeEngine:
 
     # ---------------- tier transitions + spill scheduler --------------
     def _spill_rows(self, pairs: List[Tuple[int, int]]) -> None:
-        """Copy the k/v rows of just-spilled blocks into the host pool
-        (one batched device→host gather per tensor).  The vacated HBM
+        """Copy the rows of just-spilled blocks into the host pool (one
+        batched device→host gather per cache pool).  The vacated HBM
         ids are already back on their free lists, but their rows stay
         intact until a next tenant writes — the copy races nothing."""
         with span("serve.spill", blocks=len(pairs)):
@@ -1041,29 +1065,28 @@ class ServeEngine:
                                              np.int32))
             idx = np.asarray([h - self.n_blocks for _, h in pairs],
                              np.int64)
-            self._host["k"][:, idx] = np.asarray(
-                self._gather_blocks(self.cache["k"], old_ids))
-            self._host["v"][:, idx] = np.asarray(
-                self._gather_blocks(self.cache["v"], old_ids))
+            for key in self._pools:
+                self._host[key][:, idx] = np.asarray(
+                    self._gather_blocks(self.cache[key], old_ids))
 
     def _promote_rows(self, pairs: List[Tuple[int, int]],
-                      k_rows=None, v_rows=None) -> None:
-        """Copy spilled k/v rows back into the device pool at the
-        pairs' new HBM ids — from the prefetcher's staged device arrays
-        when they landed, else a synchronous host→device transfer (the
-        stall ``kv_prefetch="off"`` benchmarks)."""
+                      rows: Optional[Dict[str, Any]] = None) -> None:
+        """Copy spilled rows back into the device pools at the pairs'
+        new HBM ids — from the prefetcher's staged device arrays
+        (``rows``, one per pool) when they landed, else a synchronous
+        host→device transfer (the stall ``kv_prefetch="off"``
+        benchmarks)."""
         with span("serve.promote", blocks=len(pairs)):
             idx = np.asarray([h - self.n_blocks for h, _ in pairs],
                              np.int64)
             new_ids = jnp.asarray(np.asarray([b for _, b in pairs],
                                              np.int32))
-            if k_rows is None:
-                k_rows = jnp.asarray(self._host["k"][:, idx])
-                v_rows = jnp.asarray(self._host["v"][:, idx])
-            self.cache["k"] = self._scatter_blocks(self.cache["k"],
-                                                   new_ids, k_rows)
-            self.cache["v"] = self._scatter_blocks(self.cache["v"],
-                                                   new_ids, v_rows)
+            if rows is None:
+                rows = {key: jnp.asarray(self._host[key][:, idx])
+                        for key in self._pools}
+            for key in self._pools:
+                self.cache[key] = self._scatter_blocks(self.cache[key],
+                                                       new_ids, rows[key])
 
     def _promote_matched(self, matched: List[int],
                          group: int) -> List[int]:
@@ -1292,19 +1315,17 @@ class ServeEngine:
             if blocks is None:
                 return False       # placement said yes; lost the race
             r.blocks = blocks
-        pk = pv = None
+        prefix = None
         if start_block:
             ids = jnp.asarray(np.asarray(r.blocks[:start_block], np.int32))
-            pk = np.asarray(self._gather_blocks(self.cache["k"], ids))
-            pv = np.asarray(self._gather_blocks(self.cache["v"], ids))
-            L = pk.shape[0]
             m = start_block * self.block_len
-            pk = pk.reshape(L, m, *pk.shape[3:])
-            pv = pv.reshape(L, m, *pv.shape[3:])
+            prefix = {}
+            for key in self._pools:
+                rows = np.asarray(self._gather_blocks(self.cache[key], ids))
+                prefix[key] = rows.reshape(rows.shape[0], m, *rows.shape[3:])
         feed = r.feed_tokens
         ok = self._fleet.dispatch(
-            r.rid, feed[start_block * self.block_len:],
-            prefix_k=pk, prefix_v=pv)
+            r.rid, feed[start_block * self.block_len:], prefix=prefix)
         if not ok:
             if fresh and r.blocks:
                 self._release_blocks(r.blocks)
@@ -1321,9 +1342,10 @@ class ServeEngine:
         return True
 
     def _on_chunk(self, fl: _DisaggFlight, idx: int,
-                  k_rows, v_rows) -> None:
-        """Scatter one streamed pool-block-shaped KV slab into the
-        paged pool (the tier-spill mover run in reverse) and advance
+                  rows: Dict[str, Any]) -> None:
+        """Scatter one streamed pool-block-shaped slab of each cache
+        pool into the paged pools (the tier-spill mover run in reverse)
+        and advance
         the journal.  Chunks re-sent after a re-dispatch overwrite
         bit-identical rows — idempotent by the chunked-prefill
         contract.  Requests with no blocks (satisfied by the prefill
@@ -1331,23 +1353,20 @@ class ServeEngine:
         r = fl.request
         if not r.blocks or idx >= len(r.blocks) or idx > fl.acked:
             return
-        k_rows = np.asarray(k_rows)
-        v_rows = np.asarray(v_rows)
-        t = k_rows.shape[1]
-        if t < self.block_len:
-            # partial tail block: pad to block shape (slot_len masks
-            # the zero rows, exactly like the inline scatter's clamp)
-            shape = (k_rows.shape[0], self.block_len, *k_rows.shape[2:])
-            kf = np.zeros(shape, k_rows.dtype)
-            vf = np.zeros(shape, v_rows.dtype)
-            kf[:, :t] = k_rows
-            vf[:, :t] = v_rows
-            k_rows, v_rows = kf, vf
         bid = jnp.asarray(np.asarray([r.blocks[idx]], np.int32))
-        self.cache["k"] = self._scatter_blocks(
-            self.cache["k"], bid, jnp.asarray(k_rows)[:, None])
-        self.cache["v"] = self._scatter_blocks(
-            self.cache["v"], bid, jnp.asarray(v_rows)[:, None])
+        t = 0
+        for key in self._pools:
+            part = np.asarray(rows[key])
+            t = part.shape[1]
+            if t < self.block_len:
+                # partial tail block: pad to block shape (slot_len masks
+                # the zero rows, exactly like the inline scatter's clamp)
+                full = np.zeros((part.shape[0], self.block_len,
+                                 *part.shape[2:]), part.dtype)
+                full[:, :t] = part
+                part = full
+            self.cache[key] = self._scatter_blocks(
+                self.cache[key], bid, jnp.asarray(part)[:, None])
         self.disagg_chunks += 1
         if t == self.block_len and idx == fl.acked:
             fl.acked = idx + 1
@@ -1470,7 +1489,7 @@ class ServeEngine:
             if kind == "chunk":
                 fl = self._disagg.get(ev[1])
                 if fl is not None:
-                    self._on_chunk(fl, ev[2], ev[3], ev[4])
+                    self._on_chunk(fl, ev[2], ev[3])
             elif kind == "done":
                 fl = self._disagg.get(ev[1])
                 if fl is not None:
@@ -1659,16 +1678,16 @@ class ServeEngine:
                     blk = np.concatenate(
                         [blk, np.repeat(blk[:1], padded - len(group), axis=0)])
                 bid = jnp.asarray(blk.reshape(-1))
-                pk = self.cache["k"][:, bid]
-                pv = self.cache["v"][:, bid]
-                L = pk.shape[0]
-                pk = pk.reshape(L, padded, m_tok, *pk.shape[3:])
-                pv = pv.reshape(L, padded, m_tok, *pv.shape[3:])
-                logits, tail_k, tail_v = self._prefill_tail(
-                    self.params, {"tokens": jnp.asarray(toks)}, pk, pv)
+                pre = {}
+                for key in self._pools:
+                    rows = self._gather_blocks(self.cache[key], bid)
+                    pre[key] = rows.reshape(rows.shape[0], padded, m_tok,
+                                            *rows.shape[3:])
+                logits, tail, stats = self._prefill_tail(
+                    self.params, {"tokens": jnp.asarray(toks)}, pre)
             else:
-                logits, cacheg = self._prefill(self.params,
-                                               {"tokens": jnp.asarray(toks)})
+                logits, cacheg, stats = self._prefill(
+                    self.params, {"tokens": jnp.asarray(toks)})
             self.prefill_calls += 1
             self.prefill_batches.append(len(group))
             live: List[Request] = []
@@ -1682,7 +1701,7 @@ class ServeEngine:
                 # a resumed request's row, like a dummy row, is not drawn
                 toks = self._draw(logits, keys, {
                     i: r for i, r in enumerate(group) if not r.out_tokens},
-                    batched)
+                    batched, stats, "prefill", range(len(group)))
                 for i, r in enumerate(group):
                     if r.out_tokens:
                         # resumed after preemption: keep the retained tokens,
@@ -1715,13 +1734,13 @@ class ServeEngine:
             if self.arch.has_attention:
                 if self.kv_residency == "paged":
                     if m_tok:
-                        self._scatter_tail(live, slots, gidx, tail_k, tail_v,
+                        self._scatter_tail(live, slots, gidx, tail,
                                            plen, m_tok, live_matches)
                     else:
                         self._scatter_paged_prefill(live, slots, gidx, cacheg,
                                                     plen, live_matches)
                 else:
-                    for key in ("k", "v"):
+                    for key in self._pools:
                         self.cache[key] = self.cache[key].at[:, slots].set(
                             cacheg[key][:, gidx])
             for key in ("ssm", "conv"):
@@ -1747,7 +1766,7 @@ class ServeEngine:
                                gidx: np.ndarray, cacheg, plen: int,
                                matches: Optional[List[List[int]]] = None
                                ) -> None:
-        """Move a bucket's prefilled KV rows into their pool blocks.
+        """Move a bucket's prefilled cache rows into their pool blocks.
 
         Each survivor gets its admission block budget now — matched
         blocks aliased with a refcount bump, the rest freshly allocated
@@ -1783,7 +1802,7 @@ class ServeEngine:
                 ent_col.append(c)
                 ent_blk.append(r.blocks[c])
         if ent_blk:
-            S = cacheg["k"].shape[2]
+            S = cacheg[self._pools[0]].shape[2]
             req = jnp.asarray(np.asarray(ent_req, np.int32)[:, None])
             ridx = np.asarray(ent_col, np.int32)[:, None] * bl \
                 + np.arange(bl, dtype=np.int32)[None, :]
@@ -1792,17 +1811,19 @@ class ServeEngine:
             # append overwrites them
             ridx = jnp.asarray(np.minimum(ridx, S - 1))
             blk_ids = jnp.asarray(np.asarray(ent_blk, np.int32))
-            for key in ("k", "v"):
-                upd = cacheg[key][:, req, ridx]        # (L, E, bl, K, hd)
-                self.cache[key] = self.cache[key].at[:, blk_ids].set(upd)
+            for key in self._pools:
+                upd = cacheg[key][:, req, ridx]        # (L, E, bl, *row)
+                self.cache[key] = self._scatter_blocks(self.cache[key],
+                                                       blk_ids, upd)
         self.cache["block_tbl"] = \
             self.cache["block_tbl"].at[slots].set(jnp.asarray(rows))
 
     def _scatter_tail(self, live: List[Request], slots: np.ndarray,
-                      gidx: np.ndarray, tail_k, tail_v, plen: int,
+                      gidx: np.ndarray, tail: Dict[str, Any], plen: int,
                       m_tok: int, matches: List[List[int]]) -> None:
         """Install aliased-prefix block tables and scatter the
-        tail-only prefill's K/V rows into freshly granted blocks (the
+        tail-only prefill's rows of each pool into freshly granted
+        blocks (the
         compute-skip counterpart of :meth:`_scatter_paged_prefill`:
         the first ``m_tok`` rows were never recomputed — their blocks
         are aliased as-is)."""
@@ -1827,14 +1848,15 @@ class ServeEngine:
         ntb = nbp - nbm
         blk_ids = jnp.asarray(np.asarray(tail_blocks, np.int32))
         T = plen - m_tok
-        for key, src in (("k", tail_k), ("v", tail_v)):
-            upd = src[:, gidx]                         # (L, Bs, T, K, hd)
+        for key in self._pools:
+            upd = tail[key][:, gidx]                   # (L, Bs, T, *row)
             L = upd.shape[0]
             if T < ntb * bl:
-                upd = jnp.pad(upd, ((0, 0), (0, 0), (0, ntb * bl - T),
-                                    (0, 0), (0, 0)))
+                upd = jnp.pad(upd, ((0, 0), (0, 0), (0, ntb * bl - T))
+                              + ((0, 0),) * (upd.ndim - 3))
             upd = upd.reshape(L, len(live) * ntb, bl, *upd.shape[3:])
-            self.cache[key] = self.cache[key].at[:, blk_ids].set(upd)
+            self.cache[key] = self._scatter_blocks(self.cache[key],
+                                                   blk_ids, upd)
         self.cache["block_tbl"] = \
             self.cache["block_tbl"].at[slots].set(jnp.asarray(rows))
 
@@ -1924,17 +1946,18 @@ class ServeEngine:
                 self._preempt_for(r)
 
     def _cow_copy(self, r: Request, col: int, fresh: int) -> None:
-        """Copy ``r``'s shared append block into ``fresh`` (k/v rows +
-        table entry, one jitted gather-scatter) and drop this holder's
+        """Copy ``r``'s shared append block into ``fresh`` (its rows in
+        every pool + table entry, one jitted gather-scatter) and drop
+        this holder's
         reference to the original — the sharers keep it resident, trie
         entry and all."""
         old = r.blocks[col]
         with span("serve.cow", rid=r.rid):
-            k, v, tbl = self._cow_kernel(
-                self.cache["k"], self.cache["v"], self.cache["block_tbl"],
-                np.int32(old), np.int32(fresh), np.int32(r.slot),
-                np.int32(col))
-            self.cache["k"], self.cache["v"] = k, v
+            pools, tbl = self._cow_kernel(
+                {key: self.cache[key] for key in self._pools},
+                self.cache["block_tbl"], np.int32(old), np.int32(fresh),
+                np.int32(r.slot), np.int32(col))
+            self.cache.update(pools)
             self.cache["block_tbl"] = tbl
             r.blocks[col] = fresh
             self._release_blocks([old])
@@ -1978,9 +2001,10 @@ class ServeEngine:
         if old:
             old_ids = jnp.asarray(old, jnp.int32)
             new_ids = jnp.asarray(new_blocks[:len(old)], jnp.int32)
-            for key in ("k", "v"):
-                self.cache[key] = self.cache[key].at[:, new_ids].set(
-                    self.cache[key][:, old_ids])
+            for key in self._pools:
+                self.cache[key] = self._scatter_blocks(
+                    self.cache[key], new_ids,
+                    self._gather_blocks(self.cache[key], old_ids))
         for key in ("ssm", "conv"):
             if key in self.cache:
                 self.cache[key] = self.cache[key].at[:, s2].set(
@@ -2109,8 +2133,8 @@ class ServeEngine:
                 self.cache["block_tbl"].at[slot].set(-1)
         elif self.arch.has_attention:
             n = st["slot_len"]
-            st["kv_rows"] = (np.asarray(self.cache["k"][:, slot, :n]),
-                             np.asarray(self.cache["v"][:, slot, :n]))
+            st["kv_rows"] = {key: np.asarray(self.cache[key][:, slot, :n])
+                             for key in self._pools}
         for key in ("ssm", "conv"):
             if key in self.cache:
                 st[key] = np.asarray(self.cache[key][:, slot])
@@ -2208,7 +2232,7 @@ class ServeEngine:
             pairs = self._alloc.promote(host_ids, g)
             assert pairs is not None, "free count was just checked"
             if staged is not None and staged[0] == tuple(host_ids):
-                self._promote_rows(pairs, staged[1], staged[2])
+                self._promote_rows(pairs, staged[1])
             else:
                 self._promote_rows(pairs)
             r.blocks = [b for _, b in pairs]
@@ -2223,13 +2247,10 @@ class ServeEngine:
                                     r.blocks[:len(r.prefix_hashes)], g)
         else:
             slot = min(self.free_slots)
-            if "kv_rows" in st:
-                n = st["slot_len"]
-                k_rows, v_rows = st["kv_rows"]
-                self.cache["k"] = self.cache["k"].at[:, slot, :n].set(
-                    jnp.asarray(k_rows))
-                self.cache["v"] = self.cache["v"].at[:, slot, :n].set(
-                    jnp.asarray(v_rows))
+            n = st["slot_len"]
+            for key, rows in st.get("kv_rows", {}).items():
+                self.cache[key] = self.cache[key].at[:, slot, :n].set(
+                    jnp.asarray(rows))
         for key in ("ssm", "conv"):
             if key in st:
                 self.cache[key] = self.cache[key].at[:, slot].set(
@@ -2279,9 +2300,9 @@ class ServeEngine:
                 continue
             idx = np.asarray([h - self.n_blocks for h in ids], np.int64)
             with span("serve.promote", blocks=len(ids), rid=rid):
-                self._staged[rid] = (
-                    ids, jax.device_put(self._host["k"][:, idx]),
-                    jax.device_put(self._host["v"][:, idx]))
+                self._staged[rid] = (ids, {
+                    key: jax.device_put(self._host[key][:, idx])
+                    for key in self._pools})
 
     # ------------------------------------------------------------------
     def _next_key(self) -> jax.Array:
@@ -2308,15 +2329,24 @@ class ServeEngine:
                 is ServeEngine._own_sample)
 
     def _draw(self, logits: jax.Array, keys: jax.Array,
-              rows: Dict[int, Request], batched: bool) -> Dict[int, int]:
+              rows: Dict[int, Request], batched: bool,
+              stats: Optional[Dict[str, jax.Array]] = None,
+              phase: str = "", live=()) -> Dict[int, int]:
         """The next token of each request in ``rows`` (logits row index
         -> request), with ``keys[i]`` for row ``i``.  Batched: one
         ``serve_sample`` over every row of ``logits`` (rows not in
         ``rows`` are drawn greedily and discarded) and one transfer;
-        otherwise ``_sample`` row by row, in ``rows``' order."""
-        if not rows:
-            return {}
-        if not batched:
+        otherwise ``_sample`` row by row, in ``rows``' order.
+
+        ``stats`` are the counters of the program that made ``logits``:
+        its per-row ``moe_held_pairs`` ride home in the same transfer
+        and the rows in ``live`` add to ``self.moe_held_pairs[phase]``."""
+        counts = (stats or {}).get("moe_held_pairs")
+        if not rows or not batched:
+            if counts is not None:
+                self._count_pairs(phase, np.asarray(counts), live)
+            if not rows:
+                return {}
             self.sampled_per_slot += len(rows)
             return {i: int(self._sample(r, logits[i], keys[i]))
                     for i, r in rows.items()}
@@ -2327,10 +2357,23 @@ class ServeEngine:
             # a padded prefill batch: dummy rows need a key of any value
             keys = jnp.pad(keys, ((0, logits.shape[0] - keys.shape[0]),
                                   (0, 0)))
+        extra = {} if counts is None else {"counts": counts}
         toks = np.asarray(self._sample_rows(
-            logits, temps, keys, sampled=bool((temps > 0).any())))
+            logits, temps, keys, sampled=bool((temps > 0).any()), **extra))
+        if counts is not None:
+            toks, got = toks
+            self._count_pairs(phase, got, live)
         self.sampled_batched += len(rows)
         return {i: int(toks[i]) for i in rows}
+
+    def _count_pairs(self, phase: str, counts: np.ndarray, live) -> None:
+        """Add the ``live`` rows' held pairs to the counter, and mark
+        them on the trace (``serve.moe``, an instant whose ``pairs``
+        and ``phase`` the benchmark's readers sum per tick)."""
+        n = int(counts[list(live)].sum())
+        self.moe_held_pairs[phase] += n
+        with span("serve.moe", phase=phase, pairs=n):
+            pass
 
     def _sync_pos(self) -> None:
         """Mirror per-slot lengths into the device cache (freed slots 0)."""
@@ -2385,7 +2428,7 @@ class ServeEngine:
                     # output
                     tokens[slot, 0] = (r.out_tokens[-1] if r.out_tokens
                                        else int(r.feed_tokens[-1]))
-                logits, self.cache = self._decode(
+                logits, self.cache, stats = self._decode(
                     self.params, self.cache, {"tokens": jnp.asarray(tokens)})
             # the first sample also waits for the decode dispatched above
             batched = self._samples_batched()
@@ -2395,7 +2438,8 @@ class ServeEngine:
                 slot_keys = jax.random.split(self._next_key(),
                                              self.max_batch)
                 toks = self._draw(logits, slot_keys, dict(self.active),
-                                  batched)
+                                  batched, stats, "decode",
+                                  list(self.active))
                 finished = []
                 for slot, r in list(self.active.items()):
                     r.out_tokens.append(toks[slot])

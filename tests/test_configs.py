@@ -17,6 +17,7 @@ LABELED = {
     "deepseek-coder-33b": (32, 34.5),
     "minitron-8b": (7.3, 8.6),
     "hymba-1.5b": (1.4, 1.8),
+    "deepseek-v2-lite": (15.5, 16.0),
 }
 
 ACTIVE = {
@@ -41,11 +42,11 @@ def test_active_params(name):
 
 def test_cells_total_40():
     cells = all_cells()
-    assert len(cells) == 40
+    assert len(cells) == 44
     runnable = [c for c in cells if c[2]]
     skipped = [c for c in cells if not c[2]]
-    assert len(skipped) == 9          # DESIGN.md §5
-    assert len(runnable) == 31
+    assert len(skipped) == 10         # DESIGN.md §5, + deepseek-v2-lite
+    assert len(runnable) == 34
     for _, _, ok, why in skipped:
         assert why
 

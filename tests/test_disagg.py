@@ -53,11 +53,11 @@ def test_chunked_prefill_bitwise_identical_to_dense():
             arch, params, {"tokens": jnp.asarray(p[None])}, cfg,
             max_len=64)
         chunks = []
-        lg_c, ks, vs = lm.prefill_chunked(
+        lg_c, rows = lm.prefill_chunked(
             arch, params, p, bl, cfg, kv_heads=cache["k"].shape[3],
-            on_chunk=lambda i, k, v: chunks.append(i))
-        k_c = np.asarray(jnp.concatenate(ks, axis=1))
-        v_c = np.asarray(jnp.concatenate(vs, axis=1))
+            on_chunk=lambda i, r: chunks.append(i))
+        k_c = np.asarray(jnp.concatenate([r["k"] for r in rows], axis=1))
+        v_c = np.asarray(jnp.concatenate([r["v"] for r in rows], axis=1))
         assert chunks == list(range(-(-plen // bl)))
         assert (np.asarray(cache["k"][:, 0, :plen]) == k_c).all()
         assert (np.asarray(cache["v"][:, 0, :plen]) == v_c).all()

@@ -346,36 +346,38 @@ def test_kv_residency_plan_decision():
 
 def test_costmodel_kv_block_geometry():
     from repro.core.costmodel import kv_block_geometry
-    geo = kv_block_geometry(32768, 128, 80, 8, 128)
+    tok = 2 * 80 * 8 * 128 * 2      # K and V, 80 layers of 8x128, bf16
+    geo = kv_block_geometry(32768, 128, tok)
     assert geo.block_len == 512
     assert geo.blocks_per_seq == 64
     assert geo.n_blocks == 128 * 64           # uncapped: dense worst case
     assert geo.paged_bytes == geo.dense_bytes
     # a budget cap shrinks the pool but never below one full sequence
-    capped = kv_block_geometry(32768, 128, 80, 8, 128,
+    capped = kv_block_geometry(32768, 128, tok,
                                budget_bytes=geo.dense_bytes / 4)
     assert geo.n_blocks / 4.1 < capped.n_blocks <= geo.n_blocks // 4
-    tiny = kv_block_geometry(32768, 128, 80, 8, 128, budget_bytes=1.0)
+    tiny = kv_block_geometry(32768, 128, tok, budget_bytes=1.0)
     assert tiny.n_blocks == tiny.blocks_per_seq
     # zero headroom is a real cap (the one-sequence floor), NOT uncapped
-    zero = kv_block_geometry(32768, 128, 80, 8, 128, budget_bytes=0.0)
+    zero = kv_block_geometry(32768, 128, tok, budget_bytes=0.0)
     assert zero.n_blocks == zero.blocks_per_seq
     # 2-D: the data degree still divides capacity (the reclamation
     # bet), but the pool splits into data_shards sub-pools, each
     # model-aligned and never below one sequence — the 16x16 case's
     # raw 512-block target bumps to the 16 x 64-block sub-pool floor
-    dp = kv_block_geometry(32768, 128, 80, 8, 128, data_shards=16, align=16)
+    dp = kv_block_geometry(32768, 128, tok, data_shards=16, align=16)
     assert dp.data_degree == 16 and dp.model_degree == 16
     assert dp.n_blocks == 16 * 64           # 16 sub-pools at the floor
     assert dp.sub_pool_blocks == 64 and dp.n_blocks % (16 * 16) == 0
     assert dp.paged_bytes < dp.dense_bytes
-    wide = kv_block_geometry(32768, 2048, 80, 8, 128,
+    wide = kv_block_geometry(32768, 2048, tok,
                              data_shards=16, align=16)
     assert wide.n_blocks == 2048 * 64 // 16     # bet above the floor
     assert wide.sub_pool_blocks % 16 == 0
-    odd = kv_block_geometry(64, 3, 2, 2, 16, align=8)     # want=12 -> 8
+    small = 2 * 2 * 2 * 16 * 2
+    odd = kv_block_geometry(64, 3, small, align=8)        # want=12 -> 8
     assert odd.n_blocks == 8
-    floor = kv_block_geometry(64, 1, 2, 2, 16, align=8)   # per_seq=4 -> 8
+    floor = kv_block_geometry(64, 1, small, align=8)      # per_seq=4 -> 8
     assert floor.n_blocks == 8
     # prefix-reuse capacity math: r/(h + r(1-h)) approaches 1/(1-h),
     # headroom is (r-1)*floor(h*blocks_per_seq) capped at the sub-pool,

@@ -495,8 +495,8 @@ def test_kv_block_geometry_2d_invariants(seed):
         d = rng.choice([1, 2, 4, 8, 16])
         m = rng.choice([1, 2, 4, 8, 16])
         budget = rng.choice([None, 0.0, 2.0**rng.randint(20, 40)])
-        geo = kv_block_geometry(seq, batch, 4, 2, 64, budget_bytes=budget,
-                                data_shards=d, align=m)
+        geo = kv_block_geometry(seq, batch, 2 * 4 * 2 * 64 * 2,
+                                budget_bytes=budget, data_shards=d, align=m)
         # the pool always splits into d equal, model-shardable sub-pools
         assert geo.n_blocks % d == 0
         sub = geo.n_blocks // d

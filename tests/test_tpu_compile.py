@@ -105,3 +105,41 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     compiled = jax.jit(lambda *a, **k: fn(*a, **k)).lower(
         *map(sds, args), **{k: sds(v) for k, v in kwargs.items()}).compile()
     assert "tpu_custom_call" in compiled.as_text(), case
+
+
+def test_latent_decode_step_fits_one_v5e(one_chip):
+    """The serving decode of deepseek-v2-lite's one-chip share (8 of 64
+    experts held, 27 layers) over the plan's latent pool compiles for a
+    v5e and fits its HBM: the engine donates the cache, and no pass
+    may copy a 6 GiB pool (the compiler refuses a program that does not
+    fit)."""
+    import dataclasses
+
+    from repro.configs import ShapeConfig, get_arch
+    from repro.core.passes.lowering import build_run_cfg
+    from repro.core.pipeline import specialize
+    from repro.models import lm
+
+    arch = dataclasses.replace(get_arch("deepseek-v2-lite"),
+                               n_routed_experts=8)
+    plan = specialize(arch, ShapeConfig("serve", "decode", 6144, 32),
+                      mesh_axes=("data", "model"), mesh_shape=(1, 1),
+                      cache=False)
+    cfg = build_run_cfg(plan, arch, None)
+    e = plan.estimates
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda k: lm.init_params(arch, k), jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: lm.init_paged_cache(
+        arch, 32, 6144, int(e["kv_block_len"]), int(e["kv_n_blocks"]))))
+    tokens = jax.ShapeDtypeStruct((32, 1), I32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, c, b: lm.decode_step(arch, p, c, b, cfg, stats=True),
+        donate_argnums=1).lower(params, cache, {"tokens": tokens}).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 5 * 2**30          # the pool, in place
+    assert mem.temp_size_in_bytes < 2 * 2**30
