@@ -380,6 +380,15 @@ class DataOrganizationPass(Pass):
             f"partitioned across data) vs dense stripes "
             f"{geo.dense_bytes/n_chips/2**30:.2f} GiB/chip; freed slots "
             "return blocks to their sub-pool instead of masking rows")
+        if n_chips == 1 and not arch.is_mla \
+                and ctx.target.name.startswith("tpu"):
+            plan.estimates["decode_impl"] = "pallas_paged"
+            self.record(
+                ctx, "decode_impl", "pallas_paged",
+                "per-head K/V pool on one chip: the Pallas paged decode "
+                "streams each slot's live blocks from the stacked pool "
+                "in place, where the XLA gather reads every table column "
+                "of every slot")
 
     # ------------------------------------------------------------------
     def _pick_strategy(self, ctx: PassContext) -> str:

@@ -72,16 +72,18 @@ def _decode_kernel(
 
 
 def _paged_decode_kernel(
-    tbl_ref,      # SMEM (B, nb) int32 block table (scalar prefetch)
-    len_ref,      # SMEM (B,) int32 per-slot valid lengths (scalar prefetch)
+    fetch_ref,    # SMEM (B, nb) int32 pool block each step reads (scalar prefetch)
+    live_ref,     # SMEM (B, nb) int32 1 where the step's block is live
+    len_ref,      # SMEM (B,) int32 per-slot valid lengths
+    layer_ref,    # SMEM (1,) int32 layer of the stacked pools
+    win_ref,      # SMEM (1,) int32 attention window, 0 = full
     q_ref,        # (1, K, G, D)
-    k_ref,        # (1, block_len, K, D) — the slot's j-th block, all kv heads
-    v_ref,        # (1, block_len, K, D)
+    k_ref,        # (1, 1, block_len, K, D) — the step's block, all kv heads
+    v_ref,        # (1, 1, block_len, K, D)
     o_ref,        # (1, K, G, D)
     m_scr, l_scr, acc_scr,      # (K, G), (K, G), (K, G, D)
     *,
     block_len: int,
-    window: int,
     scale: float,
 ):
     b = pl.program_id(0)
@@ -94,31 +96,36 @@ def _paged_decode_kernel(
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     cache_len = len_ref[b]
-    # dense-view positions: block j covers rows [j*bl, (j+1)*bl); an
-    # unassigned (-1) table entry was clamped to block 0 by the index
-    # map, but its whole range sits past cache_len, so the mask kills it
-    kpos = j * block_len + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_len), 1)[0]
-    mask = kpos < cache_len
-    if window > 0:
-        mask &= (cache_len - 1 - kpos) < window
+    window = win_ref[0]
 
-    for h in range(q_ref.shape[1]):
-        q = q_ref[0, h].astype(jnp.float32) * scale      # (G, D)
-        k = k_ref[0, :, h].astype(jnp.float32)           # (block_len, D)
-        v = v_ref[0, :, h].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(mask[None, :], s, NEG_INF)
-        m_prev = m_scr[h]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[h] = m_cur
+    @pl.when(live_ref[b, j] != 0)
+    def _block():
+        def seen(shape, dim):
+            kpos = j * block_len + jax.lax.broadcasted_iota(
+                jnp.int32, shape, dim)
+            return (kpos < cache_len) & (
+                (window <= 0) | ((cache_len - 1 - kpos) < window))
+
+        s_mask = seen((1, block_len), 1)
+        v_mask = seen((block_len, 1), 0)
+        for h in range(q_ref.shape[1]):
+            q = q_ref[0, h] * jnp.asarray(scale, q_ref.dtype)  # (G, D)
+            k = k_ref[0, 0, :, h]                               # (bl, D)
+            # rows past cache_len hold a block's stale or unwritten
+            # rows: zero them, so a non-finite one never meets p == 0
+            v = jnp.where(v_mask, v_ref[0, 0, :, h], 0).astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(s_mask, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(s - m_cur[:, None])
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=-1)
+            acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[h] = m_cur
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finish():
@@ -126,28 +133,58 @@ def _paged_decode_kernel(
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _fetch_plan(tbl, cache_len, window, block_len):
+    """Which table entries the decode reads, and the block each grid
+    step fetches.
+
+    An entry is live when it is assigned and holds a row the query sees:
+    ``[cache_len - window, cache_len)``, or all of ``[0, cache_len)``
+    when ``window`` is 0.  A step on a dead entry names the block the
+    last live step before it fetched (the first live block, before any),
+    so Pallas, which skips the copy of a block index that has not
+    changed, issues one DMA per live block and none for the dead steps:
+    the tail past a slot's length, the columns before its window, and a
+    free slot's row."""
+    B, nb = tbl.shape
+    start = jnp.arange(nb, dtype=jnp.int32) * block_len
+    lo = jnp.where(window > 0, cache_len - window, 0)[:, None]
+    live = (start < cache_len[:, None]) & (start + block_len > lo) \
+        & (tbl >= 0)
+    step = jnp.arange(B * nb, dtype=jnp.int32)
+    flat = live.reshape(-1)
+    last = jax.lax.cummax(jnp.where(flat, step, -1))
+    src = jnp.where(last >= 0, last, jnp.argmax(flat))
+    fetch = jnp.maximum(tbl.reshape(-1)[src], 0).reshape(B, nb)
+    return fetch, live.astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(
     q: jax.Array,              # (B, H, D)
-    k_pool: jax.Array,         # (N, block_len, K, D) block pool
-    v_pool: jax.Array,         # (N, block_len, K, D)
+    k_pool: jax.Array,         # (L, N, bl, K, D) every layer's pool
+    v_pool: jax.Array,         # (L, N, bl, K, D)
     block_tbl: jax.Array,      # (B, nb) int32 block ids (-1 = unassigned)
     *,
     cache_len: jax.Array,      # (B,) or scalar int32 valid lengths
-    window: int = 0,
+    window=0,                  # int or int32 scalar; 0 = full attention
+    layer=0,                   # int or int32 scalar: the layer to read
     interpret: bool = False,
 ) -> jax.Array:
-    """Flash-decode over a paged cache: the block table streams the
-    slot's blocks through VMEM via scalar-prefetch indexed DMA.
+    """Flash-decode over a paged cache: each slot's live blocks stream
+    through VMEM via scalar-prefetch indexed DMA.
 
     The grid walks (batch, table_cols); each step's k/v BlockSpec index
-    map reads ``block_tbl[b, j]`` (prefetched to SMEM before the kernel
-    runs) to pick the pool block to DMA, all kv heads at once — the gather
-    never materializes a dense per-slot cache in HBM.  Semantics match
-    :func:`repro.kernels.ref.paged_decode_attention_ref`.
+    map reads the block to fetch from a table prefetched to SMEM before
+    the kernel runs (:func:`_fetch_plan`), all kv heads at once, from
+    ``layer`` of a stacked pool where it lies: neither the layer nor a
+    dense per-slot view is ever copied out in HBM.  Only live steps
+    compute; the others neither fetch nor compute.  A free slot (table
+    row all -1) reads as zeros, like an unassigned block in the oracle,
+    :func:`repro.kernels.ref.paged_decode_attention_ref`, whose
+    semantics this matches.
     """
     B, H, D = q.shape
-    N, block_len, K = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    block_len, K = k_pool.shape[2], k_pool.shape[3]
     nb = block_tbl.shape[1]
     G = H // K
     scale = D ** -0.5
@@ -155,24 +192,23 @@ def paged_decode_attention(
     qg = q.reshape(B, K, G, D)
     clen = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (B,))
-    tbl = jnp.asarray(block_tbl, jnp.int32)
+    win = jnp.asarray(window, jnp.int32)
+    fetch, live = _fetch_plan(jnp.asarray(block_tbl, jnp.int32), clen, win,
+                              block_len)
 
-    def kv_index(b, j, tbl_ref, len_ref):
-        # the pool is shared: the slot's table row picks the block; a
-        # block carries every kv head, so one DMA per (slot, block)
-        return (jnp.maximum(tbl_ref[b, j], 0), 0, 0, 0)
+    def kv_index(b, j, fetch_ref, live_ref, len_ref, layer_ref, win_ref):
+        return (layer_ref[0], fetch_ref[b, j], 0, 0, 0)
 
-    grid = (B, nb)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, block_len=block_len,
-                          window=window, scale=scale),
+                          scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
+            num_scalar_prefetch=5,
+            grid=(B, nb),
             in_specs=[
                 pl.BlockSpec((1, K, G, D), lambda b, j, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, block_len, K, D), kv_index),
-                pl.BlockSpec((1, block_len, K, D), kv_index),
+                pl.BlockSpec((1, 1, block_len, K, D), kv_index),
+                pl.BlockSpec((1, 1, block_len, K, D), kv_index),
             ],
             out_specs=pl.BlockSpec((1, K, G, D),
                                    lambda b, j, *_: (b, 0, 0, 0)),
@@ -184,7 +220,8 @@ def paged_decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
-    )(tbl, clen, qg, k_pool, v_pool)
+    )(fetch, live, clen, jnp.asarray(layer, jnp.int32).reshape(1),
+      win.reshape(1), qg, k_pool, v_pool)
     return out.reshape(B, H, D)
 
 

@@ -10,10 +10,14 @@ Tiles come from the plan's BlockPlans — kernel code never picks its own
 (paper §4: the template is parameterized by the compiler, the datapath
 just runs).
 
-The model path does not call these wrappers: prefill and decode
-attention are XLA-level (:mod:`repro.models.attention`,
-:mod:`repro.dist.flash_decode`), and the plan's ``use_pallas`` is only
-recorded.
+The model path does not call these wrappers.  One kernel is on it,
+called directly: the paged decode
+(:func:`repro.kernels.decode_attention.paged_decode_attention`, through
+:func:`repro.models.attention.attention_decode_paged_stacked`) where
+the plan records ``decode_impl = "pallas_paged"``: a paged per-head K/V
+pool on one TPU chip.  Prefill and every other decode attention are
+XLA-level (:mod:`repro.models.attention`, :mod:`repro.dist.flash_decode`),
+and the plan's ``use_pallas`` is only recorded.
 """
 
 from __future__ import annotations

@@ -18,6 +18,7 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.decode_attention import paged_decode_attention
 from repro.models.common import (apply_rope, mla_softmax_scale, rms_norm,
                                  rope_interleaved, yarn_rope_table)
 
@@ -222,6 +223,32 @@ def attention_decode_paged(
         k = k_pool[safe].reshape(B, nb * bl, *k_pool.shape[2:])
         v = v_pool[safe].reshape(B, nb * bl, *v_pool.shape[2:])
     return attention_decode(q, k, v, cache_len=cache_len, window=window)
+
+
+def attention_decode_paged_stacked(
+    q: jax.Array,              # (B, 1, H, hd) — post-RoPE
+    k_pools: jax.Array,        # (L, N, bl, K, hd) every layer's pool
+    v_pools: jax.Array,        # (L, N, bl, K, hd)
+    block_tbl: jax.Array,      # (B, nb) block ids; -1 = unassigned
+    *,
+    layer: jax.Array,          # int32 scalar: the layer to read
+    cache_len: jax.Array,      # (B,): number of valid positions
+    window=0,
+) -> jax.Array:
+    """One-token decode against layer ``layer`` of the stacked paged
+    pools (Pallas path): the kernel streams only each slot's live
+    blocks, from where they lie in the stacked pool.  Lowered for
+    another platform than a TPU, it runs in interpret mode.  Oracle:
+    :func:`attention_decode_paged` of the layer's pools."""
+    def run(interpret):
+        return functools.partial(
+            paged_decode_attention, cache_len=cache_len, window=window,
+            layer=layer, interpret=interpret)
+
+    ctx = jax.lax.platform_dependent(
+        q[:, 0], k_pools, v_pools, block_tbl,
+        tpu=run(False), default=run(True))
+    return ctx[:, None]
 
 
 def project_qkv(

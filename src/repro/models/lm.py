@@ -64,6 +64,7 @@ class RunCfg:
     remat: str = "none"            # none | dots | full
     moe_impl: str = "gshard_einsum"  # or shard_map_alltoall | dense_einsum
     decode_impl: str = "xla"       # or shard_map_flash (seq-sharded cache)
+                                   # | pallas_paged (paged per-head K/V)
     combine_topology: Optional[str] = None  # flat|ring|bidir; None -> predicate
     mesh: Optional[jax.sharding.Mesh] = None   # needed by shard_map path
     data_axes: Tuple[str, ...] = ("data",)
@@ -726,7 +727,7 @@ def scatter_blocks(pool: jax.Array, ids: jax.Array,
 
 
 def append_kv_paged(pool: jax.Array, new: jax.Array, pos: jax.Array,
-                    tbl: jax.Array, start=0) -> jax.Array:
+                    tbl: jax.Array, start=0, layer=None) -> jax.Array:
     """Paged KV append: write ``new[b]`` into slot b's owning block.
 
     ``pool`` is ``(n_blocks, block_len, *row)``, ``new`` ``(B, 1,
@@ -737,15 +738,18 @@ def append_kv_paged(pool: jax.Array, new: jax.Array, pos: jax.Array,
     sharded pool (``dist.flash_decode.flash_decode_paged`` — under 2-D
     pool sharding the shard's offset linearizes its (data..., model)
     mesh coordinates data-major): blocks owned elsewhere are dropped
-    too.  Oracle: :func:`repro.kernels.ref.paged_append_ref`.
+    too.  With ``layer``, ``pool`` is every layer's ``(L, n_blocks,
+    block_len, *row)`` stack and the rows land in that layer, in place.
+    Oracle: :func:`repro.kernels.ref.paged_append_ref`.
     """
-    N, bl = pool.shape[0], pool.shape[1]
+    n = 0 if layer is None else 1
+    N, bl = pool.shape[n], pool.shape[n + 1]
     blk = jnp.take_along_axis(tbl, (pos // bl)[:, None], axis=1)[:, 0] - start
     # scatter mode="drop" still *wraps* negative indices, so route
     # unassigned/off-shard entries to an always-out-of-range sentinel
     blk = jnp.where(blk < 0, N, blk)
-    return pool.at[blk, pos % bl].set(new[:, 0].astype(pool.dtype),
-                                      mode="drop")
+    at = (blk, pos % bl) if layer is None else (layer, blk, pos % bl)
+    return pool.at[at].set(new[:, 0].astype(pool.dtype), mode="drop")
 
 
 def append_kv(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:
@@ -787,10 +791,16 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
     block_tbl = cache.get("block_tbl")        # paged residency marker
     pools = tuple(cache_rows(arch))
     counting = _counts_pairs(cfg)
+    # the Pallas paged decode reads and appends each layer in place in
+    # the stacked pools: the layer loop carries them whole and never
+    # slices a layer out
+    in_place = (cfg.decode_impl == "pallas_paged" and block_tbl is not None
+                and not arch.is_mla)
 
-    def attend(grp, h, w, kv):
-        """Decode attention of one layer over its pools ``kv``; returns
-        the layer's attention output and the appended pools."""
+    def attend(grp, h, w, kv, li):
+        """Decode attention of layer ``li`` over its pools ``kv`` (every
+        layer's, when ``in_place``); returns the layer's attention
+        output and the appended pools."""
         if arch.is_mla:
             p = _mla_params(grp)
             with jax.named_scope("qkv"):
@@ -831,6 +841,12 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
                         q, k, v, kc, vc, pos, w, mesh=cfg.mesh,
                         data_axes=cfg.data_axes, model_axis=cfg.model_axis,
                         combine=cfg.combine_topology)
+            elif in_place:
+                kc = append_kv_paged(kc, k, pos, block_tbl, layer=li)
+                vc = append_kv_paged(vc, v, pos, block_tbl, layer=li)
+                ctx = attn_mod.attention_decode_paged_stacked(
+                    q, kc, vc, block_tbl, layer=li, cache_len=pos + 1,
+                    window=w)
             else:
                 if not cfg.shard_heads:
                     pass
@@ -858,13 +874,13 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
                                                     window=w)
         return ctx.reshape(B, 1, -1) @ ap.wo, (kc, vc)
 
-    def layer(x, grp, w, kv, sc, cc):
-        """One layer of decode; returns (x, new kv/sc/cc, held pairs)."""
+    def layer(x, grp, w, kv, sc, cc, li):
+        """Decode of layer ``li``; returns (x, new kv/sc/cc, held pairs)."""
         h = rms_norm(x, grp["pre_norm"], arch.norm_eps)
         out = 0.0
         n_paths = int(arch.has_attention) + int(arch.has_ssm)
         if arch.has_attention:
-            y, kv = attend(grp, h, w, kv)
+            y, kv = attend(grp, h, w, kv, li)
             out = out + y
         if arch.has_ssm:
             sp = SSMParams(**grp["ssm"])
@@ -890,7 +906,7 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
     cc_full = cache.get("conv")
     if g > 1:
         kv_full = tuple(f.reshape(Lg, g, *f.shape[1:]) for f in kv_full) \
-            if pools else kv_full
+            if pools and not in_place else kv_full
         if sc_full is not None:
             sc_full = sc_full.reshape(Lg, g, *sc_full.shape[1:])
             cc_full = cc_full.reshape(Lg, g, *cc_full.shape[1:])
@@ -906,26 +922,32 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
     def body(carry, xs):
         x, i, kf, sf, cf, pairs = carry
         grp, w = xs
-        kv = tuple(at(f, i) for f in kf)
+        kv = kf if in_place else tuple(at(f, i) for f in kf)
         sc, cc = at(sf, i), at(cf, i)
         if g > 1:
-            x, kv0, sc0, cc0, h0 = layer(x, grp["dense"], w[0],
-                                         tuple(c[0] for c in kv),
-                                         sc[0], cc[0])
-            x, kv1, sc1, cc1, h1 = layer(x, grp["moe"], w[1],
-                                         tuple(c[1] for c in kv),
-                                         sc[1], cc[1])
-            kv = tuple(jnp.stack([a, b]) for a, b in zip(kv0, kv1)) \
-                if arch.has_attention else kv
+            x, kv0, sc0, cc0, h0 = layer(
+                x, grp["dense"], w[0],
+                kv if in_place else tuple(c[0] for c in kv),
+                sc[0], cc[0], i * g)
+            x, kv1, sc1, cc1, h1 = layer(
+                x, grp["moe"], w[1],
+                kv0 if in_place else tuple(c[1] for c in kv),
+                sc[1], cc[1], i * g + 1)
+            if in_place:
+                kv = kv1
+            elif arch.has_attention:
+                kv = tuple(jnp.stack([a, b]) for a, b in zip(kv0, kv1))
             sc = jnp.stack([sc0, sc1]) if arch.has_ssm else sc
             cc = jnp.stack([cc0, cc1]) if arch.has_ssm else cc
             held = (h0, h1)
         else:
-            x, kv, sc, cc, h = layer(x, grp, w, kv, sc, cc)
+            x, kv, sc, cc, h = layer(x, grp, w, kv, sc, cc, i)
             held = (h,)
         if counting:
             pairs = pairs + _held_sum(held)
-        return (x, i + 1, tuple(put(f, i, c) for f, c in zip(kf, kv)),
+        return (x, i + 1,
+                kv if in_place else tuple(put(f, i, c)
+                                          for f, c in zip(kf, kv)),
                 put(sf, i, sc), put(cf, i, cc), pairs), None
 
     pairs = jnp.zeros((B,), jnp.int32) if counting else ()
@@ -936,17 +958,22 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
     lead = []
     for j in range(arch.first_dense_layers):
         grp, w = jax.tree.map(lambda a: a[j], stacks[0])
-        x, kv, sc, cc, h = layer(x, grp, w, tuple(at(f, j) for f in kv_full),
-                                 at(sc_full, j), at(cc_full, j))
+        x, kv, sc, cc, h = layer(
+            x, grp, w,
+            kv_full if in_place else tuple(at(f, j) for f in kv_full),
+            at(sc_full, j), at(cc_full, j), j)
         if counting:
             pairs = pairs + _held_sum((h,))
+        if in_place:
+            kv_full = kv
         lead.append((kv, sc, cc))
     carry = (x, jnp.asarray(len(lead), jnp.int32), kv_full, sc_full,
              cc_full, pairs)
     carry, _ = jax.lax.scan(body, carry, stacks[-1])
     x, _, new_kv, new_s, new_c, pairs = carry
     for j, (kv, sc, cc) in enumerate(lead):
-        new_kv = tuple(put(f, j, c) for f, c in zip(new_kv, kv))
+        if not in_place:
+            new_kv = tuple(put(f, j, c) for f, c in zip(new_kv, kv))
         new_s, new_c = put(new_s, j, sc), put(new_c, j, cc)
     with jax.named_scope("logits"):
         x = rms_norm(x, params["final_norm"], arch.norm_eps)
@@ -954,7 +981,8 @@ def decode_step(arch: ArchConfig, params, cache, batch, cfg: RunCfg,
     new_cache = dict(cache)
     new_cache["pos"] = pos + 1
     for key, f in zip(pools, new_kv):
-        new_cache[key] = f.reshape(L, *f.shape[2:]) if g > 1 else f
+        new_cache[key] = f.reshape(L, *f.shape[2:]) \
+            if g > 1 and not in_place else f
     if arch.has_ssm:
         new_cache["ssm"] = new_s.reshape(L, *new_s.shape[2:]) if g > 1 else new_s
         new_cache["conv"] = new_c.reshape(L, *new_c.shape[2:]) if g > 1 else new_c

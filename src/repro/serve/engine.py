@@ -485,6 +485,10 @@ class ServeEngine:
         # rows drawn by the batched program / by per-row ``_sample``
         self.sampled_batched = 0
         self.sampled_per_slot = 0
+        # paged decode: the busy slots' live blocks the ticks read, and
+        # the table entries (max_batch x columns) those ticks addressed
+        self.decode_blocks_live = 0
+        self.decode_blocks_table = 0
         # token-expert pairs the held-expert layer computed, by phase
         # (the model programs' ``moe_held_pairs`` counter)
         self.moe_held_pairs = {"prefill": 0, "decode": 0}
@@ -559,11 +563,17 @@ class ServeEngine:
         executes; ``"flash"`` when the internal single-shard combine
         takes over — model axis of size 1, or the sharded dim not
         divisible (``max_len`` for a dense cache, ``n_blocks`` for a
-        paged pool); ``"xla"`` when no mesh was provided.
+        paged pool); ``"pallas_paged"`` when the Pallas kernel reads a
+        paged per-head K/V pool in place; ``"xla"`` when no mesh was
+        provided.
         """
         impl = self.cfg.decode_impl
         if impl == "xla":
             return impl
+        if impl == "pallas_paged":
+            # lm.decode_step's own guard: a paged per-head K/V pool
+            return impl if self.kv_residency == "paged" \
+                and not self.arch.is_mla else "xla"
         if self.cfg.mesh is None:
             return "xla"               # lm.decode_step's own guard
         if impl == "shard_map_flash":
@@ -705,7 +715,12 @@ class ServeEngine:
                     "respecialize with the larger decode shape instead of "
                     "overriding past it")
         cfg = build_run_cfg(plan, arch, mesh)
-        if mesh is None and cfg.decode_impl != "xla":
+        if mesh is None and cfg.decode_impl == "shard_map_flash":
+            cfg = dataclasses.replace(cfg, decode_impl="xla")
+        if cfg.decode_impl == "pallas_paged" \
+                and jax.default_backend() != "tpu":
+            # the Pallas kernel compiles for a TPU only; elsewhere the
+            # XLA gather (its oracle) decodes
             cfg = dataclasses.replace(cfg, decode_impl="xla")
         if combine_topology is not None:
             # ops escape hatch, same shape as kv_admission: pin the
@@ -903,6 +918,8 @@ class ServeEngine:
                 "disagg_inflight": len(self._disagg),
                 "sampled_batched": self.sampled_batched,
                 "sampled_per_slot": self.sampled_per_slot,
+                "decode_blocks_live": self.decode_blocks_live,
+                "decode_blocks_table": self.decode_blocks_table,
                 "moe_held_pairs": dict(self.moe_held_pairs)}
 
     def telemetry(self) -> Dict[str, Any]:
@@ -2375,6 +2392,19 @@ class ServeEngine:
         with span("serve.moe", phase=phase, pairs=n):
             pass
 
+    def _count_decode_blocks(self) -> int:
+        """Add this tick's decode reads to the block counters: each busy
+        slot's ``ceil((len + 1) / block_len)`` live blocks (the row it
+        appends included), against every table entry.  Returns the live
+        count (0 for a dense cache)."""
+        if self.kv_residency != "paged":
+            return 0
+        bl = self.block_len
+        live = sum(int(self.slot_len[s] + bl) // bl for s in self.active)
+        self.decode_blocks_live += live
+        self.decode_blocks_table += self.cache["block_tbl"].size
+        return live
+
     def _sync_pos(self) -> None:
         """Mirror per-slot lengths into the device cache (freed slots 0)."""
         pos = jnp.asarray(self.slot_len)
@@ -2413,7 +2443,9 @@ class ServeEngine:
                     # speed waiting
                     time.sleep(0.01)
                 return 0
-            with span("serve.decode", slots=len(self.active)):
+            live = self._count_decode_blocks()
+            with span("serve.decode", slots=len(self.active),
+                      live_blocks=live):
                 # per-slot positions: every slot decodes at its own
                 # offset.  Freed slots are masked to (token 0, pos 0):
                 # their decode is a bounded dummy over one cache row, so

@@ -66,19 +66,90 @@ def test_append_kv_paged_matches_ref():
     assert np.array_equal(np.asarray(got2[tbl[1, 0]]), np.asarray(kp[tbl[1, 0]]))
 
 
+def _stacked_case(key, window, L=3, H=4, K=2, D=16, bl=8, N=12,
+                  dtype=jnp.bfloat16):
+    """Stacked ``(L, N, bl, K, D)`` pools and slots of mixed lengths: a
+    full ``nb x bl`` slot, a free slot (table row -1, decoded at length
+    1 as the engine does), a length-1 slot, and two partial ones.  The
+    poisoned twin holds NaN in every pool row no slot's query sees (past
+    its length, before its window, or in no slot's block)."""
+    ks = jax.random.split(key, 3)
+    q = jax.random.normal(ks[0], (5, H, D)).astype(dtype)
+    kp = jax.random.normal(ks[1], (L, N, bl, K, D)).astype(dtype)
+    vp = jax.random.normal(ks[2], (L, N, bl, K, D)).astype(dtype)
+    tbl = np.asarray([[4, 9, 2, 7], [-1, -1, -1, -1], [11, -1, -1, -1],
+                      [0, 5, 3, -1], [8, 10, -1, -1]], np.int32)
+    cl = np.asarray([4 * bl, 1, 1, 2 * bl + 5, bl + 1], np.int32)
+    seen = np.zeros((N, bl), bool)
+    for b in range(len(cl)):
+        lo = cl[b] - window if window > 0 else 0
+        for pos in range(max(lo, 0), cl[b]):
+            if tbl[b, pos // bl] >= 0:
+                seen[tbl[b, pos // bl], pos % bl] = True
+    hide = jnp.asarray(~seen)[None, :, :, None, None]
+    poison = lambda p: jnp.where(hide, jnp.nan, p).astype(dtype)
+    return (q, kp, vp, poison(kp), poison(vp), jnp.asarray(tbl),
+            jnp.asarray(cl))
+
+
+@pytest.mark.parametrize("layout", ["pool", "stacked"])
 @pytest.mark.parametrize("window", [0, 6])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
-def test_paged_decode_attention_kernel_vs_oracle(window, dtype):
+def test_paged_decode_attention_kernel_vs_oracle(layout, window, dtype):
     """The scalar-prefetch Pallas kernel (interpret mode) streams blocks
-    via the table and matches the gather oracle exactly."""
-    q, kp, vp, *_ , tbl, cl = _pool_case(jax.random.PRNGKey(2), dtype=dtype)
-    got = paged_decode_attention(q, kp, vp, tbl, cache_len=cl,
-                                 window=window, interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, tbl, cache_len=cl,
-                                          window=window)
-    err = np.abs(np.asarray(got, np.float32)
-                 - np.asarray(want, np.float32)).max()
-    assert err < (2e-2 if dtype == jnp.bfloat16 else 1e-5), (window, err)
+    via the table and matches the gather oracle.  On stacked pools it
+    reads the layer it is given, per layer, and only each slot's live
+    blocks: with every unseen pool row NaN the output stays finite."""
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    if layout == "pool":
+        q, kp, vp, *_, tbl, cl = _pool_case(jax.random.PRNGKey(2),
+                                            dtype=dtype)
+        got = paged_decode_attention(q, kp[None], vp[None], tbl,
+                                     cache_len=cl, window=window,
+                                     interpret=True)
+        want = ref.paged_decode_attention_ref(q, kp, vp, tbl, cache_len=cl,
+                                              window=window)
+        err = np.abs(np.asarray(got, np.float32)
+                     - np.asarray(want, np.float32)).max()
+        assert err < tol, (window, err)
+        return
+    q, kp, vp, kbad, vbad, tbl, cl = _stacked_case(
+        jax.random.PRNGKey(12), window, dtype=dtype)
+    for layer in range(kp.shape[0]):
+        got = paged_decode_attention(q, kbad, vbad, tbl, cache_len=cl,
+                                     window=window, layer=layer,
+                                     interpret=True)
+        want = ref.paged_decode_attention_ref(q, kp[layer], vp[layer], tbl,
+                                              cache_len=cl, window=window)
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all(), (layer, window)
+        err = np.abs(got - np.asarray(want, np.float32)).max()
+        assert err < tol, (layer, window, err)
+
+
+@pytest.mark.parametrize("window", [0, 6])
+def test_paged_decode_fetches_only_live_blocks(window):
+    """The kernel's grid fetches a block only where an entry is live
+    (assigned, and holding a row the query sees): every other step names
+    the block its predecessor fetched, which Pallas does not copy again.
+    So the number of block changes along the grid is the live count."""
+    from repro.kernels.decode_attention import _fetch_plan
+    *_, tbl, cl = _stacked_case(jax.random.PRNGKey(12), window)
+    bl = 8
+    fetch, live = _fetch_plan(tbl, cl, jnp.int32(window), bl)
+    fetch, live = np.asarray(fetch).reshape(-1), np.asarray(live)
+    want = np.zeros_like(live)
+    for b, n in enumerate(np.asarray(cl)):
+        lo = max(n - window, 0) if window else 0
+        for j in range(tbl.shape[1]):
+            want[b, j] = (tbl[b, j] >= 0 and j * bl < n
+                          and (j + 1) * bl > lo)
+    assert np.array_equal(live, want)
+    flat_live = live.reshape(-1).astype(bool)
+    assert np.array_equal(fetch[flat_live],
+                          np.asarray(tbl).reshape(-1)[flat_live])
+    changes = 1 + np.count_nonzero(fetch[1:] != fetch[:-1])
+    assert changes == flat_live.sum()
 
 
 def test_paged_decode_attention_xla_gather_vs_oracle():
@@ -195,10 +266,11 @@ def test_aliased_tables_read_identical_xla_gather():
 def test_aliased_tables_read_identical_pallas(window):
     q, kp, vp, kpp, vpp, ta, tp, cl = _aliased_vs_private_case(
         jax.random.PRNGKey(8))
-    got = paged_decode_attention(q, kp, vp, ta, cache_len=cl,
+    got = paged_decode_attention(q, kp[None], vp[None], ta, cache_len=cl,
                                  window=window, interpret=True)
-    want = paged_decode_attention(q, kpp, vpp, tp, cache_len=cl,
-                                  window=window, interpret=True)
+    want = paged_decode_attention(q, kpp[None], vpp[None], tp,
+                                  cache_len=cl, window=window,
+                                  interpret=True)
     assert np.array_equal(np.asarray(got, np.float32),
                           np.asarray(want, np.float32))
 

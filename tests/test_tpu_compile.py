@@ -50,9 +50,19 @@ def one_chip():
 def _paged(B, H, K, D, bl, window=0):
     n = B * 8
     return (functools.partial(paged_decode_attention, window=window),
-            [((B, H, D), BF16), ((n, bl, K, D), BF16),
-             ((n, bl, K, D), BF16), ((B, 8), I32)],
+            [((B, H, D), BF16), ((1, n, bl, K, D), BF16),
+             ((1, n, bl, K, D), BF16), ((B, 8), I32)],
             {"cache_len": ((B,), I32)})
+
+
+def _stacked(L, N, B, H, K, D, bl, nb):
+    """The model path's call: stacked pools, the layer and the window
+    traced scalars (a scan's layer index, a per-layer window)."""
+    return (paged_decode_attention,
+            [((B, H, D), BF16), ((L, N, bl, K, D), BF16),
+             ((L, N, bl, K, D), BF16), ((B, nb), I32)],
+            {"cache_len": ((B,), I32), "layer": ((), I32),
+             "window": ((), I32)})
 
 
 def _flash(H, K, D, bq, bkv, window=0, S=4096):
@@ -81,6 +91,10 @@ def _ssd(H, P, N, S=4096, chunk=256):
 CASES = {
     "paged_decode_qwen3": _paged(8, 32, 8, 128, 256),
     "paged_decode_hymba": _paged(8, 25, 5, 64, 256, window=1024),
+    # the chat cell's pool (16 layers of 100 x 512) and slots (32 x 8
+    # columns); hymba's 32 layers with window layers, 32 x 16 columns
+    "paged_decode_stacked_qwen3": _stacked(16, 100, 32, 32, 8, 128, 512, 8),
+    "paged_decode_stacked_hymba": _stacked(32, 64, 32, 25, 5, 64, 256, 16),
     "flash_packed_qwen3": _flash(32, 8, 128, 512, 512),
     "flash_plain_qwen3": _flash(32, 8, 128, 512, 1024),
     "flash_packed_hymba": _flash(25, 5, 64, 512, 512, window=1024),
